@@ -1,0 +1,423 @@
+#!/usr/bin/env python3
+"""Quickest proof that ramses_tpu still starts on the chip.
+
+One process, one chip (``python chip_smoke.py``): drives the system's
+main path — the ``python -m ramses_tpu <namelist>`` entry — once on the
+two headline Sedov configurations and checks the result by the repo's
+own means (conservation audit, finite state, the step programs really
+hold the Pallas kernels):
+
+* uniform: ``namelists/sedov3d.nml`` as committed (256³ f32, 10 steps);
+* AMR: ``namelists/sedov3d_amr.nml`` (levels 7→9, regrid every step).
+
+``--chips 4`` runs ONLY the sharded path and its comparison
+(``ShardedSim`` / ``ShardedAmrSim`` over four devices against the same
+class on one device, in this process).
+
+No accelerator ⇒ non-zero exit and no result line; never selects a
+platform, never falls back to the CPU.  ``--rehearse`` is the sandbox
+rehearsal (tiny levels, Pallas kernels interpreted, whatever backend
+``JAX_PLATFORMS`` gives): it exercises the control flow and can never
+print an ``"ok": true`` line.  Any phase that raises ends the process
+non-zero; nothing here catches a failure and carries on.
+
+Everything the run writes lands under ``<checkout>/chip_out/`` (the
+compile cache under ``<checkout>/.jax_cache`` unless
+``JAX_COMPILATION_CACHE_DIR`` says otherwise).  The seconds printed are
+smoke facts of one cold or warm start, not benchmark numbers.
+"""
+
+import argparse
+import json
+import os
+import re
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+OUT = os.path.join(ROOT, "chip_out")
+NML_UNI = os.path.join(ROOT, "namelists", "sedov3d.nml")
+NML_AMR = os.path.join(ROOT, "namelists", "sedov3d_amr.nml")
+KERNEL = 'custom_call_target="tpu_custom_call"'
+
+# f32 state, totals audited in f64.  Early Sedov keeps nearly all the
+# energy in a handful of blast cells, so their f32 rounding does not
+# average out: the sandbox rehearsal measured ~1.4e-7 relative drift per
+# finest-level substep, and 12 coarse steps of levels 7->9 make 48 of
+# them (~7e-6).  1e-4 is one order above that; a lost coarse-fine flux
+# correction or a dropped level shows at >= 1e-3.
+CONS_RTOL = 1e-4
+# sharded vs one device: same class, same XLA formulation, same f32
+# inputs; only the partitioner's fusion/reduction order differs, so the
+# states agree to a few ulp per step.  L1(diff)/L1(ref) over <= 12
+# steps stays below 1e-5; a wrong halo or a dropped shard is O(1).
+SHARD_L1_RTOL = 1e-5
+
+
+def say(msg):
+    print(msg, flush=True)
+
+
+def rel(a, b):
+    return abs(float(a) - float(b)) / max(abs(float(b)), 1e-300)
+
+
+class Phase:
+    """Wall/compile/cache/memory facts of one phase, printed on exit
+    from a clean ``with`` block (a raising phase prints nothing more
+    and ends the process)."""
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        from ramses_tpu.platform import compile_cache_stats
+        self.c0 = compile_cache_stats()
+        self.t0 = time.perf_counter()
+        say(f"[{self.name}] start")
+        return self
+
+    def __exit__(self, etype, exc, tb):
+        if etype is not None:
+            return False
+        import jax
+        from ramses_tpu.platform import compile_cache_stats
+        wall = time.perf_counter() - self.t0
+        c1 = compile_cache_stats()
+        comp = c1["compile_s"] - self.c0["compile_s"]
+        stats = jax.devices()[0].memory_stats() or {}
+        say(f"[{self.name}] ok wall_s={wall:.2f} "
+            f"first_call_compile_s={comp:.2f} rest_s={wall - comp:.2f} "
+            f"cache_dir={c1['dir'] or '(off)'} "
+            f"cache_hits={c1['hits'] - self.c0['hits']} "
+            f"cache_misses={c1['misses'] - self.c0['misses']} "
+            f"peak_device_bytes={stats.get('peak_bytes_in_use')}")
+        return False
+
+
+def shrunk(nml, lmin, lmax, nstep):
+    """Rehearsal copy of a committed namelist at tiny levels."""
+    txt = open(nml).read()
+    txt = re.sub(r"levelmin=\d+", f"levelmin={lmin}", txt)
+    txt = re.sub(r"levelmax=\d+", f"levelmax={lmax}", txt)
+    txt = re.sub(r"nstepmax=\d+", f"nstepmax={nstep}", txt)
+    dst = os.path.join(OUT, "rehearse_" + os.path.basename(nml))
+    with open(dst, "w") as f:
+        f.write(txt)
+    return dst
+
+
+def run_cli(nml):
+    """The command line's own path: parse like ``python -m ramses_tpu
+    <nml> --ndim 3`` and hand back the sim it ran."""
+    from ramses_tpu.__main__ import build_parser, run
+    return run(build_parser().parse_args([nml, "--ndim", "3"]))
+
+
+def check_finite(name, arrays):
+    import jax.numpy as jnp
+    for key, a in arrays:
+        assert bool(jnp.isfinite(a).all()), f"{name}: non-finite {key}"
+
+
+# ----------------------------------------------------------------------
+# one-chip phases
+# ----------------------------------------------------------------------
+def phase_native():
+    from ramses_tpu import native
+    L = native.lib()
+    assert L is not None, f"native build failed: {native.build_error}"
+    say(f"[native] ok built from ramses_tpu/native/src/ramses_native.cpp "
+        f"-> {os.path.relpath(native.so_path(), ROOT)}")
+
+
+def phase_uniform(nml, rehearse):
+    import jax
+    import jax.numpy as jnp
+    from ramses_tpu.config import load_params
+    from ramses_tpu.driver import Simulation
+    from ramses_tpu.grid import uniform
+
+    with Phase("uniform"):
+        params = load_params(nml, ndim=3)
+        tot0 = Simulation(params, dtype=jnp.float32).totals()
+        m0, e0 = float(tot0["mass"]), float(tot0["energy"])
+        sim = run_cli(nml)
+        st, grid = sim.state, sim.grid
+        n = grid.shape[0]
+        assert st.nstep == params.run.nstepmax, st.nstep
+        assert st.u.dtype == jnp.float32 and st.u.shape == (5, n, n, n)
+        check_finite("uniform", [("u", st.u)])
+        tot = sim.totals()
+        dm, de = rel(tot["mass"], m0), rel(tot["energy"], e0)
+        say(f"[uniform] {n}^3 f32 nstep={st.nstep} t={st.t:.6e} "
+            f"mass_rel_err={dm:.3e} energy_rel_err={de:.3e} "
+            f"(tol {CONS_RTOL:g})")
+        assert dm < CONS_RTOL and de < CONS_RTOL
+        # the step program the run used, recompiled from its own
+        # arguments (a persistent-cache hit): it must hold the kernel
+        tdt = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+        txt = uniform.run_steps.lower(
+            grid, st.u, jnp.asarray(st.t, tdt),
+            jnp.asarray(sim.tend, tdt), st.nstep).compile().as_text()
+        ncall = txt.count(KERNEL)
+        fused = uniform._pallas_ok(grid, st.u.dtype)
+        say(f"[uniform] step program: "
+            f"{'fused Pallas kernel' if fused else 'XLA formulation'}, "
+            f"tpu_custom_calls={ncall}")
+        if not rehearse:
+            assert fused and ncall >= 1, \
+                "uniform step program holds no fused Pallas kernel"
+
+
+def level_formulations(sim):
+    """[(level, name, is_kernel)] for the sim's CURRENT fused spec —
+    the same gates, asked with the same arguments, as the traced step
+    (hierarchy._advance_traced → amr/kernels.py)."""
+    from ramses_tpu.hydro import pallas_muscl as pk
+    from ramses_tpu.hydro import pallas_oct as po
+    spec = sim._fused_spec()
+    cfg, dtype = spec.cfg, sim.dtype
+    out = []
+    for i, l in enumerate(spec.levels):
+        if spec.complete[i]:
+            if spec.slab and spec.slab[i] is not None:
+                sl = spec.slab[i]
+                cut = tuple(p is not None for p in sl.perms)
+                kax = pk.shard_axes(cfg, sl.loc, cut, dtype)
+                out.append((l, f"dense slab-sharded sweep (grid {sl.grid}"
+                            f", halo {sl.backend}, per-shard "
+                            + (f"fused kernel axes {kax}" if kax
+                               else "XLA update") + ")", kax is not None))
+                continue
+            root = spec.root or (1,) * cfg.ndim
+            shape = tuple(r << l for r in root[:cfg.ndim])
+            k = pk.kernel_available(cfg, shape, spec.bspec.faces, dtype)
+            out.append((l, "dense fused kernel (pallas_muscl)" if k
+                        else "dense XLA sweep", k))
+        elif spec.comm and spec.comm[i] is not None:
+            out.append((l, "explicit-comm stencil sweep (XLA)", False))
+        elif spec.blocked and spec.blocked[i]:
+            nt = sim.blocks[l].ntile_pad
+            k = spec.pallas_tiles and po.tile_available(
+                cfg, nt, dtype, spec.block_shift)
+            out.append((l, f"tile_sweep kernel (pallas_oct, {nt} tiles)"
+                        if k else f"XLA tiles ({nt} tiles)", k))
+        else:
+            no = sim.maps[l].noct_pad
+            k = po.available(cfg, no, dtype)
+            out.append((l, f"oct_sweep kernel (pallas_oct, {no} octs)"
+                        if k else f"XLA oct stencils ({no} octs)", k))
+    return out
+
+
+def phase_amr(nml, rehearse):
+    import jax.numpy as jnp
+    from ramses_tpu.amr import hierarchy as H
+    from ramses_tpu.amr.hierarchy import AmrSim
+    from ramses_tpu.config import load_params
+
+    with Phase("amr"):
+        params = load_params(nml, ndim=3)
+        lmax = params.amr.levelmax
+        sim0 = AmrSim(params, dtype=jnp.float32)
+        tot0 = sim0.totals()
+        octs0 = {l: sim0.tree.noct(l) for l in sim0.levels()}
+        del sim0
+        sim = run_cli(nml)
+        octs = {l: sim.tree.noct(l) for l in sim.levels()}
+        say(f"[amr] levels {params.amr.levelmin}->{lmax} f32 "
+            f"nstep={sim.nstep} t={sim.t:.6e} regrid_interval="
+            f"{sim.regrid_interval} octs initial={octs0} final={octs}")
+        assert sim.nstep == params.run.nstepmax, sim.nstep
+        assert octs.get(lmax, 0) > 0, f"level {lmax} never populated"
+        # a regrid runs before every coarse step; the blast must have
+        # moved the refined shell at least once for it to count
+        assert sim.regrid_interval == 1 and sim.nstep >= 2
+        assert octs != octs0, "no regrid changed the tree"
+        check_finite("amr", [(f"u[{l}]", sim.u[l]) for l in sim.levels()])
+        tot = sim.totals()
+        dm, de = rel(tot[0], tot0[0]), rel(tot[4], tot0[4])
+        say(f"[amr] mass_rel_err={dm:.3e} energy_rel_err={de:.3e} "
+            f"(tol {CONS_RTOL:g})")
+        assert dm < CONS_RTOL and de < CONS_RTOL
+        # the coarse-step program of the final tree, recompiled from
+        # the sim's own arguments: one kernel call per level substep
+        # (level lmin+i is swept 2^i times per coarse step)
+        forms = level_formulations(sim)
+        spec = sim._fused_spec()
+        txt = H._fused_coarse_step.lower(
+            sim.u, sim.dev, {}, jnp.asarray(sim.dt_old, sim.dtype), spec,
+            sim._cool_bundle()).compile().as_text()
+        ncall = txt.count(KERNEL)
+        want = sum(1 << (l - spec.lmin) for l, _, k in forms if k)
+        for l, name, _ in forms:
+            say(f"[amr] level {l}: {name}")
+        say(f"[amr] coarse-step program: tpu_custom_calls={ncall} "
+            f"(expected {want} from the gates)")
+        if not rehearse:
+            assert ncall == want and all(k for _, _, k in forms), \
+                "a level of the default Sedov path fell off its kernel"
+
+
+# ----------------------------------------------------------------------
+# four-chip phases (builder-run): sharded path vs one device
+# ----------------------------------------------------------------------
+def assert_spans(name, arrays, ndev):
+    for key, a in arrays:
+        got = len(a.sharding.device_set)
+        assert got == ndev, f"{name}: {key} spans {got} devices, not {ndev}"
+
+
+def l1_rel(a, b):
+    import numpy as np
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.abs(a - b).sum() / max(np.abs(b).sum(), 1e-300))
+
+
+def phase_sharded_uniform(nml, devs):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from ramses_tpu.config import load_params
+    from ramses_tpu.grid.uniform import _pallas_ok, totals
+    from ramses_tpu.parallel.sharded import ShardedSim
+
+    with Phase("sharded-uniform"):
+        params = load_params(nml, ndim=3)
+        nstep = params.run.nstepmax
+        res = {}
+        for nd in (len(devs), 1):
+            sim = ShardedSim(params, devices=devs[:nd], dtype=jnp.float32)
+            t0 = totals(sim.u, sim.inner.cfg, sim.inner.dx)
+            m0, e0 = float(t0["mass"]), float(t0["energy"])
+            sim.run(nstep, tend=sim.inner.tend)
+            assert_spans(f"ShardedSim/{nd}", [("u", sim.u)], nd)
+            check_finite(f"ShardedSim/{nd}", [("u", sim.u)])
+            t1 = totals(sim.u, sim.inner.cfg, sim.inner.dx)
+            dm, de = rel(t1["mass"], m0), rel(t1["energy"], e0)
+            say(f"[sharded-uniform] {nd} device(s): mesh="
+                f"{dict(sim.mesh.shape)} nstep={sim.nstep} "
+                f"t={sim.t:.6e} mass_rel_err={dm:.3e} "
+                f"energy_rel_err={de:.3e} fused_kernel_gate="
+                f"{_pallas_ok(sim.grid, sim.u.dtype)} (the kernel gates "
+                f"need jax.device_count()==1; here it is "
+                f"{jax.device_count()}, so both sides run the XLA "
+                f"formulation)")
+            assert sim.nstep == nstep
+            assert dm < CONS_RTOL and de < CONS_RTOL
+            res[nd] = (np.asarray(sim.u), sim.t)
+            del sim
+        (u4, t4), (u1, t1) = res[len(devs)], res[1]
+        d = l1_rel(u4, u1)
+        say(f"[sharded-uniform] L1(u_{len(devs)}dev - u_1dev)/L1(u_1dev)"
+            f"={d:.3e} dt_rel={rel(t4, t1):.3e} (tol {SHARD_L1_RTOL:g})")
+        assert d < SHARD_L1_RTOL and rel(t4, t1) < SHARD_L1_RTOL
+
+
+def phase_sharded_amr(nml, devs, nstep):
+    import jax
+    import jax.numpy as jnp
+    from ramses_tpu.config import load_params
+    from ramses_tpu.parallel.amr_sharded import ShardedAmrSim
+
+    with Phase("sharded-amr"):
+        params = load_params(nml, ndim=3)
+        res = {}
+        for nd in (len(devs), 1):
+            sim = ShardedAmrSim(params, devices=devs[:nd],
+                                dtype=jnp.float32)
+            tot0 = sim.totals()
+            sim.evolve(1e9, nstepmax=nstep)
+            octs = {l: sim.tree.noct(l) for l in sim.levels()}
+            arrays = [(f"u[{l}]", sim.u[l]) for l in sim.levels()]
+            arrays += [(f"dev[{l}][{k}]", v) for l in sim.levels()
+                       for k, v in sim.dev[l].items()
+                       if isinstance(v, jax.Array)]
+            assert_spans(f"ShardedAmrSim/{nd}", arrays, nd)
+            check_finite(f"ShardedAmrSim/{nd}", arrays[:len(octs)])
+            tot = sim.totals()
+            dm, de = rel(tot[0], tot0[0]), rel(tot[4], tot0[4])
+            say(f"[sharded-amr] {nd} device(s): nstep={sim.nstep} "
+                f"t={sim.t:.6e} octs={octs} mass_rel_err={dm:.3e} "
+                f"energy_rel_err={de:.3e}")
+            for l, name, _ in level_formulations(sim):
+                say(f"[sharded-amr] {nd} device(s) level {l}: {name}")
+            assert sim.nstep == nstep
+            assert dm < CONS_RTOL and de < CONS_RTOL
+            res[nd] = (octs, sim.t, {l: sim.tree_order_cells(sim.u[l], l)
+                                     for l in sim.levels()})
+            del sim
+        (o4, t4, u4), (o1, t1, u1) = res[len(devs)], res[1]
+        assert o4 == o1, f"octs per level differ: {o4} vs {o1}"
+        for l in sorted(u1):
+            d = l1_rel(u4[l], u1[l])
+            say(f"[sharded-amr] level {l}: L1 rel diff {d:.3e} "
+                f"(tol {SHARD_L1_RTOL:g})")
+            assert d < SHARD_L1_RTOL
+        assert rel(t4, t1) < SHARD_L1_RTOL
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
+                    help="4: run only the sharded path and its "
+                         "one-device comparison")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="sandbox rehearsal at tiny levels; never "
+                         "prints an ok line")
+    args = ap.parse_args()
+
+    sys.path.insert(0, ROOT)
+    import jax
+    devs = jax.devices()
+    d0 = devs[0]
+    if d0.platform != "tpu" and not args.rehearse:
+        print(f"chip_smoke: no accelerator — jax found "
+              f"{d0.platform!r} ({len(devs)} device(s)); this script "
+              f"never falls back to it", file=sys.stderr)
+        return 2
+    if len(devs) < args.chips:
+        print(f"chip_smoke: --chips {args.chips} but jax found "
+              f"{len(devs)} device(s)", file=sys.stderr)
+        return 2
+    if args.chips == 1 and len(devs) != 1 and not args.rehearse:
+        print(f"chip_smoke: the one-chip phases need a one-device "
+              f"process (the Pallas gates require jax.device_count()"
+              f"==1), jax found {len(devs)}; use --chips 4 here",
+              file=sys.stderr)
+        return 2
+    import ramses_tpu  # noqa: F401  (engages the compile-cache rule)
+    os.makedirs(OUT, exist_ok=True)
+    os.chdir(OUT)                   # snapshots/telemetry land here
+    say(f"[device] platform={d0.platform} device_kind={d0.device_kind} "
+        f"count={len(devs)} jax={jax.__version__} chips={args.chips} "
+        f"rehearse={args.rehearse}")
+
+    uni, amr, amr_steps = NML_UNI, NML_AMR, 3
+    if args.rehearse:
+        from ramses_tpu.hydro import pallas_oct
+        pallas_oct.FORCE_INTERPRET = True
+        uni = shrunk(NML_UNI, 5, 5, 4)
+        amr = shrunk(NML_AMR, 4, 6, 4)
+        amr_steps = 2
+    t0 = time.perf_counter()
+    if args.chips == 1:
+        phase_native()
+        phase_uniform(uni, args.rehearse)
+        phase_amr(amr, args.rehearse)
+    else:
+        phase_sharded_uniform(uni, devs[:4])
+        phase_sharded_amr(amr, devs[:4], amr_steps)
+    say(f"[total] wall_s={time.perf_counter() - t0:.2f}")
+    if args.rehearse:
+        say("rehearsal complete (not a chip run: no result line)")
+        return 0
+    print(json.dumps({"ok": True, "device": {
+        "platform": d0.platform, "kind": d0.device_kind,
+        "count": len(devs)}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -11,7 +11,7 @@ import argparse
 import sys
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         prog="ramses_tpu",
         description="TPU-native AMR astrophysics framework")
@@ -100,6 +100,11 @@ def main(argv=None) -> int:
                          "interrupted or failed run, rebuild from the "
                          "latest valid checkpoint and continue, up to "
                          "this many attempts (exponential backoff)")
+    return ap
+
+
+def main(argv=None) -> int:
+    ap = build_parser()
     args = ap.parse_args(argv)
 
     # run-service front-end: --submit enqueues and exits; --serve is
@@ -147,7 +152,16 @@ def main(argv=None) -> int:
         return 1 if counts["failed"] else 0
     if not args.namelist:
         ap.error("a namelist is required (or use --serve/--submit)")
+    run(args)
+    return 0
 
+
+def run(args):
+    """Run the namelist ``args`` (a parsed :func:`build_parser` namespace)
+    names to its end and return the simulation object (``None`` for
+    the calibration and ensemble front-ends, which own no single
+    sim) — :func:`main` minus the process exit code, so a caller can
+    inspect the state the command line would have produced."""
     import jax.numpy as jnp
 
     from ramses_tpu.config import load_params
@@ -155,8 +169,8 @@ def main(argv=None) -> int:
     dtype = getattr(jnp, args.dtype)
     params = load_params(args.namelist, ndim=args.ndim)
 
-    # persistent compile cache (&RUN_PARAMS compile_cache_dir, env
-    # RAMSES_COMPILE_CACHE): must land before the first trace
+    # persistent compile cache (&RUN_PARAMS compile_cache_dir): must
+    # land before the first trace
     from ramses_tpu.platform import setup_compile_cache
     setup_compile_cache(params)
 
@@ -223,7 +237,7 @@ def main(argv=None) -> int:
               f"loss {res['loss_first']:.4e} -> "
               f"{res['loss_final']:.4e} "
               f"{best}-> {res['checkpoint']}")
-        return 0
+        return None
 
     supervised = (args.max_attempts > 1 or params.run.auto_resume
                   or params.run.nrestart == -1)
@@ -258,7 +272,7 @@ def main(argv=None) -> int:
                   f"{info.get('reason')} at nstep={info.get('nstep')} "
                   f"t={info.get('t')}")
         eng.telemetry.close(eng)
-        return 0
+        return None
 
     def drive_amr(tend):
         def drive(sim):
@@ -395,7 +409,7 @@ def main(argv=None) -> int:
     tel = getattr(sim, "telemetry", None)
     if tel is not None:
         tel.close(sim)
-    return 0
+    return sim
 
 
 if __name__ == "__main__":

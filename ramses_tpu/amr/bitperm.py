@@ -15,10 +15,19 @@ coordinate bits 1..l-1 with z most significant per triplet —
 with x slowest — ``amr/tree.py`` ``cell_offsets``.)
 
 A gather by this permutation moves one ~nvar-float row per index: on
-TPU that lowers to millions of latency-bound small copies and was the
-dominant cost of the steady-state AMR step (BENCH_CAPTURED_r04).  A
-reshape to ``(2,)*ndim*lvl`` axes + transpose expresses the same data
-movement with static regular strides that XLA vectorizes.
+TPU that lowers to millions of latency-bound small copies.  Reshapes +
+transposes express the same data movement with static regular strides
+that XLA vectorizes.
+
+The permutation is applied in STAGES, one Morton group (one bit of
+every axis) at a time from the least significant up: each stage is a
+single transpose of at most ``2*ndim + 2`` axes that moves whole
+contiguous sub-boxes.  The one-shot form — reshape to ``(2,)*ndim*lvl``
+axes and transpose once — is the same permutation, but the TPU compiler
+takes 34–63 s for that 21-axis transpose at 128³ and, once the flag
+criteria fuse into it, 394–478 s and 68 MB of code for the flags
+program (sandbox compiles for a described v5e, PR 22); the staged form
+compiles in ~1.5 s.  Pure data movement, so both are bitwise identical.
 
 Only valid for cubic complete levels (2^lvl cells per dim); callers
 fall back to the index-permutation gather otherwise (non-cubic roots).
@@ -39,6 +48,7 @@ lands on an oct boundary.
 
 from __future__ import annotations
 
+import math
 from functools import lru_cache
 
 import jax.numpy as jnp
@@ -56,33 +66,36 @@ def _bit_seq(lvl: int, ndim: int) -> tuple:
 
 
 @lru_cache(maxsize=None)
-def _slab_axes(lvl: int, ndim: int, mbits: int = 0) -> tuple:
-    """Transpose permutation taking the REMAINING flat bit axes (after
-    fixing the top ``mbits`` device bits) to dense coordinate-major
-    order over the local sub-box.  ``mbits=0`` is the full-box case:
-    axis p of the reshaped flat array holds the p-th most significant
-    flat index bit."""
-    seq = _bit_seq(lvl, ndim)
-    pos = {bit: p - mbits for p, bit in enumerate(seq) if p >= mbits}
-    return tuple(pos[(d, i)] for d in range(ndim)
-                 for i in range(lvl - 1, -1, -1) if (d, i) in pos)
+def _stages(lvl: int, ndim: int, mbits: int = 0) -> tuple:
+    """Per Morton group ``k = 1..lvl-1`` (least significant first): the
+    axes whose coordinate bit ``k`` is still a flat index bit after the
+    top ``mbits`` device bits are fixed, in flat MSB→LSB order, with
+    the transpose that merges them into the local box.  The cut takes
+    whole groups from the top and at most one group partly, so the
+    list simply ends where the cut begins.
 
-
-@lru_cache(maxsize=None)
-def _inv_slab_axes(lvl: int, ndim: int, mbits: int = 0) -> tuple:
-    fwd = _slab_axes(lvl, ndim, mbits)
-    inv = [0] * len(fwd)
-    for i, a in enumerate(fwd):
-        inv[a] = i
-    return tuple(inv)
-
-
-def _bit_axes(lvl: int, ndim: int) -> tuple:
-    return _slab_axes(lvl, ndim, 0)
-
-
-def _inv_bit_axes(lvl: int, ndim: int) -> tuple:
-    return _inv_slab_axes(lvl, ndim, 0)
+    Stage input ``[A, 2 per kept axis, s_0..s_{ndim-1}, W]`` (``s_d`` =
+    local oct-grid extent of axis d assembled so far, ``W`` = the cells
+    of one oct × trailing); the permutation puts each kept axis' new
+    bit directly above that axis' extent."""
+    kept = set(_bit_seq(lvl, ndim)[mbits:])
+    out = []
+    for k in range(1, lvl):
+        axes = tuple(d for d in range(ndim - 1, -1, -1) if (d, k) in kept)
+        if not axes:
+            break
+        na = len(axes)
+        perm = [0]
+        for d in range(ndim):
+            if d in axes:
+                perm.append(1 + axes.index(d))
+            perm.append(1 + na + d)
+        perm.append(1 + na + ndim)
+        inv = [0] * len(perm)
+        for i, p in enumerate(perm):
+            inv[p] = i
+        out.append((axes, tuple(perm), tuple(inv)))
+    return tuple(out)
 
 
 @lru_cache(maxsize=None)
@@ -123,16 +136,41 @@ def chunk_coords(lvl: int, ndim: int, mbits: int) -> tuple:
     return tuple(out)
 
 
+@lru_cache(maxsize=None)
+def _offset_perm(ndim: int) -> tuple:
+    """``[s_0..s_{nd-1}, 2_0..2_{nd-1}, T]`` → ``[s_0, 2_0, s_1, 2_1,
+    ..., T]``: the last step, putting every axis' within-oct bit below
+    that axis' oct coordinate.  Returns (perm, inverse)."""
+    perm = tuple(x for d in range(ndim) for x in (d, ndim + d)) \
+        + (2 * ndim,)
+    return perm, tuple(perm.index(i) for i in range(len(perm)))
+
+
 def flat_to_dense_slab(rows, lvl: int, ndim: int, mbits: int):
     """One chunk's flat-order rows ``[ncell/2^mbits, *trailing]`` →
-    its dense local sub-box ``slab_shape + trailing`` (pure
-    reshape/transpose, shard-local)."""
+    its dense local sub-box ``slab_shape + trailing`` (staged
+    reshape/transposes, shard-local).
+
+    The ``2^ndim`` cells of an oct ride through the stages as part of
+    the minor (trailing) axis and are interleaved last: every
+    intermediate keeps a minor axis of at least ``2^ndim`` elements —
+    a bare ``[n, n, n]`` mask staged with a minor axis of 1–2 elements
+    costs the TPU compiler 30 s at 64³ where this form costs 0.3 s."""
     loc = slab_shape(lvl, ndim, mbits)
     trailing = rows.shape[1:]
-    nb = ndim * lvl - mbits
-    x = rows.reshape((2,) * nb + trailing)
-    ax = _slab_axes(lvl, ndim, mbits) + tuple(range(nb, nb + len(trailing)))
-    return jnp.transpose(x, ax).reshape(loc + trailing)
+    T = math.prod(trailing)
+    W = T << ndim
+    s = [1] * ndim                      # oct-grid extent assembled so far
+    a = rows.reshape((-1,) + tuple(s) + (W,))
+    for axes, perm, _ in _stages(lvl, ndim, mbits):
+        A = a.shape[0] >> len(axes)
+        a = a.reshape((A,) + (2,) * len(axes) + tuple(s) + (W,))
+        a = jnp.transpose(a, perm)
+        for d in axes:
+            s[d] *= 2
+        a = a.reshape((A,) + tuple(s) + (W,))
+    a = a.reshape(tuple(s) + (2,) * ndim + (T,))
+    return jnp.transpose(a, _offset_perm(ndim)[0]).reshape(loc + trailing)
 
 
 def dense_to_flat_slab(dense, lvl: int, ndim: int, mbits: int):
@@ -140,11 +178,24 @@ def dense_to_flat_slab(dense, lvl: int, ndim: int, mbits: int):
     :func:`flat_to_dense_slab`)."""
     ncell = 1 << (ndim * lvl - mbits)
     trailing = dense.shape[ndim:]
-    nb = ndim * lvl - mbits
-    x = dense.reshape((2,) * nb + trailing)
-    ax = _inv_slab_axes(lvl, ndim, mbits) + tuple(
-        range(nb, nb + len(trailing)))
-    return jnp.transpose(x, ax).reshape((ncell,) + trailing)
+    T = math.prod(trailing)
+    W = T << ndim
+    s = [n // 2 for n in slab_shape(lvl, ndim, mbits)]
+    a = dense.reshape(tuple(x for n in s for x in (n, 2)) + (T,))
+    a = jnp.transpose(a, _offset_perm(ndim)[1]).reshape(
+        (1,) + tuple(s) + (W,))
+    for axes, _, inv in reversed(_stages(lvl, ndim, mbits)):
+        A = a.shape[0]
+        split = []
+        for d in range(ndim):
+            if d in axes:
+                s[d] //= 2
+                split += [2, s[d]]
+            else:
+                split.append(s[d])
+        a = jnp.transpose(a.reshape((A,) + tuple(split) + (W,)), inv)
+        a = a.reshape((A << len(axes),) + tuple(s) + (W,))
+    return a.reshape((ncell,) + trailing)
 
 
 def flat_index_np(coords, lvl: int, ndim: int):

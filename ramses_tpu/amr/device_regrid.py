@@ -240,8 +240,8 @@ def _migrate_level_jit(old_u, u_coarse, new_keys, old_keys, coarse_keys,
     oi, j = rows // ttd, rows % ttd
     sgn_tab = jnp.asarray((cell_offsets(ndim) * 2 - 1).astype(np.float64),
                           dtype=u_coarse.dtype)   # [2^d, ndim]
-    vals = K.interp_cells(u_coarse, f_cell[oi], nb[oi], sgn_tab[j], cfg,
-                          itype=itype)
+    # father rows gathered once per oct; ncell_pad == noct_pad * 2^ndim
+    vals = K.interp_octs(u_coarse, f_cell, nb, sgn_tab, cfg, itype=itype)
     copied = old_u[pos[oi] * ttd + j]
     return jnp.where(kept[oi][:, None], copied.astype(old_u.dtype),
                      jnp.where(valid[oi][:, None],

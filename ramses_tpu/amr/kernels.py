@@ -95,15 +95,45 @@ def interp_cells(u_coarse, cell_idx, nb_idx, sgn, cfg: HydroStatic,
     u_coarse: [ncell, nvar]; cell_idx: [ni]; nb_idx: [ni, ndim, 2];
     sgn: [ni, ndim] ±1.  Returns [ni, nvar].
     """
-    a0 = u_coarse[cell_idx]                            # [ni, nvar]
+    a0, ws = _interp_slopes(u_coarse, cell_idx, nb_idx, cfg, itype)
     out = a0
+    for d, w in enumerate(ws):
+        out = out + w * (0.5 * sgn[:, d:d + 1])
+    return out
+
+
+def interp_octs(u_coarse, cell_idx, nb_idx, sgn_tab, cfg: HydroStatic,
+                itype: int = 1):
+    """:func:`interp_cells` for ALL ``2^ndim`` children of each
+    requested father cell at once: the father/neighbour rows are
+    gathered once per oct and the children differ only in the sign
+    table ``sgn_tab [2^ndim, ndim]``.  Returns ``[ni * 2^ndim, nvar]``
+    in (oct, child) row order — bitwise what ``interp_cells`` gives on
+    the ``2^ndim``-fold repeated indices, with ``2^ndim``× fewer
+    gathered rows (on TPU the repeated form also compiled 20× slower:
+    42 s vs 2 s for one 512-oct level, sandbox compile, PR 22)."""
+    a0, ws = _interp_slopes(u_coarse, cell_idx, nb_idx, cfg, itype)
+    out = jnp.broadcast_to(a0[:, None], (a0.shape[0], sgn_tab.shape[0],
+                                         a0.shape[1]))
+    for d, w in enumerate(ws):
+        out = out + w[:, None] * (0.5 * sgn_tab[None, :, d:d + 1])
+    return out.reshape(-1, a0.shape[1])
+
+
+def _interp_slopes(u_coarse, cell_idx, nb_idx, cfg: HydroStatic,
+                   itype: int):
+    """Father values ``a0 [ni, nvar]`` and the per-dim limited slopes
+    ``[w_d [ni, nvar]]`` (empty for ``itype == 0``) of
+    :func:`interp_cells`."""
+    a0 = u_coarse[cell_idx]                            # [ni, nvar]
+    ws = []
     if itype == 0:
-        return out
+        return a0, ws
     for d in range(cfg.ndim):
         al = u_coarse[nb_idx[:, d, 0]]
         ar = u_coarse[nb_idx[:, d, 1]]
-        dl = 0.5 * (a0 - al)                           # halved differences
-        dr = 0.5 * (ar - a0)                           # (compute_limiter_minmod)
+        dl = 0.5 * (a0 - al)                     # halved differences
+        dr = 0.5 * (ar - a0)                     # (compute_limiter_minmod)
         if itype == 1:
             w = jnp.where(dl * dr <= 0.0, 0.0,
                           jnp.sign(dr) * jnp.minimum(jnp.abs(dl),
@@ -116,8 +146,8 @@ def interp_cells(u_coarse, cell_idx, nb_idx, sgn, cfg: HydroStatic,
             lim = jnp.minimum(2.0 * jnp.abs(dl), 2.0 * jnp.abs(dr))
             w = jnp.where(dl * dr <= 0.0, 0.0,
                           jnp.sign(dc) * jnp.minimum(jnp.abs(dc), lim))
-        out = out + w * (0.5 * sgn[:, d:d + 1])
-    return out
+        ws.append(w)
+    return a0, ws
 
 
 def _gather_uloc(u_flat, interp_vals, stencil_src, vsgn, cfg: HydroStatic):
@@ -351,7 +381,8 @@ def tile_sweep(u_flat, interp_vals, tile_src, tile_vsgn, tile_ok,
     okl = tile_ok.T.reshape((td,) * ndim + (ntile,))
 
     from ramses_tpu.hydro import pallas_oct
-    if pallas_ok and pallas_oct.tile_available(cfg, ntile, u_flat.dtype):
+    if pallas_ok and pallas_oct.tile_available(cfg, ntile, u_flat.dtype,
+                                                shift):
         out_k = pallas_oct.tile_sweep(ut, okl.astype(ut.dtype), dt, cfg,
                                       dx, shift, want_flux=ret_flux)
         du_t, corrp = out_k[0], out_k[1]

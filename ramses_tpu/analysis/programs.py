@@ -271,9 +271,9 @@ def build_programs(names: Optional[List[str]] = None) -> List[Program]:
     into the canonical lowerings as f64 select/multiply chains —
     exactly what ``f64-leak`` flags, but as a host-environment
     artifact rather than a program property."""
-    from jax.experimental import disable_x64
+    import jax
     out: List[Program] = []
-    with disable_x64():
+    with jax.enable_x64(False):
         for name, build in BUILDERS.items():
             if names is not None and name not in names:
                 continue

@@ -82,12 +82,12 @@ class RunParams:
     # on 8, restore on 4 or 1, and vice versa).  .false. refuses a
     # restore whose saved process count differs from the current run.
     elastic_restore: bool = True
-    # JAX persistent compilation cache directory (env fallback
-    # RAMSES_COMPILE_CACHE): set before the first trace so a known
-    # namelist cold-starts in O(load) instead of O(compile); "" keeps
-    # the package default (~/.cache/ramses_tpu_xla on TPU, off on
-    # CPU-forced runs).  Cache hit/miss counts land in the telemetry
-    # run header.
+    # JAX persistent compilation cache directory: set before the
+    # first trace so a known namelist cold-starts in O(load) instead of
+    # O(compile); "" keeps the package default (<checkout>/.jax_cache,
+    # off on CPU-forced runs).  JAX_COMPILATION_CACHE_DIR, when set,
+    # outranks both.  Cache hit/miss counts land in the telemetry run
+    # header.
     compile_cache_dir: str = ""
 
 
@@ -458,9 +458,9 @@ class EnsembleParams:
     gang_starve_s: float = 600.0
     # serve-loop default: point the persistent compile cache at a
     # shared <queue_dir>/compile_cache so fleet workers warm-start each
-    # other (an explicit &RUN_PARAMS compile_cache_dir or
-    # RAMSES_COMPILE_CACHE still wins); .false. restores the PR 12
-    # opt-in behavior
+    # other (an explicit &RUN_PARAMS compile_cache_dir still wins,
+    # JAX_COMPILATION_CACHE_DIR outranks both); .false. restores the
+    # PR 12 opt-in behavior
     shared_compile_cache: bool = True
     # hang watchdog for the batched engine (resilience/watchdog.py):
     # same semantics as the &RUN_PARAMS deadlines, but guarding the

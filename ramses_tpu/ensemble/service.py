@@ -109,11 +109,11 @@ def _job_setup(queue_dir: str, job: "jq.Job", log=print):
     # persistent compile cache before the first trace: a fleet worker
     # re-claiming a known namelist cold-starts in O(load), not
     # O(compile).  Default: the queue's shared dir, so workers warm-
-    # start EACH OTHER; an explicit &RUN_PARAMS compile_cache_dir or
-    # RAMSES_COMPILE_CACHE env still wins, and
+    # start EACH OTHER; an explicit &RUN_PARAMS compile_cache_dir
+    # still wins (and JAX_COMPILATION_CACHE_DIR outranks both —
+    # platform._engage_cache), and
     # &ENSEMBLE_PARAMS shared_compile_cache=.false. opts out.
     if (not (params.run.compile_cache_dir or "").strip()
-            and not os.environ.get("RAMSES_COMPILE_CACHE", "").strip()
             and params.ensemble.shared_compile_cache):
         params.run.compile_cache_dir = os.path.join(queue_dir,
                                                     "compile_cache")

@@ -27,16 +27,8 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-try:  # Element block-indexing mode is absent from older jax releases
-    from jax._src.pallas.core import Element
-except ImportError:         # pragma: no cover - depends on jax version
-    Element = None
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams → CompilerParams between releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
 
 from ramses_tpu.hydro.core import HydroStatic
 
@@ -52,7 +44,7 @@ def kernel_available(cfg: HydroStatic, shape, bc_faces, dtype) -> bool:
     device (the kernel has no GSPMD partitioning rule — sharded runs
     must keep the XLA solver so the SPMD partitioner can insert halo
     collectives), and configuration coverage."""
-    if DISABLED or Element is None:
+    if DISABLED:
         return False
     if jax.default_backend() != "tpu" or jax.device_count() != 1:
         return False
@@ -353,14 +345,14 @@ def fused_step_padded(u_pad, dt, cfg: HydroStatic, dx: float,
                         want_flux)
     in_specs = [
         pl.BlockSpec(
-            (Element(5), Element(bx + 2 * NG), Element(WY), Element(nz)),
+            (pl.Element(5), pl.Element(bx + 2 * NG), pl.Element(WY), pl.Element(nz)),
             lambda i, j: (0, i * bx, j * by, 0),
             memory_space=pltpu.VMEM),
     ]
     args = [u_pad]
     if ok_pad is not None:
         in_specs.append(pl.BlockSpec(
-            (Element(bx + 2 * NG), Element(WY), Element(nz)),
+            (pl.Element(bx + 2 * NG), pl.Element(WY), pl.Element(nz)),
             lambda i, j: (i * bx, j * by, 0),
             memory_space=pltpu.VMEM))
         args.append(ok_pad)
@@ -391,7 +383,7 @@ def fused_step_padded(u_pad, dt, cfg: HydroStatic, dx: float,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,           # CPU parity tests
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
     )(*args)
 
@@ -410,7 +402,7 @@ def shard_axes(cfg: HydroStatic, loc, cut, dtype):
     requirement — inside ``shard_map`` the kernel runs on the local
     block, so no GSPMD partitioning rule is needed.
     """
-    if DISABLED or Element is None:
+    if DISABLED:
         return None
     if jax.default_backend() != "tpu":
         return None

@@ -45,10 +45,6 @@ from ramses_tpu.hydro.core import HydroStatic
 from ramses_tpu.hydro.pallas_muscl import (DISABLED, _hllc_flux, _llf_flux,
                                            _slopes)
 
-# jax renamed TPUCompilerParams → CompilerParams between releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 # Test hook: force the kernel branch on any backend, run it in Pallas
 # interpreter mode — lets CI drive level_sweep's REAL pallas branch (not
@@ -58,11 +54,9 @@ FORCE_INTERPRET = bool(__import__("os").environ
                        .get("RAMSES_PALLAS_OCT_INTERPRET"))
 
 
-def available(cfg: HydroStatic, noct_pad: int, dtype) -> bool:
-    """Availability gate for the oct-batch kernel (see module docstring;
-    the single-device restriction mirrors ``pallas_muscl.kernel_available``
-    — sharded levels keep the XLA formulation so GSPMD can partition;
-    with blocking on they still get the compact tile batch)."""
+def _in_scope(cfg: HydroStatic, dtype) -> bool:
+    """Platform + physics scope shared by both kernels' gates (see the
+    module docstring)."""
     if DISABLED:
         return False
     if not FORCE_INTERPRET and (jax.default_backend() != "tpu"
@@ -80,7 +74,15 @@ def available(cfg: HydroStatic, noct_pad: int, dtype) -> bool:
         return False
     if dtype not in (jnp.float32, jnp.dtype("float32")):
         return False
-    return noct_pad % 128 == 0
+    return True
+
+
+def available(cfg: HydroStatic, noct_pad: int, dtype) -> bool:
+    """Availability gate for the oct-batch kernel (see module docstring;
+    the single-device restriction mirrors ``pallas_muscl.kernel_available``
+    — sharded levels keep the XLA formulation so GSPMD can partition;
+    with blocking on they still get the compact tile batch)."""
+    return _in_scope(cfg, dtype) and noct_pad % 128 == 0
 
 
 def _tile(noct_pad: int) -> int:
@@ -224,7 +226,7 @@ def oct_sweep(uloc, ok, dt, cfg: HydroStatic, dx: float,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
     )(uloc, ok, dt2)
 
@@ -236,37 +238,31 @@ def oct_sweep(uloc, ok, dt, cfg: HydroStatic, dx: float,
 _NG = 2                                   # tile halo width (MUSCL stencil)
 
 
-def tile_available(cfg: HydroStatic, ntile_pad: int, dtype) -> bool:
+# tile sizes (``oct_block_shift``) whose kernel the chip's compiler is
+# proven to accept (tests/test_chip_compile.py).  shift=1 (td=8) makes
+# Mosaic ABORT the process (lower_to_llo.cc "d >> 32 == 0"), so the gate
+# refuses it and those runs take the XLA tile formulation openly.
+_PROVEN_SHIFTS = (2,)
+_LANES = 128
+
+
+def tile_shape_ok(ntile_pad: int, shift: int) -> bool:
+    """Tile-batch shapes Mosaic accepts: the lane block must be the
+    whole tile axis (counts below 128, sublane-aligned) or a multiple
+    of 128.  Tile counts are power-of-2 bucketed (>= 8), so every
+    bucket qualifies."""
+    if shift not in _PROVEN_SHIFTS:
+        return False
+    if ntile_pad < _LANES:
+        return ntile_pad % 8 == 0
+    return ntile_pad % _LANES == 0
+
+
+def tile_available(cfg: HydroStatic, ntile_pad: int, dtype,
+                   shift: int) -> bool:
     """Availability gate for the blocked tile kernel — same physics scope
-    as :func:`available`; tile counts are power-of-2 bucketed (>=8)."""
-    if DISABLED:
-        return False
-    if not FORCE_INTERPRET and (jax.default_backend() != "tpu"
-                                or jax.device_count() != 1):
-        return False
-    if getattr(cfg, "physics", "hydro") != "hydro":
-        return False
-    if cfg.ndim != 3 or cfg.nener != 0 or cfg.npassive != 0:
-        return False
-    if cfg.pressure_fix or cfg.scheme != "muscl":
-        return False
-    if cfg.slope_type not in (1, 2, 8):
-        return False
-    if cfg.riemann not in ("llf", "hllc"):
-        return False
-    if dtype not in (jnp.float32, jnp.dtype("float32")):
-        return False
-    return ntile_pad % 8 == 0
-
-
-def _tile_nt(ntile_pad: int, td: int) -> int:
-    """Lane-tile size: keep slots*lanes near the 6^3 kernel's proven
-    VMEM budget (216 slots x 512 lanes)."""
-    cap = max(8, (216 * 512) // td ** 3)
-    nt = 8
-    while nt * 2 <= cap and ntile_pad % (nt * 2) == 0:
-        nt *= 2
-    return nt
+    as :func:`available`, plus the compile-proven tile shapes."""
+    return _in_scope(cfg, dtype) and tile_shape_ok(ntile_pad, shift)
 
 
 def _make_tile_kernel(cfg: HydroStatic, dx: float, c: int,
@@ -371,7 +367,7 @@ def tile_sweep(ut, ok, dt, cfg: HydroStatic, dx: float, shift: int,
     td = c + 2 * _NG
     o = c // 2
     n = ut.shape[-1]
-    nt = _tile_nt(n, td)
+    nt = min(n, _LANES)   # whole tile axis below 128 tiles, else 128 lanes
     dt2 = jnp.asarray(dt, ut.dtype).reshape(1, 1)
     kern = _make_tile_kernel(cfg, dx, c, want_flux)
     interpret = interpret or FORCE_INTERPRET
@@ -407,6 +403,6 @@ def tile_sweep(ut, ok, dt, cfg: HydroStatic, dx: float, shift: int,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
     )(ut, ok, dt2)

@@ -30,13 +30,8 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-except Exception:                                  # pragma: no cover
-    pl = pltpu = _CompilerParams = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ramses_tpu.mhd import uniform as mu
 from ramses_tpu.mhd.core import MhdStatic, NCOMP
@@ -61,7 +56,7 @@ def slab_available(cfg: MhdStatic, loc, dtype) -> bool:
     the interpreter is a correctness vehicle, not a fast path), and the
     padded box inside the VMEM budget.  Compiled runs additionally
     require float32 (the f64 VPU story is interpret-only)."""
-    if DISABLED or pl is None:
+    if DISABLED:
         return False
     dt = jnp.dtype(dtype)
     if not FORCE_INTERPRET:
@@ -120,8 +115,8 @@ def ct_step_slab(up, bfp_ext, dt, dx: Sequence[float], cfg: MhdStatic,
         return pl.BlockSpec(shape, lambda: (0,) * rank)
 
     kwargs = {}
-    if not interpret and _CompilerParams is not None:
-        kwargs["compiler_params"] = _CompilerParams(
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_BUDGET + 28 * 1024 * 1024)
     un, bfn = pl.pallas_call(
         kern,

@@ -36,6 +36,7 @@ from __future__ import annotations
 
 from typing import Dict, NamedTuple, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
@@ -56,8 +57,8 @@ class SweepCommSpec(NamedTuple):
 
 
 def _shard_map(fn, mesh, in_specs, out_specs, check_rep=True):
-    return dma_halo.shard_map_compat(fn, mesh, in_specs, out_specs,
-                                     check_rep=check_rep)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
 
 
 def _halo_schedule(need: Dict[int, Dict[int, np.ndarray]], ndev: int):

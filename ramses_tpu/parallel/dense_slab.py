@@ -50,6 +50,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -145,9 +146,9 @@ def _take(a, ax: int, sl: slice):
 def _sm(spec: SlabSpec, body, in_specs, out_specs, use_pallas=False):
     """shard_map with replication checking off whenever the body holds
     a pallas_call (DMA halos or the CT kernel)."""
-    return dma_halo.shard_map_compat(
-        body, spec.mesh, in_specs, out_specs,
-        check_rep=(spec.backend != "dma" and not use_pallas))
+    return jax.shard_map(
+        body, mesh=spec.mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=(spec.backend != "dma" and not use_pallas))
 
 
 def halo_extend(a, spec: SlabSpec, ng: int, spatial0: int,

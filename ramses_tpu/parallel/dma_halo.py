@@ -47,14 +47,8 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-try:  # pallas is part of jax, but keep import-failure soft like pallas_muscl
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    # jax renamed TPUCompilerParams → CompilerParams between releases
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-except Exception:                                  # pragma: no cover
-    pl = pltpu = _CompilerParams = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DISABLED = bool(os.environ.get("RAMSES_NO_PALLAS"))
 
@@ -94,7 +88,7 @@ def _count(*slabs):
 
 def available() -> bool:
     """True when the DMA kernel can run compiled (real TPU backend)."""
-    if DISABLED or pl is None:
+    if DISABLED:
         return False
     try:
         return jax.default_backend() == "tpu"
@@ -117,7 +111,7 @@ def resolve_backend(requested) -> str:
     if req == "auto":
         return "dma" if available() else "ppermute"
     if req == "dma":
-        if available() or (FORCE_INTERPRET and pl is not None):
+        if available() or FORCE_INTERPRET:
             return "dma"
         if "dma" not in _warned:
             _warned.add("dma")
@@ -134,25 +128,6 @@ def resolve_backend(requested) -> str:
 
 def _interpret() -> bool:
     return FORCE_INTERPRET or jax.default_backend() != "tpu"
-
-
-def shard_map_compat(fn, mesh, in_specs, out_specs, check_rep=True):
-    """``shard_map`` across jax releases.  ``check_rep=False`` is
-    required whenever the body contains a ``pallas_call`` (no
-    replication rule exists for it); newer jax renamed the kwarg to
-    ``check_vma``."""
-    try:
-        sm = jax.shard_map                         # jax >= 0.8
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-    if check_rep:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:                              # pragma: no cover
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
 
 
 # ----------------------------------------------------------------------
@@ -204,13 +179,13 @@ def _dma_exchange(slabs, dsts, interpret: bool):
     dst_arr = jnp.stack([jnp.asarray(d, jnp.int32) for d in dsts])
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = _CompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             collective_id=next(_collective_ids) % 32)
     outs = pl.pallas_call(
         _exchange_kernel(n, barrier=not interpret),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
-        + [pl.BlockSpec(memory_space=pltpu.ANY)] * n,
-        out_specs=tuple(pl.BlockSpec(memory_space=pltpu.ANY)
+        + [pl.BlockSpec(memory_space=pl.ANY)] * n,
+        out_specs=tuple(pl.BlockSpec(memory_space=pl.ANY)
                         for _ in range(n)),
         out_shape=tuple(jax.ShapeDtypeStruct(s.shape, s.dtype)
                         for s in slabs),

@@ -166,10 +166,10 @@ def _build_run(grid: UniformGrid, mesh: Mesh, nsteps: int,
             body, (u_loc, t, ndone0), None, length=nsteps)
         return u_loc, t, ndone
 
-    return jax.jit(dma_halo.shard_map_compat(
-        shard_body, mesh, (P(None, AXIS), P(), P()),
-        (P(None, AXIS), P(), P()),
-        check_rep=(backend != "dma")))
+    return jax.jit(jax.shard_map(
+        shard_body, mesh=mesh, in_specs=(P(None, AXIS), P(), P()),
+        out_specs=(P(None, AXIS), P(), P()),
+        check_vma=(backend != "dma")))
 
 
 def run_steps_halo(grid: UniformGrid, mesh: Mesh, u, t, tend,

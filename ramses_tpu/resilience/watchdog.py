@@ -1,7 +1,7 @@
 """Deadline watchdog: hangs become first-class, classified faults.
 
 The failure mode the crash/NaN ladder (stepguard) cannot see is a run
-that simply *stops making progress* — a wedged device tunnel, a
+that simply *stops making progress* — a wedged device, a
 backend init that never returns, a compile that spins.  Every driver
 does exactly one blocking host fetch per fused window, so "hung" has a
 precise, observable definition: that fetch exceeded its wall-clock

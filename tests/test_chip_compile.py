@@ -1,0 +1,134 @@
+"""Compile the Sedov main-path kernels for a DESCRIBED TPU v5e.
+
+The sandbox has no chip, but the chip's compiler is installed and
+compiles for a described topology (``v5e:2x2``).  These cases hold the
+three Pallas hydro kernels of the default main path to what Mosaic
+accepts, at the real widths the Sedov runs use — every shape the f32
+hydro gates (``pallas_muscl.kernel_available``, ``pallas_oct.available``
+/ ``tile_available``) admit must have a passing case here, or the gate
+must not admit it.  A compile is not a run: nothing here says anything
+about results or speed.
+
+This is the only file that describes the chip: only one process may
+load the TPU library, so the topology is described inside a
+module-scoped fixture (never at import), the compiles run in the
+test's own process, and the persistent compile cache is off around
+them (a described-device entry cannot be read back without a chip).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ramses_tpu.config import load_params
+from ramses_tpu.hydro import pallas_muscl as pk
+from ramses_tpu.hydro import pallas_oct as po
+from ramses_tpu.hydro.core import HydroStatic
+
+NML = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "namelists", "sedov3d.nml")
+F32 = jnp.float32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return HydroStatic.from_params(load_params(NML, ndim=3))
+
+
+@pytest.fixture()
+def no_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes):
+    """Lower+compile ``fn`` for the described chip with x64 off (the
+    suite turns it on; index maps must stay i32 for Mosaic)."""
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("n,mask,want_flux", [
+    (256, False, False),      # uniform sedov3d.nml step (courant fused)
+    (128, True, False),       # AMR complete base level 7
+    (128, True, True),        # ... with the MC-tracer flux capture
+    (256, True, False),       # complete level 8
+])
+def test_fused_step_padded_compiles(one_chip, cfg, no_cache, n, mask,
+                                    want_flux):
+    shape = (n, n, n)
+    kinds = ((0, 0),) * 3
+    assert pk.supports(cfg, shape, kinds, F32)
+    pad = (n + 2 * pk.NG, n + pk.WY - pk.BY, n)
+    u = jax.ShapeDtypeStruct((5,) + pad, F32, sharding=one_chip)
+    dt = jax.ShapeDtypeStruct((), F32, sharding=one_chip)
+    dx = 0.5 / n
+    if mask:
+        ok = jax.ShapeDtypeStruct(pad, F32, sharding=one_chip)
+        _compile(lambda u, ok, dt: pk.fused_step_padded(
+            u, dt, cfg, dx, shape, ok_pad=ok, want_flux=want_flux),
+            u, ok, dt)
+    else:
+        _compile(lambda u, dt: pk.fused_step_padded(
+            u, dt, cfg, dx, shape, courant=True), u, dt)
+
+
+@pytest.mark.parametrize("noct", [128, 256, 512, 4096])
+def test_oct_sweep_compiles(one_chip, cfg, no_cache, noct):
+    """128/256/512 are the three lane tiles ``pallas_oct._tile`` picks;
+    4096 is many grid steps of the widest."""
+    assert po._tile(noct) == min(noct, 512)
+    u = jax.ShapeDtypeStruct((5, 6, 6, 6, noct), F32, sharding=one_chip)
+    ok = jax.ShapeDtypeStruct((6, 6, 6, noct), F32, sharding=one_chip)
+    dt = jax.ShapeDtypeStruct((), F32, sharding=one_chip)
+    _compile(lambda u, ok, dt: po.oct_sweep(u, ok, dt, cfg, 1.0 / 512),
+             u, ok, dt)
+
+
+@pytest.mark.parametrize("ntile", [8, 64, 128, 1024])
+def test_tile_sweep_compiles(one_chip, cfg, no_cache, ntile):
+    """Default ``oct_block_shift``; the tile buckets are powers of two
+    >= 8, so 8/64 cover the whole-axis lane tile and 128/1024 the
+    128-lane tile (one and many grid steps)."""
+    from ramses_tpu.config import AmrParams
+    shift = AmrParams().oct_block_shift
+    assert po.tile_shape_ok(ntile, shift)
+    td = (1 << (shift + 1)) + 2 * po._NG
+    u = jax.ShapeDtypeStruct((5, td, td, td, ntile), F32,
+                             sharding=one_chip)
+    ok = jax.ShapeDtypeStruct((td, td, td, ntile), F32, sharding=one_chip)
+    dt = jax.ShapeDtypeStruct((), F32, sharding=one_chip)
+    compiled = _compile(lambda u, ok, dt: po.tile_sweep(
+        u, ok, dt, cfg, 1.0 / 512, shift), u, ok, dt)
+    # the kernel asks for a 100 MiB scoped-VMEM limit; what the program
+    # keeps resident in HBM (args + outputs + temps) must fit one chip
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes)
+    assert total < 16 * 2 ** 30

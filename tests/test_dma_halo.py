@@ -112,11 +112,11 @@ def test_exchange_slabs_bitwise(dma):
                 [a_loc, b_loc], [fwd, bwd], OCT_AXIS, backend=backend)
             return ga, gb
 
-        f = dma_halo.shard_map_compat(
-            body, mesh,
+        f = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(P(OCT_AXIS), P(OCT_AXIS)),
             out_specs=(P(OCT_AXIS), P(OCT_AXIS)),
-            check_rep=(backend != "dma"))
+            check_vma=(backend != "dma"))
         results[backend] = jax.jit(f)(a, b)
     for x, y in zip(results["ppermute"], results["dma"]):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))

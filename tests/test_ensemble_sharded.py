@@ -257,7 +257,7 @@ def test_second_worker_zero_miss_cold_start(tmp_path):
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=os.path.dirname(os.path.dirname(
                    os.path.abspath(__file__))))
-    env.pop("RAMSES_COMPILE_CACHE", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     code = ("import sys; from ramses_tpu.ensemble.service import serve;"
             "serve(sys.argv[1], idle_exit=True, max_jobs=1,"
             "      max_attempts=1)")

@@ -35,6 +35,7 @@ CONFIGS = {
     "sedov2d.nml": (2, []),
     "sedov2d_balance.nml": (2, []),
     "sedov3d.nml": (3, []),
+    "sedov3d_amr.nml": (3, []),
     "sedov3d_telemetry.nml": (3, []),
     "static.nml": (3, []),
     "iliev1.nml": (3, []),

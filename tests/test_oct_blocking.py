@@ -243,7 +243,8 @@ def test_blocked_parity_pallas_interpret(monkeypatch):
             if blk == ".true.":
                 for l, b in s.blocks.items():
                     assert pallas_oct.tile_available(
-                        s.cfg, b.ntile_pad, jnp.float32), (l, b.ntile_pad)
+                        s.cfg, b.ntile_pad, jnp.float32,
+                        b.shift), (l, b.ntile_pad)
             for _ in range(2):
                 s.step_coarse(s.coarse_dt())
             sims[blk] = s

@@ -19,14 +19,6 @@ from ramses_tpu.config import Params
 
 SHAPE = (16, 16, 128)
 
-# the fused kernel's overlapping x/y halo windows need the Element
-# block-indexing mode; jax releases without it can't run the kernel
-# even in interpreter mode (production gates it off the same way in
-# pallas_muscl.kernel_available)
-pytestmark = pytest.mark.skipif(
-    pk.Element is None,
-    reason="pl.Element block mode absent from this jax release")
-
 
 def _cfg(riemann="llf", slope_type=1):
     p = Params(ndim=3)
