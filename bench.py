@@ -481,7 +481,7 @@ def bench_amr(params, dtype, jnp, hb=lambda *a, **k: None):
     hb("production")
 
     # per-phase regrid wallclock (flag / balance / maps / migrate /
-    # upload — hierarchy.regrid timer sections), folded out of the
+    # restrict — hierarchy.regrid timer sections), folded out of the
     # mixed timer dicts so the regrid cost trend is directly readable:
     # "growth" covers the cadenced-growth window, "production" the
     # regrid-every-step window above

@@ -1024,15 +1024,16 @@ class AmrSim:
                 if lay_p1 is not None:
                     m = balance.remap_son_oct(m, lay_p1)
                 self.maps[l] = m
-                self.dev[l] = dict(
-                    prev_dev[l],
-                    ok_dense=(self._place(jnp.asarray(m.ok_dense), "cells")
-                              if m.ok_dense is not None else None),
-                    ok_flat=(self._place(jnp.asarray(m.ok_flat), "cells")
-                             if m.ok_flat is not None else None),
-                    ref_cell=self._place(jnp.asarray(m.ref_cell), "rep"),
-                    son_oct=self._place(jnp.asarray(m.son_oct), "rep"),
-                )
+                with self.timers.section("regrid: maps upload"):
+                    self.dev[l] = dict(
+                        prev_dev[l],
+                        ok_dense=(self._place(jnp.asarray(m.ok_dense), "cells")
+                                  if m.ok_dense is not None else None),
+                        ok_flat=(self._place(jnp.asarray(m.ok_flat), "cells")
+                                 if m.ok_flat is not None else None),
+                        ref_cell=self._place(jnp.asarray(m.ref_cell), "rep"),
+                        son_oct=self._place(jnp.asarray(m.son_oct), "rep"),
+                    )
                 continue
             m = mapmod.build_level_maps(
                 self.tree, l, self.bc_kinds,
@@ -1050,36 +1051,39 @@ class AmrSim:
                 # on cubic levels (amr/bitperm.py) — no device index
                 # arrays needed; NON-cubic roots keep the index-gather
                 # conversion and ship the perm maps.
+                with self.timers.section("regrid: maps upload"):
+                    self.dev[l] = dict(
+                        ok_dense=(self._place(jnp.asarray(m.ok_dense), "cells")
+                                  if m.ok_dense is not None else None),
+                        ok_flat=(self._place(jnp.asarray(m.ok_flat), "cells")
+                                 if m.ok_flat is not None else None),
+                        ref_cell=self._place(jnp.asarray(m.ref_cell), "rep"),
+                        son_oct=self._place(jnp.asarray(m.son_oct), "rep"),
+                        valid_cell=self._place(jnp.asarray(valid_cell),
+                                               "cells"),
+                    )
+                    if not K.pow2_cube(self.tree.cell_dims(l)):
+                        self.dev[l].update(
+                            perm=self._place(jnp.asarray(m.perm), "cells"),
+                            inv_perm=self._place(jnp.asarray(m.inv_perm),
+                                                 "cells"))
+                continue
+            with self.timers.section("regrid: maps upload"):
                 self.dev[l] = dict(
-                    ok_dense=(self._place(jnp.asarray(m.ok_dense), "cells")
-                              if m.ok_dense is not None else None),
-                    ok_flat=(self._place(jnp.asarray(m.ok_flat), "cells")
-                             if m.ok_flat is not None else None),
+                    stencil_src=self._place(jnp.asarray(m.stencil_src),
+                                            "octs"),
+                    vsgn=(self._place(jnp.asarray(m.vsgn), "octs")
+                          if m.vsgn is not None else None),
+                    ok_ref=self._place(jnp.asarray(m.ok_ref), "octs"),
+                    interp_cell=self._place(jnp.asarray(m.interp_cell), "rep"),
+                    interp_nb=self._place(jnp.asarray(m.interp_nb), "rep"),
+                    interp_sgn=self._place(
+                        jnp.asarray(m.interp_sgn, dtype=self.dtype), "rep"),
+                    corr_idx=self._place(jnp.asarray(m.corr_idx), "rep"),
                     ref_cell=self._place(jnp.asarray(m.ref_cell), "rep"),
                     son_oct=self._place(jnp.asarray(m.son_oct), "rep"),
-                    valid_cell=self._place(jnp.asarray(valid_cell),
-                                           "cells"),
+                    valid_cell=self._place(jnp.asarray(valid_cell), "cells"),
                 )
-                if not K.pow2_cube(self.tree.cell_dims(l)):
-                    self.dev[l].update(
-                        perm=self._place(jnp.asarray(m.perm), "cells"),
-                        inv_perm=self._place(jnp.asarray(m.inv_perm),
-                                             "cells"))
-                continue
-            self.dev[l] = dict(
-                stencil_src=self._place(jnp.asarray(m.stencil_src), "octs"),
-                vsgn=(self._place(jnp.asarray(m.vsgn), "octs")
-                      if m.vsgn is not None else None),
-                ok_ref=self._place(jnp.asarray(m.ok_ref), "octs"),
-                interp_cell=self._place(jnp.asarray(m.interp_cell), "rep"),
-                interp_nb=self._place(jnp.asarray(m.interp_nb), "rep"),
-                interp_sgn=self._place(
-                    jnp.asarray(m.interp_sgn, dtype=self.dtype), "rep"),
-                corr_idx=self._place(jnp.asarray(m.corr_idx), "rep"),
-                ref_cell=self._place(jnp.asarray(m.ref_cell), "rep"),
-                son_oct=self._place(jnp.asarray(m.son_oct), "rep"),
-                valid_cell=self._place(jnp.asarray(valid_cell), "cells"),
-            )
             if self._block_level_ok(l):
                 b = mapmod.build_block_maps(
                     self.tree, l, self.bc_kinds,
@@ -1094,47 +1098,50 @@ class AmrSim:
                 self.block_stats["blocks_rebuilt"] += b.blocks_rebuilt
                 bt = (balance.apply_layout_blocks(b, lay_m1, lay_l)
                       if (lay_m1 is not None or lay_l is not None) else b)
-                self.dev[l].update(
-                    tile_src=self._place(jnp.asarray(bt.tile_src), "octs"),
-                    tile_vsgn=(self._place(jnp.asarray(bt.tile_vsgn),
-                                           "octs")
-                               if bt.tile_vsgn is not None else None),
-                    tile_ok=self._place(jnp.asarray(bt.tile_ok), "octs"),
-                    cell_tile=self._place(jnp.asarray(bt.cell_tile),
-                                          "cells"),
-                    cell_slot=self._place(jnp.asarray(bt.cell_slot),
-                                          "cells"),
-                    oct_tile=self._place(jnp.asarray(bt.oct_tile), "octs"),
-                    oct_slot=self._place(jnp.asarray(bt.oct_slot), "octs"),
-                    b_interp_cell=self._place(
-                        jnp.asarray(bt.interp_cell), "rep"),
-                    b_interp_nb=self._place(jnp.asarray(bt.interp_nb),
-                                            "rep"),
-                    b_interp_sgn=self._place(
-                        jnp.asarray(bt.interp_sgn, dtype=self.dtype),
-                        "rep"),
-                )
+                with self.timers.section("regrid: maps upload"):
+                    self.dev[l].update(
+                        tile_src=self._place(jnp.asarray(bt.tile_src),
+                                             "octs"),
+                        tile_vsgn=(self._place(jnp.asarray(bt.tile_vsgn),
+                                               "octs")
+                                   if bt.tile_vsgn is not None else None),
+                        tile_ok=self._place(jnp.asarray(bt.tile_ok), "octs"),
+                        cell_tile=self._place(jnp.asarray(bt.cell_tile),
+                                              "cells"),
+                        cell_slot=self._place(jnp.asarray(bt.cell_slot),
+                                              "cells"),
+                        oct_tile=self._place(jnp.asarray(bt.oct_tile), "octs"),
+                        oct_slot=self._place(jnp.asarray(bt.oct_slot), "octs"),
+                        b_interp_cell=self._place(
+                            jnp.asarray(bt.interp_cell), "rep"),
+                        b_interp_nb=self._place(jnp.asarray(bt.interp_nb),
+                                                "rep"),
+                        b_interp_sgn=self._place(
+                            jnp.asarray(bt.interp_sgn, dtype=self.dtype),
+                            "rep"),
+                    )
             if self.gravity:
                 g = mapmod.build_gravity_maps(self.tree, l, self.bc_kinds,
                                               noct_pad=m.noct_pad)
                 if lay_m1 is not None or lay_l is not None:
                     g = balance.apply_layout_gravity(g, lay_m1, lay_l)
-                self.dev[l].update(
-                    g_nb=self._place(jnp.asarray(g.nb), "cells"),
-                    g_cell=self._place(jnp.asarray(g.g_cell), "rep"),
-                    g_gnb=self._place(jnp.asarray(g.g_nb), "rep"),
-                    g_sgn=self._place(jnp.asarray(g.g_sgn), "rep"),
-                    g_octnb=self._place(jnp.asarray(g.oct_nb), "octs"),
-                    g_valid=self._place(jnp.asarray(g.valid_cell),
-                                        "cells"),
-                    # masked-multigrid ladder: the depth-0 parent map
-                    # is oct-row-sized (shards with the octs); deeper
-                    # lattices are genuinely small and replicate
-                    g_mg=tuple((self._place(jnp.asarray(nb_j), "rep"),
-                                self._place(jnp.asarray(par_j),
-                                            "octs" if j == 0 else "rep"))
-                               for j, (nb_j, par_j, _n)
-                               in enumerate(g.mg)))
+                with self.timers.section("regrid: maps upload"):
+                    self.dev[l].update(
+                        g_nb=self._place(jnp.asarray(g.nb), "cells"),
+                        g_cell=self._place(jnp.asarray(g.g_cell), "rep"),
+                        g_gnb=self._place(jnp.asarray(g.g_nb), "rep"),
+                        g_sgn=self._place(jnp.asarray(g.g_sgn), "rep"),
+                        g_octnb=self._place(jnp.asarray(g.oct_nb), "octs"),
+                        g_valid=self._place(jnp.asarray(g.valid_cell),
+                                            "cells"),
+                        # masked-multigrid ladder: the depth-0 parent map
+                        # is oct-row-sized (shards with the octs); deeper
+                        # lattices are genuinely small and replicate
+                        g_mg=tuple((self._place(jnp.asarray(nb_j), "rep"),
+                                    self._place(jnp.asarray(par_j),
+                                                "octs" if j == 0 else "rep"))
+                                   for j, (nb_j, par_j, _n)
+                                   in enumerate(g.mg)))
         # coverage telemetry: fraction of partial-level octs swept via
         # the blocked tile path (1.0 when every partial level is blocked
         # or there is none to block)
@@ -1250,12 +1257,15 @@ class AmrSim:
                   float(rr.err_grad_p))
             fls = (float(rr.floor_d), float(rr.floor_u),
                    float(rr.floor_p))
-            flags = jax.device_get(self._offload.criteria_flags_packed(
+            flags = self._offload.criteria_flags_packed(
                 self, spec, eg, fls,
-                int(self.params.refine.interpol_type), ttd))
+                int(self.params.refine.interpol_type), ttd)
         else:
-            flags = jax.device_get(_pack_flag_bits(
-                self._criteria_flags(spec), ttd))       # ONE trip
+            flags = _pack_flag_bits(self._criteria_flags(spec), ttd)
+        # the blocking fetch also waits out whatever the device still
+        # owes (the previous coarse step only dispatched): its own span
+        with self.timers.section("regrid: flag fetch"):
+            flags = jax.device_get(flags)               # ONE trip
         crit: Dict[int, np.ndarray] = {}
         for fl, l in zip(flags, spec.levels):
             m = self.maps[l]
@@ -1320,37 +1330,48 @@ class AmrSim:
         """Flag, rebuild the tree, and migrate device state
         (``flag_fine`` + ``refine_fine``/``kill_grid``,
         ``amr/refine_utils.f90:332,953``)."""
-        if self.lmax == self.lmin:
-            return
-        with self.timers.section("regrid: flag"):
-            newtree = self._flag_and_tree()
-        old_u = self.u
-        oldtree = self.tree
-        old_maps, old_dev = self.maps, self.dev
-        old_layouts = dict(self.layouts)
-        self.tree = newtree
-        with self.timers.section("regrid: balance"):
-            self._maybe_rebalance(oldtree)
+        with self.timers.section("regrid"):
+            if self.lmax == self.lmin:
+                return
+            with self.timers.section("regrid: flag"):
+                newtree = self._flag_and_tree()
+            old_u = self.u
+            oldtree = self.tree
+            old_maps, old_dev = self.maps, self.dev
+            old_layouts = dict(self.layouts)
+            self.tree = newtree
+            with self.timers.section("regrid: balance"):
+                self._maybe_rebalance(oldtree)
+            from ramses_tpu.parallel import balance
+            lay_range = range(self.lmin, self.lmax + 2)
+            unchanged = (all(self._keys_same(oldtree, l) for l in lay_range)
+                         and balance.layouts_same(old_layouts, self.layouts,
+                                                  lay_range))
+            if unchanged:
+                self.tree = oldtree
+                if getattr(self, "blocks", None):
+                    # steady-state regrid: tree untouched, every per-block
+                    # map stays live — zero blocks rebuilt
+                    self.block_stats = {
+                        "blocks_total": sum(b.ntile
+                                            for b in self.blocks.values()),
+                        "blocks_rebuilt": 0,
+                        "blocked_frac": self.block_stats.get(
+                            "blocked_frac", 1.0)}
+                return
+            with self.timers.section("regrid: maps"):
+                self._rebuild_maps(oldtree, old_maps, old_dev)
+            with self.timers.section("regrid: migrate"):
+                self._migrate(oldtree, old_u, old_layouts)
+            with self.timers.section("regrid: restrict"):
+                self._restrict_all()
+            self._dt_cache = None          # u changed: stale CFL dt
+
+    def _migrate(self, oldtree, old_u, old_layouts):
+        """Move the level state onto the new tree (``self.tree`` /
+        ``self.maps``): survivors copied, new octs prolonged from the
+        level below, stale gravity state pruned."""
         from ramses_tpu.parallel import balance
-        lay_range = range(self.lmin, self.lmax + 2)
-        unchanged = (all(self._keys_same(oldtree, l) for l in lay_range)
-                     and balance.layouts_same(old_layouts, self.layouts,
-                                              lay_range))
-        if unchanged:
-            self.tree = oldtree
-            if getattr(self, "blocks", None):
-                # steady-state regrid: tree untouched, every per-block
-                # map stays live — zero blocks rebuilt
-                self.block_stats = {
-                    "blocks_total": sum(b.ntile
-                                        for b in self.blocks.values()),
-                    "blocks_rebuilt": 0,
-                    "blocked_frac": self.block_stats.get(
-                        "blocked_frac", 1.0)}
-            return
-        with self.timers.section("regrid: maps"):
-            self._rebuild_maps(oldtree, old_maps, old_dev)
-        self.timers.timer("regrid: migrate")
         twotondim = 2 ** self.cfg.ndim
         offs, sgn_tab, oct_ar = _mig_consts(self.cfg.ndim)
         self._mig_log = {}
@@ -1486,10 +1507,6 @@ class AmrSim:
                 self.fg.pop(l, None)
                 self.poisson_iters.pop(l, None)
                 self._rho_dev.pop(l, None)
-        self.timers.stop()
-        with self.timers.section("regrid: upload"):
-            self._restrict_all()
-        self._dt_cache = None          # u changed: stale CFL dt
 
     def _restrict_all(self):
         """Restriction sweep fine→coarse so non-leaf cells hold son means."""
@@ -1912,12 +1929,16 @@ class AmrSim:
                 u, t, dtn, ndone = out
             self.u = u
             self._dt_cache = dtn
-        self.t = float(t)
-        n = int(ndone)
+        # the program above was only dispatched: these fetches block
+        # until the device has run it
+        with self.timers.section("evolve: wait"):
+            self.t = float(t)
+            n = int(ndone)
+            self.dt_old = float(dtn)
+            if trace:
+                ts, dts = jax.device_get(hist)
         self.nstep += n
-        self.dt_old = float(dtn)
         if trace:
-            ts, dts = jax.device_get(hist)
             return n, (ts[:n], dts[:n])
         return n
 

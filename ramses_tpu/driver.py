@@ -27,6 +27,7 @@ from ramses_tpu.pm.particles import ParticleSet
 from ramses_tpu.poisson.coupling import GravitySpec, gravity_field
 from ramses_tpu.telemetry import make_telemetry, sim_run_info
 from ramses_tpu.telemetry import screen as telemetry_screen
+from ramses_tpu.utils.timers import NullTimers, Timers
 
 
 @dataclass
@@ -239,6 +240,7 @@ class Simulation:
         # structured run telemetry (&OUTPUT_PARAMS telemetry=; the
         # shared no-op NULL when off — zero-overhead contract)
         self.telemetry = make_telemetry(params)
+        self.timers = Timers() if self.telemetry.enabled else NullTimers()
         # in-run fault recovery (&RUN_PARAMS max_step_retries) + the
         # deterministic fault-injection harness (fault_inject)
         from ramses_tpu.resilience.faultinject import FaultInjector
@@ -289,124 +291,135 @@ class Simulation:
             # time, so a relative factor on tout would flip direction
             ttol = 1e-12 * (abs(tout) + 1.0)
             while st.t < tout - ttol and st.nstep < nstepmax:
-                if guard is not None and not guard.check():
-                    return st
-                n = min(chunk, nstepmax - st.nstep)
-                if self.movie is not None:
-                    # fused chunks may not run past the movie cadence
-                    # (frames sample at chunk boundaries)
-                    n = min(n, self.movie_imov)
-                if self._fault is not None:
-                    # pending step-indexed faults must land exactly at
-                    # their target step, not at a chunk boundary
-                    n = self._fault.clamp_window(int(st.nstep), n)
-                t_before = st.t
-                if self.rt is not None and self.params.run.static:
-                    # frozen gas: pure RT evolution to the output time
-                    # (the reference's static Stromgren tests)
-                    st.u = self.rt.advance(st.u, tout - st.t)
-                    st.t = tout
-                    st.nstep += 1
+                with self.timers.section("evolve"):
+                    if guard is not None and not guard.check():
+                        return st
+                    n = min(chunk, nstepmax - st.nstep)
+                    if self.movie is not None:
+                        # fused chunks may not run past the movie cadence
+                        # (frames sample at chunk boundaries)
+                        n = min(n, self.movie_imov)
+                    if self._fault is not None:
+                        # pending step-indexed faults must land exactly at
+                        # their target step, not at a chunk boundary
+                        n = self._fault.clamp_window(int(st.nstep), n)
+                    t_before = st.t
+                    if self.rt is not None and self.params.run.static:
+                        # frozen gas: pure RT evolution to the output time
+                        # (the reference's static Stromgren tests)
+                        st.u = self.rt.advance(st.u, tout - st.t)
+                        st.t = tout
+                        st.nstep += 1
+                        if self.movie is not None \
+                                and st.nstep >= self._movie_next:
+                            self.movie.emit(self)
+                            self._movie_next = st.nstep + self.movie_imov
+                        continue
+                    # redo-step guard: on the plain-hydro dispatch (no
+                    # donation — these are live references, not copies) the
+                    # pre-step state is retained so a non-finite window can
+                    # roll back; pm/cool scans expose no dt_scale hook and
+                    # rely on OpsGuard's trap instead
+                    plain = not (self.pspec.enabled or self.gspec.enabled
+                                 or self.cosmo is not None
+                                 or self.cool_tables is not None)
+                    prev = ((st.u, st.t, st.nstep, st.dt_old)
+                            if self._sguard is not None and plain else None)
+                    if self._fault is not None:
+                        self._fault.maybe_nan(self)
+                    t0 = time.perf_counter()
+                    # the whole dispatch + blocking fetch runs under the
+                    # step deadline (first window: compile deadline) —
+                    # nullcontext when the watchdog is off keeps this path
+                    # fetch-identical to the unguarded one
+                    with (self._wd.guard("step") if self._wd is not None
+                            else nullcontext()):
+                        if self._fault is not None:
+                            self._fault.maybe_hang(int(st.nstep))
+                        with self.timers.section("evolve: dispatch"):
+                            u, t, ndone, dt_old, hist = self._dispatch(
+                                n, tout, tdtype)
+                        # only dispatched so far: these block until the
+                        # device has run the window
+                        with self.timers.section("evolve: wait"):
+                            u.block_until_ready()
+                            ndone = int(ndone)
+                            t = float(t)
+                            if dt_old is not None:
+                                st.dt_old = float(dt_old)
+                    wall = time.perf_counter() - t0
+                    self.wall_s += wall
+                    st.u, st.t, st.nstep = u, t, st.nstep + ndone
+                    if self._wd is not None:
+                        self._wd.note(nstep=st.nstep, t=st.t)
+                    self.cell_updates += ndone * self.grid.ncell
+                    if prev is not None and not self._sguard.ok(st.t):
+                        # non-finite window: roll back and redo at halved
+                        # dt (raises StepRetryExhausted after the ladder)
+                        ndone = self._retry_window(prev, tout, tdtype)
+                        hist = None
+                    if telem.enabled and ndone:
+                        if hist is not None:
+                            ts, dts = jax.device_get(hist)
+                            telem.record_chunk(self, ts[:ndone], dts[:ndone],
+                                               ndone, wall,
+                                               nstep_end=st.nstep)
+                        else:
+                            # pm/cool scans don't expose per-step history:
+                            # one aggregate record per dispatch
+                            telem.record_step(
+                                self, dt=(st.t - t_before) / ndone,
+                                wall_s=wall, steps=ndone, t=st.t,
+                                nstep=st.nstep, chunked=ndone)
+                    self._source_passes(st.t - t_before)
+                    if self.rt is not None and st.t > t_before:
+                        st.u = self.rt.advance(st.u, st.t - t_before)
                     if self.movie is not None \
                             and st.nstep >= self._movie_next:
                         self.movie.emit(self)
                         self._movie_next = st.nstep + self.movie_imov
-                    continue
-                # redo-step guard: on the plain-hydro dispatch (no
-                # donation — these are live references, not copies) the
-                # pre-step state is retained so a non-finite window can
-                # roll back; pm/cool scans expose no dt_scale hook and
-                # rely on OpsGuard's trap instead
-                plain = not (self.pspec.enabled or self.gspec.enabled
-                             or self.cosmo is not None
-                             or self.cool_tables is not None)
-                prev = ((st.u, st.t, st.nstep, st.dt_old)
-                        if self._sguard is not None and plain else None)
-                if self._fault is not None:
-                    self._fault.maybe_nan(self)
-                t0 = time.perf_counter()
-                hist = None
-                # the whole dispatch + blocking fetch runs under the
-                # step deadline (first window: compile deadline) —
-                # nullcontext when the watchdog is off keeps this path
-                # fetch-identical to the unguarded one
-                with (self._wd.guard("step") if self._wd is not None
-                        else nullcontext()):
-                    if self._fault is not None:
-                        self._fault.maybe_hang(int(st.nstep))
-                    if (self.pspec.enabled or self.gspec.enabled
-                            or self.cosmo is not None):
-                        u, st.p, st.f, t, dt_old, ndone = run_steps_pm(
-                            self.grid, self.gspec, self.pspec, st.u,
-                            st.p, st.f, jnp.asarray(st.t, tdtype),
-                            jnp.asarray(tout, tdtype),
-                            jnp.asarray(st.dt_old, tdtype), n,
-                            cosmo=self.cosmo)
-                        st.dt_old = float(dt_old)
-                    elif self.cool_tables is not None:
-                        from ramses_tpu.grid.uniform import run_steps_cool
-                        u, t, ndone = run_steps_cool(
-                            self.grid, st.u, jnp.asarray(st.t, tdtype),
-                            jnp.asarray(tout, tdtype), n,
-                            self.cool_tables, self.cool_spec)
-                    elif telem.enabled:
-                        # instrumented run: the scan additionally stacks
-                        # per-step (t, dt) so the event log gets one
-                        # record per coarse step from this single
-                        # summary fetch — the chunk stays one device
-                        # program
-                        u, t, ndone, hist = run_steps(
-                            self.grid, st.u, jnp.asarray(st.t, tdtype),
-                            jnp.asarray(tout, tdtype), n, trace=True)
-                    else:
-                        u, t, ndone = run_steps(
-                            self.grid, st.u, jnp.asarray(st.t, tdtype),
-                            jnp.asarray(tout, tdtype), n)
-                    u.block_until_ready()
-                    ndone = int(ndone)
-                wall = time.perf_counter() - t0
-                self.wall_s += wall
-                st.u, st.t, st.nstep = u, float(t), st.nstep + ndone
-                if self._wd is not None:
-                    self._wd.note(nstep=st.nstep, t=st.t)
-                self.cell_updates += ndone * self.grid.ncell
-                if prev is not None and not self._sguard.ok(st.t):
-                    # non-finite window: roll back and redo at halved
-                    # dt (raises StepRetryExhausted after the ladder)
-                    ndone = self._retry_window(prev, tout, tdtype)
-                    hist = None
-                if telem.enabled and ndone:
-                    if hist is not None:
-                        ts, dts = jax.device_get(hist)
-                        telem.record_chunk(self, ts[:ndone], dts[:ndone],
-                                           ndone, wall,
-                                           nstep_end=st.nstep)
-                    else:
-                        # pm/cool scans don't expose per-step history:
-                        # one aggregate record per dispatch
-                        telem.record_step(
-                            self, dt=(st.t - t_before) / ndone,
-                            wall_s=wall, steps=ndone, t=st.t,
-                            nstep=st.nstep, chunked=ndone)
-                self._source_passes(st.t - t_before)
-                if self.rt is not None and st.t > t_before:
-                    st.u = self.rt.advance(st.u, st.t - t_before)
-                if self.movie is not None \
-                        and st.nstep >= self._movie_next:
-                    self.movie.emit(self)
-                    self._movie_next = st.nstep + self.movie_imov
-                if verbose:
-                    print(telemetry_screen.step_line(
-                        self, dt=((st.t - t_before) / ndone
-                                  if ndone else None), chunk=ndone))
-                if ndone == 0:
-                    break
+                    if verbose:
+                        print(telemetry_screen.step_line(
+                            self, dt=((st.t - t_before) / ndone
+                                      if ndone else None), chunk=ndone))
+                    if ndone == 0:
+                        break
             if st.t < tout - ttol:
                 break  # budget exhausted before this output time: no dump
             if self.on_output is not None:
                 self.on_output(self, st.iout)
             st.iout += 1
         return st
+
+    def _dispatch(self, n: int, tout: float, tdtype):
+        """Dispatch one fused window of up to ``n`` steps towards
+        ``tout``; nothing here waits for the device.  Returns ``(u, t,
+        ndone, dt_old, hist)``: ``dt_old`` only from the pm scan,
+        ``hist`` (stacked per-step ``(t, dt)``) only when telemetry is
+        on, else None."""
+        st = self.state
+        t0, t1 = jnp.asarray(st.t, tdtype), jnp.asarray(tout, tdtype)
+        if (self.pspec.enabled or self.gspec.enabled
+                or self.cosmo is not None):
+            u, st.p, st.f, t, dt_old, ndone = run_steps_pm(
+                self.grid, self.gspec, self.pspec, st.u, st.p, st.f, t0,
+                t1, jnp.asarray(st.dt_old, tdtype), n, cosmo=self.cosmo)
+            return u, t, ndone, dt_old, None
+        if self.cool_tables is not None:
+            from ramses_tpu.grid.uniform import run_steps_cool
+            u, t, ndone = run_steps_cool(self.grid, st.u, t0, t1, n,
+                                         self.cool_tables, self.cool_spec)
+            return u, t, ndone, None, None
+        if self.telemetry.enabled:
+            # instrumented run: the scan additionally stacks per-step
+            # (t, dt) so the event log gets one record per coarse step
+            # from this single summary fetch — the chunk stays one
+            # device program
+            u, t, ndone, hist = run_steps(self.grid, st.u, t0, t1, n,
+                                          trace=True)
+            return u, t, ndone, None, hist
+        u, t, ndone = run_steps(self.grid, st.u, t0, t1, n)
+        return u, t, ndone, None, None
 
     def _source_passes(self, dt_chunk: float):
         """Coarse-step-cadence source terms: star formation, SN feedback,

@@ -1,18 +1,94 @@
-"""Label-based wallclock timers (``timer_m``, ``amr/update_time.f90:38-56``).
+"""Label-based wallclock timers (``timer_m``, ``amr/update_time.f90:38-56``)
+whose sections are spans on the profiler's clock.
 
 Same zero-overhead design as the reference: exactly one label is active;
 switching to a new label accumulates the elapsed time on the previous
 one.  ``output_timer`` prints the per-label breakdown and the fraction of
-total — the reference's per-dump report (``:77-180``).  For deep kernel
-profiles use ``jax.profiler`` (wired in ``profile_trace``); these timers
-give the host-side phase accounting.
+total — the reference's per-dump report (``:77-180``).
+
+A ``section`` is also a :func:`span`: a ``jax.profiler.TraceAnnotation``
+of the same label, so any profiler session (``profile_trace``, the
+on-demand captures of ``obs/profile.py``, the benchmark's traced runs)
+shows the host phase beside the device's ops on one clock, and — while
+such a session is on, or the section belongs to a real :class:`Timers` —
+one closed record in a process-wide ring (:func:`span_records`).  With
+no session and no telemetry a section reads no clock and stores nothing.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import threading
 import time
-from typing import Dict, Optional
+from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+from ramses_tpu import platform
+
+# closed spans, oldest first (children close, and so land, before their
+# parents); bounded: a long instrumented run keeps the newest (~1000
+# AMR coarse steps, a few MB)
+_RING: collections.deque = collections.deque(maxlen=1 << 14)
+# labels of the recorded spans open on this thread, outermost first
+_OPEN = threading.local()
+
+
+def span(label: str, timers: Optional["Timers"] = None):
+    """One host phase, as a context manager: a ``TraceAnnotation`` and,
+    only while a profiler session is on (asked here, at entry) or
+    ``timers`` is a live :class:`Timers`, a closed record in the ring.
+    Off is the bare annotation: no clock read, nothing stored."""
+    traced = TraceAnnotation.is_enabled()
+    if not traced and timers is None:
+        return TraceAnnotation(label)
+    return _recorded(label, timers, traced)
+
+
+@contextlib.contextmanager
+def _recorded(label: str, timers: Optional["Timers"], traced: bool):
+    """The recording half of :func:`span`: ``{name, parent, depth, t0_ns,
+    t1_ns, compiles, compile_s, traced}`` on ``time.perf_counter_ns`` —
+    ``parent`` the enclosing recorded span's label, ``compiles`` /
+    ``compile_s`` what the compile timer (``platform._CACHE_STATS``:
+    compile or cache load) counted across it, ``traced`` whether it
+    opened under a session.  ``timers`` also gets its label switched
+    for the span's life (self time per label)."""
+    stack = _OPEN.__dict__.setdefault("stack", [])
+    parent = stack[-1] if stack else None
+    depth = len(stack)
+    stats = platform._CACHE_STATS
+    ncomp, comp_s = stats["compiles"], stats["compile_s"]
+    with TraceAnnotation(label):
+        stack.append(label)
+        if timers is not None:
+            prev = timers._label
+            timers.timer(label)
+        t0 = time.perf_counter_ns()
+        try:
+            yield
+        finally:
+            if timers is not None:
+                timers.timer(prev if prev is not None else "stop")
+            t1 = time.perf_counter_ns()
+            stack.pop()
+            _RING.append({
+                "name": label, "parent": parent, "depth": depth,
+                "t0_ns": t0, "t1_ns": t1,
+                "compiles": stats["compiles"] - ncomp,
+                "compile_s": stats["compile_s"] - comp_s,
+                "traced": traced})
+
+
+def span_records() -> List[dict]:
+    """The ring's closed spans, oldest first; ``traced`` marks those
+    opened under a profiler session."""
+    return list(_RING)
+
+
+def clear_span_records():
+    _RING.clear()
 
 
 class Timers:
@@ -54,14 +130,8 @@ class Timers:
                 + (time.perf_counter() - self._t0)
         return out
 
-    @contextlib.contextmanager
     def section(self, label: str):
-        prev = self._label
-        self.timer(label)
-        try:
-            yield
-        finally:
-            self.timer(prev if prev is not None else "stop")
+        return span(label, self)
 
     def output_timer(self, file=None) -> str:
         """Per-label breakdown (``output_timer``, min/avg/max collapse to
@@ -87,26 +157,20 @@ class NullTimers(Timers):
     The reference's timers are compiled in unconditionally; here a run
     without telemetry must pay NOTHING — no ``perf_counter`` calls, no
     label switches (the telemetry subsystem's zero-overhead-off
-    contract).  Drivers swap in a real :class:`Timers` only when
-    telemetry (or an explicit instrumentation pass, e.g. bench.py's
-    ``Timers(sync=sim.drain)``) asks for it.
+    contract): a section is a bare :func:`span`, which records only
+    while a profiler session is on.  Drivers swap in a real
+    :class:`Timers` only when telemetry (or an explicit instrumentation
+    pass, e.g. bench.py's ``Timers(sync=sim.drain)``) asks for it.
     """
 
     def timer(self, label: str):
         pass
 
-    @contextlib.contextmanager
     def section(self, label: str):
-        yield
+        return span(label)
 
     def snapshot(self) -> Dict[str, float]:
         return {}
-
-
-GLOBAL = Timers()
-timer = GLOBAL.timer
-section = GLOBAL.section
-output_timer = GLOBAL.output_timer
 
 
 @contextlib.contextmanager

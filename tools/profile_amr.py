@@ -8,7 +8,7 @@ isolation, at the exact live shapes of the bench configuration
 (sedov3d levelmin=7 levelmax=9 by default), plus the candidate
 conversions (index-gather vs bit-permutation transpose) side by side,
 the blocked Morton-tile sweep vs the 6^3 stencil sweep, the regrid
-sub-phases (flag/maps/migrate/upload), and the static HLO
+sub-phases (flag/maps/migrate/restrict), and the static HLO
 gather-element inventory of the fused step.
 
 Results land in a machine-readable JSON file (``PROF_JSON``, default
@@ -311,7 +311,7 @@ def collect(hb=lambda *a, **k: None, emit=None):
     probe("fused_courant", p_courant)
 
     def p_regrid():
-        # regrid sub-phases (flag/maps/migrate/upload): instrumented
+        # regrid sub-phases (flag/maps/migrate/restrict): instrumented
         # timers with a device drain at each section switch, plus the
         # incremental-rebuild counters — steady state (unchanged tree)
         # must rebuild ZERO per-block maps
@@ -324,7 +324,7 @@ def collect(hb=lambda *a, **k: None, emit=None):
             k: round(v, 4) for k, v in sim.timers.acc.items()
             if k.startswith("regrid")}
         # the steady-state loop above short-circuits after balance, so
-        # maps/migrate/upload come from the growth-phase accumulator
+        # maps/migrate/restrict come from the growth-phase accumulator
         # captured during the warm-up evolve (changed-tree regrids)
         res["regrid_phase_growth_s"] = {
             k: round(v, 4) for k, v in state["growth_acc"].items()
