@@ -977,12 +977,36 @@ class AmrSim:
             return False
         return True
 
+    def _reads_stencil(self, l: int) -> bool:
+        """Will anything that runs read PARTIAL level ``l``'s 6^d
+        per-oct tables (``LevelMaps.stencil_src`` and company)?  The
+        stencil sweep and flags do wherever the tile path is not
+        taken; explicit comm schedules are cut from them
+        (``amr_comm.build_sweep_comm`` — asked of the constructor's
+        flag, since the first build precedes the schedules
+        ``_block_level_ok`` looks at); the RT transport gathers its
+        partial-level rows through them (``rt/amr.py`` — asked of the
+        namelist, since ``rt_amr`` is attached once the first maps
+        exist)."""
+        return (not self._block_level_ok(l)
+                or bool(getattr(self, "_explicit_comm", False))
+                or (bool(self.params.run.rt)
+                    and self._pm_family(self.cfg)))
+
     def _rebuild_maps(self, old_tree: Optional[Octree] = None,
                       old_maps: Optional[dict] = None,
                       old_dev: Optional[dict] = None):
         """(Re)build per-level index maps, reusing cached maps for levels
         whose (l-1, l, l+1) oct sets are unchanged — the ``build_comm``
-        amortization: steady-state steps do no host map construction."""
+        amortization: steady-state steps do no host map construction.
+
+        A partial level gets the 6^d per-oct stencil tables
+        (``stencil_src``, ``vsgn``, ``ok_ref``, ``interp_*`` in
+        ``maps[l]`` and ``dev[l]``) only where :meth:`_reads_stencil`
+        says something reads them; a level on the tile path has the
+        ``tile_*`` / ``b_interp_*`` tables instead, and its ``dev[l]``
+        has no stencil keys.  ``block_stats["stencil_octs_built"]``
+        counts the octs whose 6^d tables this call built."""
         from ramses_tpu.parallel import balance
         prev_maps = old_maps or {}
         prev_dev = old_dev or {}
@@ -992,7 +1016,8 @@ class AmrSim:
         self.maps: Dict[int, mapmod.LevelMaps] = {}
         self.dev: Dict[int, dict] = {}
         self.blocks: Dict[int, mapmod.BlockMaps] = {}
-        self.block_stats = {"blocks_total": 0, "blocks_rebuilt": 0}
+        self.block_stats = {"blocks_total": 0, "blocks_rebuilt": 0,
+                            "stencil_octs_built": 0}
         self._built_lay = {}
         for l in range(self.lmin, self.lmax + 1):
             if not self.tree.has(l):
@@ -1037,7 +1062,8 @@ class AmrSim:
                 continue
             m = mapmod.build_level_maps(
                 self.tree, l, self.bc_kinds,
-                noct_pad=self._noct_pad(l, self.tree.noct(l)))
+                noct_pad=self._noct_pad(l, self.tree.noct(l)),
+                stencil=self._reads_stencil(l))
             lay_m1, lay_l, lay_p1 = (self.layouts.get(l - 1),
                                      self.layouts.get(l),
                                      self.layouts.get(l + 1))
@@ -1070,20 +1096,27 @@ class AmrSim:
                 continue
             with self.timers.section("regrid: maps upload"):
                 self.dev[l] = dict(
-                    stencil_src=self._place(jnp.asarray(m.stencil_src),
-                                            "octs"),
-                    vsgn=(self._place(jnp.asarray(m.vsgn), "octs")
-                          if m.vsgn is not None else None),
-                    ok_ref=self._place(jnp.asarray(m.ok_ref), "octs"),
-                    interp_cell=self._place(jnp.asarray(m.interp_cell), "rep"),
-                    interp_nb=self._place(jnp.asarray(m.interp_nb), "rep"),
-                    interp_sgn=self._place(
-                        jnp.asarray(m.interp_sgn, dtype=self.dtype), "rep"),
                     corr_idx=self._place(jnp.asarray(m.corr_idx), "rep"),
                     ref_cell=self._place(jnp.asarray(m.ref_cell), "rep"),
                     son_oct=self._place(jnp.asarray(m.son_oct), "rep"),
                     valid_cell=self._place(jnp.asarray(valid_cell), "cells"),
                 )
+                if m.has_stencil:
+                    self.block_stats["stencil_octs_built"] += m.noct
+                    self.dev[l].update(
+                        stencil_src=self._place(jnp.asarray(m.stencil_src),
+                                                "octs"),
+                        vsgn=(self._place(jnp.asarray(m.vsgn), "octs")
+                              if m.vsgn is not None else None),
+                        ok_ref=self._place(jnp.asarray(m.ok_ref), "octs"),
+                        interp_cell=self._place(jnp.asarray(m.interp_cell),
+                                                "rep"),
+                        interp_nb=self._place(jnp.asarray(m.interp_nb),
+                                              "rep"),
+                        interp_sgn=self._place(
+                            jnp.asarray(m.interp_sgn, dtype=self.dtype),
+                            "rep"),
+                    )
             if self._block_level_ok(l):
                 b = mapmod.build_block_maps(
                     self.tree, l, self.bc_kinds,
@@ -1349,15 +1382,10 @@ class AmrSim:
                                                   lay_range))
             if unchanged:
                 self.tree = oldtree
-                if getattr(self, "blocks", None):
-                    # steady-state regrid: tree untouched, every per-block
-                    # map stays live — zero blocks rebuilt
-                    self.block_stats = {
-                        "blocks_total": sum(b.ntile
-                                            for b in self.blocks.values()),
-                        "blocks_rebuilt": 0,
-                        "blocked_frac": self.block_stats.get(
-                            "blocked_frac", 1.0)}
+                # steady-state regrid: tree untouched, every table
+                # stays live — nothing rebuilt
+                self.block_stats = dict(self.block_stats, blocks_rebuilt=0,
+                                        stencil_octs_built=0)
                 return
             with self.timers.section("regrid: maps"):
                 self._rebuild_maps(oldtree, old_maps, old_dev)
@@ -1525,15 +1553,6 @@ class AmrSim:
     # ------------------------------------------------------------------
     # time stepping
     # ------------------------------------------------------------------
-    def _interp_for(self, l: int) -> jnp.ndarray:
-        d = self.dev[l]
-        if l == self.lmin:
-            return jnp.zeros((self.maps[l].ni_pad, self.cfg.nvar),
-                             self.dtype)
-        return K.interp_cells(self.u[l - 1], d["interp_cell"],
-                              d["interp_nb"], d["interp_sgn"], self.cfg,
-                              itype=int(self.params.refine.interpol_type))
-
     def _fused_spec(self) -> FusedSpec:
         if self._spec is None:
             lv = tuple(self.levels())

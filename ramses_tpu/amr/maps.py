@@ -32,7 +32,16 @@ def bucket(n: int, lo: int = 16) -> int:
 
 @dataclass
 class LevelMaps:
-    """All index maps of one level (numpy; hierarchy moves them to device)."""
+    """All index maps of one level (numpy; hierarchy moves them to device).
+
+    The 6^d per-oct stencil tables (``stencil_src``, ``vsgn``, ``ok_ref``
+    and the ``interp_*`` requests of their missing cells) exist only
+    where something reads them: a partial level built with
+    ``build_level_maps(stencil=True)``, i.e. one whose sweep and flags
+    run the stencil formulation (``oct_blocking=.false.``, explicit comm
+    schedules) or whose radiation transport gathers through it.  A
+    level swept through the Morton tile tables (:class:`BlockMaps`) and
+    a COMPLETE level carry them empty (``_no_stencil``: ``ni == 0``)."""
     lvl: int
     noct: int
     noct_pad: int
@@ -40,10 +49,11 @@ class LevelMaps:
     ni_pad: int
     # gather: src row for each stencil cell, into
     # concat(cells [ncell_pad], interp [ni_pad], trash [1])
-    stencil_src: np.ndarray          # [noct_pad, 6^d] int32
+    stencil_src: np.ndarray          # [noct_pad, 6^d] int32, or [0, 0]
     vsgn: Optional[np.ndarray]       # [noct_pad, 6^d] uint8 bitmask, or None
     ok_ref: np.ndarray               # [noct_pad, 6^d] bool: cell refined
-    # interpolation requests (absent at levelmin: ni=0)
+    # interpolation requests of the stencil's missing cells (ni=0 at
+    # levelmin and wherever the stencil tables are not built)
     interp_cell: np.ndarray          # [ni_pad] int32 flat cell idx at lvl-1
     interp_nb: np.ndarray            # [ni_pad, ndim, 2] int32 (left,right)
     interp_sgn: np.ndarray           # [ni_pad, ndim] int8 (±1 child offset)
@@ -65,6 +75,10 @@ class LevelMaps:
     # same mask in FLAT row order (shardable over contiguous row chunks
     # for the slab-sharded dense path, parallel/dense_slab.py)
     ok_flat: Optional[np.ndarray] = None   # [ncell] bool refined, flat order
+
+    @property
+    def has_stencil(self) -> bool:
+        return self.stencil_src.size > 0
 
     @property
     def ndim(self) -> int:
@@ -158,16 +172,27 @@ def _interp_requests(tree: Octree, lvl: int, uniq_keys: np.ndarray,
     return interp_cell, interp_nb, interp_sgn
 
 
-def build_level_maps(tree: Octree, lvl: int, bc_kinds: List[tuple],
-                     noct_pad: Optional[int] = None) -> LevelMaps:
+def _no_stencil(ndim: int) -> dict:
+    """The stencil fields of a level that has no 6^d tables."""
+    return dict(
+        ni=0, ni_pad=8,
+        stencil_src=np.zeros((0, 0), dtype=np.int32), vsgn=None,
+        ok_ref=np.zeros((0, 0), dtype=bool),
+        interp_cell=np.zeros(8, dtype=np.int32),
+        interp_nb=np.zeros((8, ndim, 2), dtype=np.int32),
+        interp_sgn=np.ones((8, ndim), dtype=np.int8))
+
+
+def _stencil_tables(tree: Octree, lvl: int, bc_kinds: List[tuple],
+                    noct_pad: int) -> dict:
+    """The 6^d per-oct stencil fields of a partial level: gather rows,
+    refined mask, reflecting-wall sign bits and the interpolation
+    requests of the stencil cells the level lacks."""
     ndim = tree.ndim
     twotondim = 1 << ndim
     lev = tree.levels[lvl]
     noct = lev.noct
-    noct_pad = noct_pad or bucket(noct)
     ncell_pad = noct_pad * twotondim
-    if noct == int(np.prod(tree.oct_dims(lvl))):
-        return _build_complete_level_maps(tree, lvl, noct, noct_pad)
     soff = stencil_offsets(ndim)                       # [6^d, ndim]
     ns = len(soff)
 
@@ -227,14 +252,28 @@ def build_level_maps(tree: Octree, lvl: int, bc_kinds: List[tuple],
     else:
         vsgn = None
 
-    # pad interp arrays
-    def _pad(a, n, fill=0):
-        out = np.full((n,) + a.shape[1:], fill, dtype=a.dtype)
-        out[:len(a)] = a
-        return out
-    interp_cell = _pad(interp_cell, ni_pad)
-    interp_nb = _pad(interp_nb, ni_pad)
-    interp_sgn = _pad(interp_sgn, ni_pad, 1)
+    return dict(ni=ni, ni_pad=ni_pad, stencil_src=stencil_src, vsgn=vsgn,
+                ok_ref=ok_ref, interp_cell=_pad_rows(interp_cell, ni_pad),
+                interp_nb=_pad_rows(interp_nb, ni_pad),
+                interp_sgn=_pad_rows(interp_sgn, ni_pad, 1))
+
+
+def build_level_maps(tree: Octree, lvl: int, bc_kinds: List[tuple],
+                     noct_pad: Optional[int] = None,
+                     stencil: bool = True) -> LevelMaps:
+    """Index maps of level ``lvl``.  ``stencil=False`` leaves out the
+    6^d per-oct tables of a partial level (216 lookups an oct in 3D, by
+    far the larger part of the build): for a level whose sweep and
+    flags read the tile tables of :func:`build_block_maps` instead."""
+    ndim = tree.ndim
+    twotondim = 1 << ndim
+    lev = tree.levels[lvl]
+    noct = lev.noct
+    noct_pad = noct_pad or bucket(noct)
+    if noct == int(np.prod(tree.oct_dims(lvl))):
+        return _build_complete_level_maps(tree, lvl, noct, noct_pad)
+    sten = (_stencil_tables(tree, lvl, bc_kinds, noct_pad) if stencil
+            else _no_stencil(ndim))
 
     # --- coarse flux-correction targets ---
     corr_idx = np.full((noct_pad, ndim, 2), -1, dtype=np.int32)
@@ -270,13 +309,10 @@ def build_level_maps(tree: Octree, lvl: int, bc_kinds: List[tuple],
     valid_oct = np.zeros(noct_pad, dtype=bool)
     valid_oct[:noct] = True
 
-    return LevelMaps(lvl=lvl, noct=noct, noct_pad=noct_pad, ni=ni,
-                     ni_pad=ni_pad, stencil_src=stencil_src, vsgn=vsgn,
-                     ok_ref=ok_ref, interp_cell=interp_cell,
-                     interp_nb=interp_nb, interp_sgn=interp_sgn,
+    return LevelMaps(lvl=lvl, noct=noct, noct_pad=noct_pad,
                      corr_idx=corr_idx, nref=nref, nref_pad=nref_pad,
                      ref_cell=ref_cell, son_oct=son_oct,
-                     valid_oct=valid_oct)
+                     valid_oct=valid_oct, **sten)
 
 
 def _build_complete_level_maps(tree: Octree, lvl: int, noct: int,
@@ -307,12 +343,7 @@ def _build_complete_level_maps(tree: Octree, lvl: int, noct: int,
     valid_oct = np.zeros(noct_pad, dtype=bool)
     valid_oct[:noct] = True
     return LevelMaps(
-        lvl=lvl, noct=noct, noct_pad=noct_pad, ni=0, ni_pad=8,
-        stencil_src=np.zeros((0, 0), dtype=np.int32), vsgn=None,
-        ok_ref=np.zeros((0, 0), dtype=bool),
-        interp_cell=np.zeros(8, dtype=np.int32),
-        interp_nb=np.zeros((8, ndim, 2), dtype=np.int32),
-        interp_sgn=np.ones((8, ndim), dtype=np.int8),
+        lvl=lvl, noct=noct, noct_pad=noct_pad, **_no_stencil(ndim),
         corr_idx=np.full((noct_pad, ndim, 2), -1, dtype=np.int32),
         nref=nref, nref_pad=nref_pad, ref_cell=ref_cell, son_oct=son_oct,
         valid_oct=valid_oct, complete=True,

@@ -380,14 +380,15 @@ def apply_layout_level(m, lay_m1: Optional[LevelLayout],
     if lay is not None:
         assert lay.noct == m.noct and lay.noct_pad == m.noct_pad, \
             f"layout/maps mismatch at lvl {m.lvl}"
-        trash = m.ncell_pad + m.ni_pad
-        # stencil values: cells of l (< ncell_pad) remap; interp slots
-        # (>= ncell_pad) and the trash row pass through remap_cells
-        src = remap_cells(m.stencil_src, lay, ttd)
-        kw["stencil_src"] = _perm_oct_rows(src, lay, trash)
-        if m.vsgn is not None:
-            kw["vsgn"] = _perm_oct_rows(m.vsgn, lay, 0)
-        kw["ok_ref"] = _perm_oct_rows(m.ok_ref, lay, False)
+        if m.has_stencil:
+            trash = m.ncell_pad + m.ni_pad
+            # stencil values: cells of l (< ncell_pad) remap; interp slots
+            # (>= ncell_pad) and the trash row pass through remap_cells
+            src = remap_cells(m.stencil_src, lay, ttd)
+            kw["stencil_src"] = _perm_oct_rows(src, lay, trash)
+            if m.vsgn is not None:
+                kw["vsgn"] = _perm_oct_rows(m.vsgn, lay, 0)
+            kw["ok_ref"] = _perm_oct_rows(m.ok_ref, lay, False)
         kw["valid_oct"] = _perm_oct_rows(m.valid_oct, lay, False)
         corr = _perm_oct_rows(m.corr_idx, lay, -1)
         kw["ref_cell"] = remap_cells(m.ref_cell, lay, ttd)
@@ -395,8 +396,9 @@ def apply_layout_level(m, lay_m1: Optional[LevelLayout],
         corr = m.corr_idx
         kw["ref_cell"] = m.ref_cell
     if lay_m1 is not None:
-        kw["interp_cell"] = remap_cells(m.interp_cell, lay_m1, ttd)
-        kw["interp_nb"] = remap_cells(m.interp_nb, lay_m1, ttd)
+        if m.has_stencil:
+            kw["interp_cell"] = remap_cells(m.interp_cell, lay_m1, ttd)
+            kw["interp_nb"] = remap_cells(m.interp_nb, lay_m1, ttd)
         corr = remap_cells(corr, lay_m1, ttd)
     kw["corr_idx"] = corr
     son = m.son_oct

@@ -260,23 +260,27 @@ def collect(hb=lambda *a, **k: None, emit=None):
             if sim.maps[l].complete:
                 continue
             dl_ = sim.dev[l]
-            itp = K.interp_cells(sim.u[l - 1], dl_["interp_cell"],
-                                 dl_["interp_nb"], dl_["interp_sgn"],
-                                 sim.cfg, itype=spec.itype)
-            t[f"interp_cells_L{l}"] = timeit(
-                lambda: K.interp_cells(sim.u[l - 1], dl_["interp_cell"],
-                                       dl_["interp_nb"],
-                                       dl_["interp_sgn"],
-                                       sim.cfg, itype=spec.itype), reps,
-                _sync)
-            t[f"level_sweep_L{l}"] = timeit(
-                lambda: K.level_sweep(sim.u[l], itp, dl_["stencil_src"],
-                                      dl_["vsgn"], dl_["ok_ref"], None,
-                                      dt, sim.dx(l), sim.cfg), reps,
-                _sync)
+            if "stencil_src" in dl_:
+                # the 6^3 stencil sweep, where the level has its tables
+                # (oct_blocking=.false.; a tile-path level has none)
+                itp = K.interp_cells(sim.u[l - 1], dl_["interp_cell"],
+                                     dl_["interp_nb"], dl_["interp_sgn"],
+                                     sim.cfg, itype=spec.itype)
+                t[f"interp_cells_L{l}"] = timeit(
+                    lambda: K.interp_cells(sim.u[l - 1],
+                                           dl_["interp_cell"],
+                                           dl_["interp_nb"],
+                                           dl_["interp_sgn"],
+                                           sim.cfg, itype=spec.itype),
+                    reps, _sync)
+                t[f"level_sweep_L{l}"] = timeit(
+                    lambda: K.level_sweep(sim.u[l], itp,
+                                          dl_["stencil_src"], dl_["vsgn"],
+                                          dl_["ok_ref"], None, dt,
+                                          sim.dx(l), sim.cfg), reps,
+                    _sync)
             if l in sim.blocks:
-                # the gather-fused blocked sweep, same level/shapes —
-                # side-by-side with the 6^3 stencil sweep above
+                # the gather-fused blocked tile sweep of the same level
                 bi = K.interp_cells(
                     sim.u[l - 1], dl_["b_interp_cell"],
                     dl_["b_interp_nb"], dl_["b_interp_sgn"], sim.cfg,
