@@ -11,8 +11,10 @@ hold the Pallas kernels):
 * AMR: ``namelists/sedov3d_amr.nml`` (levels 7→9, regrid every step).
 
 ``--chips 4`` runs ONLY the sharded path and its comparison
-(``ShardedSim`` / ``ShardedAmrSim`` over four devices against the same
-class on one device, in this process).
+(``ShardedSim`` over four devices against one; the AMR side through
+``ramses_tpu.__main__.build_amr_sim``, the command line's own choice:
+``ShardedAmrSim`` over four devices against ``AmrSim`` over one, in this
+process).
 
 No accelerator ⇒ non-zero exit and no result line; never selects a
 platform, never falls back to the CPU.  ``--rehearse`` is the sandbox
@@ -47,9 +49,10 @@ KERNEL = 'custom_call_target="tpu_custom_call"'
 # them (~7e-6).  1e-4 is one order above that; a lost coarse-fine flux
 # correction or a dropped level shows at >= 1e-3.
 CONS_RTOL = 1e-4
-# sharded vs one device: same class, same XLA formulation, same f32
-# inputs; only the partitioner's fusion/reduction order differs, so the
-# states agree to a few ulp per step.  L1(diff)/L1(ref) over <= 12
+# sharded vs one device: same f32 inputs and arithmetic; the partitioner's
+# fusion/reduction order differs and the one-device side runs the Pallas
+# kernels (the gates ask what the simulation spans, not the host), so
+# the states agree to a few ulp per step.  L1(diff)/L1(ref) over <= 12
 # steps stays below 1e-5; a wrong halo or a dropped shard is O(1).
 SHARD_L1_RTOL = 1e-5
 
@@ -170,47 +173,6 @@ def phase_uniform(nml, rehearse):
                 "uniform step program holds no fused Pallas kernel"
 
 
-def level_formulations(sim):
-    """[(level, name, is_kernel)] for the sim's CURRENT fused spec —
-    the same gates, asked with the same arguments, as the traced step
-    (hierarchy._advance_traced → amr/kernels.py)."""
-    from ramses_tpu.hydro import pallas_muscl as pk
-    from ramses_tpu.hydro import pallas_oct as po
-    spec = sim._fused_spec()
-    cfg, dtype = spec.cfg, sim.dtype
-    out = []
-    for i, l in enumerate(spec.levels):
-        if spec.complete[i]:
-            if spec.slab and spec.slab[i] is not None:
-                sl = spec.slab[i]
-                cut = tuple(p is not None for p in sl.perms)
-                kax = pk.shard_axes(cfg, sl.loc, cut, dtype)
-                out.append((l, f"dense slab-sharded sweep (grid {sl.grid}"
-                            f", halo {sl.backend}, per-shard "
-                            + (f"fused kernel axes {kax}" if kax
-                               else "XLA update") + ")", kax is not None))
-                continue
-            root = spec.root or (1,) * cfg.ndim
-            shape = tuple(r << l for r in root[:cfg.ndim])
-            k = pk.kernel_available(cfg, shape, spec.bspec.faces, dtype)
-            out.append((l, "dense fused kernel (pallas_muscl)" if k
-                        else "dense XLA sweep", k))
-        elif spec.comm and spec.comm[i] is not None:
-            out.append((l, "explicit-comm stencil sweep (XLA)", False))
-        elif spec.blocked and spec.blocked[i]:
-            nt = sim.blocks[l].ntile_pad
-            k = spec.pallas_tiles and po.tile_available(
-                cfg, nt, dtype, spec.block_shift)
-            out.append((l, f"tile_sweep kernel (pallas_oct, {nt} tiles)"
-                        if k else f"XLA tiles ({nt} tiles)", k))
-        else:
-            no = sim.maps[l].noct_pad
-            k = po.available(cfg, no, dtype)
-            out.append((l, f"oct_sweep kernel (pallas_oct, {no} octs)"
-                        if k else f"XLA oct stencils ({no} octs)", k))
-    return out
-
-
 def phase_amr(nml, rehearse):
     import jax.numpy as jnp
     from ramses_tpu.amr import hierarchy as H
@@ -244,7 +206,7 @@ def phase_amr(nml, rehearse):
         # the coarse-step program of the final tree, recompiled from
         # the sim's own arguments: one kernel call per level substep
         # (level lmin+i is swept 2^i times per coarse step)
-        forms = level_formulations(sim)
+        forms = sim.level_formulations()
         spec = sim._fused_spec()
         txt = H._fused_coarse_step.lower(
             sim.u, sim.dev, {}, jnp.asarray(sim.dt_old, sim.dtype), spec,
@@ -276,7 +238,6 @@ def l1_rel(a, b):
 
 
 def phase_sharded_uniform(nml, devs):
-    import jax
     import jax.numpy as jnp
     import numpy as np
     from ramses_tpu.config import load_params
@@ -301,9 +262,7 @@ def phase_sharded_uniform(nml, devs):
                 f"t={sim.t:.6e} mass_rel_err={dm:.3e} "
                 f"energy_rel_err={de:.3e} fused_kernel_gate="
                 f"{_pallas_ok(sim.grid, sim.u.dtype)} (the kernel gates "
-                f"need jax.device_count()==1; here it is "
-                f"{jax.device_count()}, so both sides run the XLA "
-                f"formulation)")
+                f"ask how many devices the simulation spans: {nd})")
             assert sim.nstep == nstep
             assert dm < CONS_RTOL and de < CONS_RTOL
             res[nd] = (np.asarray(sim.u), sim.t)
@@ -318,15 +277,18 @@ def phase_sharded_uniform(nml, devs):
 def phase_sharded_amr(nml, devs, nstep):
     import jax
     import jax.numpy as jnp
+    from ramses_tpu.__main__ import build_amr_sim
     from ramses_tpu.config import load_params
-    from ramses_tpu.parallel.amr_sharded import ShardedAmrSim
 
     with Phase("sharded-amr"):
         params = load_params(nml, ndim=3)
         res = {}
         for nd in (len(devs), 1):
-            sim = ShardedAmrSim(params, devices=devs[:nd],
-                                dtype=jnp.float32)
+            # the command line's own choice of class: sharded over
+            # several devices, plain AmrSim (on its kernels) over one
+            sim = build_amr_sim(params, jnp.float32, devices=devs[:nd],
+                                log=say)
+            name = type(sim).__name__
             tot0 = sim.totals()
             sim.evolve(1e9, nstepmax=nstep)
             octs = {l: sim.tree.noct(l) for l in sim.levels()}
@@ -334,14 +296,14 @@ def phase_sharded_amr(nml, devs, nstep):
             arrays += [(f"dev[{l}][{k}]", v) for l in sim.levels()
                        for k, v in sim.dev[l].items()
                        if isinstance(v, jax.Array)]
-            assert_spans(f"ShardedAmrSim/{nd}", arrays, nd)
-            check_finite(f"ShardedAmrSim/{nd}", arrays[:len(octs)])
+            assert_spans(f"{name}/{nd}", arrays, nd)
+            check_finite(f"{name}/{nd}", arrays[:len(octs)])
             tot = sim.totals()
             dm, de = rel(tot[0], tot0[0]), rel(tot[4], tot0[4])
             say(f"[sharded-amr] {nd} device(s): nstep={sim.nstep} "
                 f"t={sim.t:.6e} octs={octs} mass_rel_err={dm:.3e} "
                 f"energy_rel_err={de:.3e}")
-            for l, name, _ in level_formulations(sim):
+            for l, name, _ in sim.level_formulations():
                 say(f"[sharded-amr] {nd} device(s) level {l}: {name}")
             assert sim.nstep == nstep
             assert dm < CONS_RTOL and de < CONS_RTOL
@@ -382,9 +344,9 @@ def main():
               f"{len(devs)} device(s)", file=sys.stderr)
         return 2
     if args.chips == 1 and len(devs) != 1 and not args.rehearse:
-        print(f"chip_smoke: the one-chip phases need a one-device "
-              f"process (the Pallas gates require jax.device_count()"
-              f"==1), jax found {len(devs)}; use --chips 4 here",
+        print(f"chip_smoke: the one-chip phases drive the command "
+              f"line, which builds the sharded class over all "
+              f"{len(devs)} devices it sees; use --chips 4 here",
               file=sys.stderr)
         return 2
     import ramses_tpu  # noqa: F401  (engages the compile-cache rule)

@@ -103,6 +103,52 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
+def _on_accelerator() -> bool:
+    """The platform test of the Pallas and DMA gates."""
+    import jax
+    return jax.default_backend() == "tpu"
+
+
+def amr_devices(devices=None) -> list:
+    """The devices an AMR hydro run is built over.  Named ``devices``
+    are taken as they are.  With none named: every device of the default
+    backend when that backend is the accelerator (the platform test of
+    the Pallas and DMA gates), else one — forced virtual CPU devices are
+    one host's cores, not a mesh.  To use one chip of four, restrict the
+    visible devices as any JAX program does."""
+    import jax
+    if devices is not None:
+        return list(devices)
+    devs = jax.devices()
+    return list(devs) if _on_accelerator() else devs[:1]
+
+
+def build_amr_sim(params, dtype, devices=None, restart=None, log=print,
+                  **kw):
+    """The hydro AMR simulation of ``python -m ramses_tpu``, fresh or
+    from the checkpoint directory ``restart``: the mesh-sharded class
+    over :func:`amr_devices` when they are more than one, ``AmrSim``
+    when there is one.  ``kw`` goes to the constructor of a fresh
+    build.  Says once what it built and which formulation each level's
+    sweep takes."""
+    from ramses_tpu.amr.hierarchy import AmrSim
+    devs = amr_devices(devices)
+    cls, mesh = AmrSim, {}
+    if len(devs) > 1:
+        from ramses_tpu.parallel.amr_sharded import ShardedAmrSim
+        cls, mesh = ShardedAmrSim, {"devices": devs}
+    if restart:
+        sim = cls.from_checkpoint_dir(params, restart, dtype=dtype, **mesh)
+    else:
+        sim = cls(params, dtype=dtype, **kw, **mesh)
+    if log is not None:
+        log(f"amr: {cls.__name__} over {len(devs)} device(s) "
+            f"[{devs[0].platform}]; " + "; ".join(
+                f"level {l}: {name}"
+                for l, name, _ in sim.level_formulations()))
+    return sim
+
+
 def main(argv=None) -> int:
     ap = build_parser()
     args = ap.parse_args(argv)
@@ -348,12 +394,9 @@ def run(args):
             sim.dump(1, params.output.output_dir,
                      namelist_path=args.namelist)
     elif args.amr or params.amr.levelmax > params.amr.levelmin:
-        from ramses_tpu.amr.hierarchy import AmrSim
-
         def build(restart):
             if restart:
-                return AmrSim.from_checkpoint_dir(params, restart,
-                                                  dtype=dtype)
+                return build_amr_sim(params, dtype, restart=restart)
             particles = None
             dense = None
             if (params.run.cosmo and params.init.initfile
@@ -366,8 +409,8 @@ def run(args):
                 particles, dense = load_cosmo_ics(
                     params, cosmo, HydroStatic.from_params(params),
                     (n,) * params.ndim)
-            return AmrSim(params, dtype=dtype, particles=particles,
-                          init_dense_u=dense)
+            return build_amr_sim(params, dtype, particles=particles,
+                                 init_dense_u=dense)
 
         def amr_tend(sim):
             if sim.cosmo is not None and params.output.aout:

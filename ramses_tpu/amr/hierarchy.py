@@ -103,10 +103,16 @@ class FusedSpec(NamedTuple):
     blocked: tuple = ()
     # octs per tile side = 2**block_shift for the blocked levels
     block_shift: int = 2
-    # allow the Pallas tile kernel inside K.tile_sweep (single-device
-    # meshes); multi-device row-sharded trees force the XLA tile
-    # formulation so GSPMD can partition the sweep
-    pallas_tiles: bool = True
+    # devices the level rows span (the SIMULATION's mesh, not the
+    # host's device count): the Pallas gates take it
+    ndev: int = 1
+
+    @property
+    def pallas_tiles(self) -> bool:
+        """Allow the Pallas tile kernel inside K.tile_sweep: a tree on
+        one device; row-sharded trees force the XLA tile formulation so
+        GSPMD can partition the sweep."""
+        return self.ndev == 1
 
 
 def _advance_traced(u, dev, fg, dt, spec: FusedSpec, cool_tables=None):
@@ -162,7 +168,8 @@ def _advance_traced(u, dev, fg, dt, spec: FusedSpec, cool_tables=None):
                 out = K.dense_sweep(u[l], d.get("inv_perm"),
                                     d.get("perm"), d["ok_dense"], dtl,
                                     dx(l), shape(l), spec.bspec, cfg,
-                                    ret_flux=spec.want_flux)
+                                    ret_flux=spec.want_flux,
+                                    ndev=spec.ndev)
             du = out[0] if spec.want_flux else out
             if spec.want_flux:
                 phi[l] = phi[l] + out[1]
@@ -198,7 +205,8 @@ def _advance_traced(u, dev, fg, dt, spec: FusedSpec, cool_tables=None):
                                     itype=spec.itype)
             out = K.level_sweep(
                 u[l], interp, d["stencil_src"], d["vsgn"], d["ok_ref"],
-                None, dtl, dx(l), cfg, ret_flux=spec.want_flux)
+                None, dtl, dx(l), cfg, ret_flux=spec.want_flux,
+                ndev=spec.ndev)
             du, corr = out[0], out[1]
             if spec.want_flux:
                 phi[l] = phi[l] + out[2]
@@ -402,14 +410,15 @@ def _fused_multi_step(u, dev, t, tend, dt0, spec: FusedSpec, nsteps: int,
 
 
 def restore_amr_scaffold(cls, params: Params, outdir: str, dtype,
-                         to_cons, place_level):
+                         to_cons, place_level, **ctor_kw):
     """Shared restart scaffold (the ``nrestart`` path) used by the
     hydro, MHD, and SRHD AMR sims: rebuild the octree from the file
     oct coords, construct the sim on it, place each level's restored
     rows (re-mapped defensively through the rebuilt tree's key order),
     then restrict.  ``to_cons(q_rows)`` converts file output columns
     to the solver's stored rows; ``place_level(sim, l, rows, og,
-    order)`` writes them into the sim state.  Returns (sim, parts)."""
+    order)`` writes them into the sim state; ``ctor_kw`` goes to the
+    constructor (the sharded class's ``devices``).  Returns (sim, parts)."""
     from ramses_tpu.io.restart import restore_particles, restore_tree_state
     tree_og, rows_lv, meta, parts = restore_tree_state(
         outdir, None, params.amr.levelmin, to_cons=to_cons)
@@ -454,7 +463,7 @@ def restore_amr_scaffold(cls, params: Params, outdir: str, dtype,
     # open box) — resurrecting a fresh population would fabricate
     # trajectories
     sim = cls(params, dtype=dtype, init_tree=tree, particles=ps,
-              seed_tracers=False)
+              seed_tracers=False, **ctor_kw)
     if tracer_x is not None:
         sim.tracer_x = tracer_x
         sim.tracer_id = tracer_id
@@ -510,7 +519,7 @@ def _place_u_rows(sim, l: int, rows: np.ndarray, og: np.ndarray,
     out = np.array(sim.u[l])
     out[sim.cell_rows(l)] = rows.reshape(
         len(og), ttd, nvar)[order].reshape(-1, nvar)
-    sim.u[l] = jnp.asarray(out, dtype=sim.dtype)
+    sim.u[l] = sim._place(jnp.asarray(out, dtype=sim.dtype), "cells")
 
 
 class AmrSim:
@@ -1570,7 +1579,8 @@ class AmrSim:
                            and len(self.tracer_x) > 0
                            and getattr(self.cfg, "physics",
                                        "hydro") == "hydro"
-                           and not cspecs))
+                           and not cspecs),
+                ndev=int(self.ndev))
             slab = tuple(self._slab_spec(l) if self.maps[l].complete
                          else None for l in lv)
             if any(s is not None for s in slab):
@@ -1580,9 +1590,52 @@ class AmrSim:
                 self._spec = self._spec._replace(
                     blocked=blocked,
                     block_shift=int(getattr(self.params.amr,
-                                            "oct_block_shift", 2)),
-                    pallas_tiles=(int(getattr(self, "ndev", 1)) == 1))
+                                            "oct_block_shift", 2)))
         return self._spec
+
+    def level_formulations(self) -> list:
+        """[(level, name, on its Pallas kernel)] for the CURRENT fused
+        spec: the same gates, asked with the same arguments, as the
+        traced step (``_advance_traced`` → ``amr/kernels.py``,
+        ``parallel/dense_slab.py``)."""
+        from ramses_tpu.hydro import pallas_muscl as pk
+        from ramses_tpu.hydro import pallas_oct as po
+        spec = self._fused_spec()
+        cfg, dtype = spec.cfg, self.dtype
+        out = []
+        for i, l in enumerate(spec.levels):
+            if spec.complete[i]:
+                sl = spec.slab[i] if spec.slab else None
+                if sl is not None:
+                    cut = tuple(p is not None for p in sl.perms)
+                    kax = pk.shard_axes(cfg, sl.loc, cut, dtype)
+                    out.append((l, f"dense slab-sharded sweep (grid "
+                                f"{sl.grid}, halo {sl.backend}, per-shard "
+                                + (f"fused kernel axes {kax}" if kax
+                                   else "XLA update") + ")",
+                                kax is not None))
+                    continue
+                root = spec.root or (1,) * cfg.ndim
+                shape = tuple(r << l for r in root[:cfg.ndim])
+                k = pk.kernel_available(cfg, shape, spec.bspec.faces,
+                                        dtype, spec.ndev)
+                out.append((l, "dense fused kernel (pallas_muscl)" if k
+                            else "dense XLA sweep", bool(k)))
+            elif spec.comm and spec.comm[i] is not None:
+                out.append((l, "explicit-comm stencil sweep (XLA)", False))
+            elif spec.blocked and spec.blocked[i]:
+                nt = self.blocks[l].ntile_pad
+                k = spec.pallas_tiles and po.tile_available(
+                    cfg, nt, dtype, spec.block_shift)
+                out.append((l, f"tile_sweep kernel (pallas_oct, {nt} tiles)"
+                            if k else f"XLA tiles ({nt} tiles)", bool(k)))
+            else:
+                no = self.maps[l].noct_pad
+                k = po.available(cfg, no, dtype, spec.ndev)
+                out.append((l, f"oct_sweep kernel (pallas_oct, {no} octs)"
+                            if k else f"XLA oct stencils ({no} octs)",
+                            bool(k)))
+        return out
 
     def _slab_spec(self, l: int):
         """SlabSpec for a complete level's explicit slab-sharded dense
@@ -2478,14 +2531,15 @@ class AmrSim:
 
     @classmethod
     def from_snapshot(cls, params: Params, outdir: str,
-                      dtype=jnp.float32) -> "AmrSim":
-        """Resume from a snapshot directory (``nrestart`` path)."""
+                      dtype=jnp.float32, **kw) -> "AmrSim":
+        """Resume from a snapshot directory (``nrestart`` path); ``kw``
+        goes to the constructor (the sharded class's ``devices``)."""
         from ramses_tpu.io.snapshot import prim_out_to_cons
         cfg = cls._make_cfg(params)
         sim, _parts = restore_amr_scaffold(
             cls, params, outdir, dtype,
             to_cons=lambda q: prim_out_to_cons(q, cfg),
-            place_level=_place_u_rows)
+            place_level=_place_u_rows, **kw)
         return sim
 
     @classmethod
@@ -2510,7 +2564,7 @@ class AmrSim:
             seen.add(os.path.abspath(cur))
             name = os.path.basename(os.path.normpath(cur))
             if not name.startswith("pario_"):
-                return cls.from_snapshot(params, cur, dtype=dtype)
+                return cls.from_snapshot(params, cur, dtype=dtype, **kw)
             try:
                 return pariomod.restore_pario(cls, params, cur,
                                               dtype=dtype, log=log,

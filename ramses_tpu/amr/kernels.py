@@ -179,10 +179,14 @@ def _flat_cells(blk, ndim: int):
         blk, (ndim,) + tuple(range(ndim))).reshape(noct * 2 ** ndim)
 
 
-@partial(jax.jit, static_argnames=("cfg", "dx", "ret_flux"))
+@partial(jax.jit, static_argnames=("cfg", "dx", "ret_flux", "ndev"))
 def level_sweep(u_flat, interp_vals, stencil_src, vsgn, ok_ref, gloc,
-                dt, dx: float, cfg: HydroStatic, ret_flux: bool = False):
+                dt, dx: float, cfg: HydroStatic, ret_flux: bool = False,
+                ndev: int = 1):
     """Full godfine1 for one level.
+
+    ``ndev``: devices the level's rows span (the caller's simulation,
+    not the host); more than one keeps the XLA formulation.
 
     Returns (du_flat [ncell, nvar], corr [noct, ndim, 2, nvar]) where
     corr[:, d, side] is the summed boundary flux (already ×dt/dx) to be
@@ -202,7 +206,8 @@ def level_sweep(u_flat, interp_vals, stencil_src, vsgn, ok_ref, gloc,
     okl = ok_ref.T.reshape((6,) * ndim + (noct,))
 
     from ramses_tpu.hydro import pallas_oct
-    if gloc is None and pallas_oct.available(cfg, noct, u_flat.dtype):
+    if gloc is None and pallas_oct.available(cfg, noct, u_flat.dtype,
+                                             ndev):
         # fused TPU oct-batch kernel (same physics, VMEM-resident);
         # self-gravity rides as the hierarchy's separate traced
         # half-kick, so gloc is None on every production path
@@ -528,10 +533,11 @@ def pad_ok_dense(ok_dense, shape: Tuple[int, ...], bc, dtype, ng: int):
     return okp
 
 
-@partial(jax.jit, static_argnames=("cfg", "shape", "bc", "dx", "ret_flux"))
+@partial(jax.jit, static_argnames=("cfg", "shape", "bc", "dx", "ret_flux",
+                                   "ndev"))
 def dense_sweep(u_flat, inv_perm, perm, ok_dense, dt, dx: float,
                 shape: Tuple[int, ...], bc, cfg: HydroStatic,
-                ret_flux: bool = False):
+                ret_flux: bool = False, ndev: int = 1):
     """Sweep for a COMPLETE level (covers the whole box) as a dense grid.
 
     The 6^d stencil gather duplicates each cell ~3^d times and its
@@ -543,7 +549,9 @@ def dense_sweep(u_flat, inv_perm, perm, ok_dense, dt, dx: float,
     ``ret_flux``: additionally return ``phi [ncell, ndim, 2]`` — the
     per-cell (low, high) face mass flux ×dt/dx in flat row order (MC
     gas-tracer capture) — served by BOTH branches (the fused kernel
-    emits it as a second output).
+    emits it as a second output).  ``ndev``: devices the level's rows
+    span (the caller's simulation, not the host); more than one keeps
+    the XLA formulation so GSPMD can partition it.
     """
     from ramses_tpu.grid import boundary as bmod
     from ramses_tpu.hydro import pallas_muscl as pk
@@ -554,7 +562,7 @@ def dense_sweep(u_flat, inv_perm, perm, ok_dense, dt, dx: float,
         ncell *= s
     ud = rows_to_dense(u_flat, inv_perm, shape)        # [*shape, nvar]
     ud = jnp.moveaxis(ud, -1, 0)                       # [nvar, *shape]
-    if pk.kernel_available(cfg, shape, bc.faces, ud.dtype):
+    if pk.kernel_available(cfg, shape, bc.faces, ud.dtype, ndev):
         # fused TPU kernel path (same physics, VMEM-resident pipeline);
         # refined-face flux zeroing rides in as the mask input, the
         # MC-tracer face-flux capture as a second kernel output

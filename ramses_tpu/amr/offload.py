@@ -181,7 +181,8 @@ def _seg_sweep(u_l, u_lm1, unew_l, unew_lm1, d, dt, spec, i: int,
         root = spec.root or (1,) * cfg.ndim
         shp = tuple(r << l for r in root[:cfg.ndim])
         du = K.dense_sweep(u_l, d.get("inv_perm"), d.get("perm"),
-                           d["ok_dense"], dtl, dxl, shp, spec.bspec, cfg)
+                           d["ok_dense"], dtl, dxl, shp, spec.bspec, cfg,
+                           ndev=spec.ndev)
         corr = None
     elif spec.blocked and spec.blocked[i]:
         interp = K.interp_cells(u_lm1, d["b_interp_cell"],
@@ -197,7 +198,8 @@ def _seg_sweep(u_l, u_lm1, unew_l, unew_lm1, d, dt, spec, i: int,
         interp = K.interp_cells(u_lm1, d["interp_cell"], d["interp_nb"],
                                 d["interp_sgn"], cfg, itype=spec.itype)
         out = K.level_sweep(u_l, interp, d["stencil_src"], d["vsgn"],
-                            d["ok_ref"], None, dtl, dxl, cfg)
+                            d["ok_ref"], None, dtl, dxl, cfg,
+                            ndev=spec.ndev)
         du, corr = out[0], out[1]
     unew_l = unew_l + du
     if corr is not None and l > spec.lmin:

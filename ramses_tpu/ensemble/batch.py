@@ -29,7 +29,7 @@ import pickle
 import re
 import time
 from contextlib import nullcontext
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -431,6 +431,10 @@ class EnsembleEngine:
         g.replicas = r
         if r <= 1:
             return
+        if hasattr(g.grid, "ndev"):
+            # the member axis spans r devices: the uniform step's
+            # kernel gate must see that (grid/uniform.UniformGrid.ndev)
+            g.grid = replace(g.grid, ndev=r)
         mesh = replica_mesh(devs[:r])
         g.state = tuple(
             jax.device_put(c, replica_sharding(mesh, c.ndim))

@@ -30,6 +30,10 @@ class UniformGrid:
     shape: Tuple[int, ...]
     dx: float
     bc: bmod.BoundarySpec
+    # devices the state array spans (the simulation's mesh, not the
+    # host's device count): more than one keeps the XLA step, which
+    # GSPMD can partition; the fused Pallas kernel has no such rule
+    ndev: int = 1
 
     @property
     def ncell(self) -> int:
@@ -44,7 +48,8 @@ def _pallas_ok(grid: UniformGrid, dtype) -> bool:
     if grid.cfg.ndim != 3:
         return False
     from ramses_tpu.hydro import pallas_muscl as pk
-    return pk.kernel_available(grid.cfg, grid.shape, grid.bc.faces, dtype)
+    return pk.kernel_available(grid.cfg, grid.shape, grid.bc.faces, dtype,
+                               grid.ndev)
 
 
 @partial(jax.jit, static_argnames=("grid",))

@@ -39,14 +39,18 @@ NG = 2  # ghost cells per side (matches muscl.NGHOST)
 DISABLED = bool(__import__("os").environ.get("RAMSES_NO_PALLAS"))
 
 
-def kernel_available(cfg: HydroStatic, shape, bc_faces, dtype) -> bool:
-    """Full availability gate: env kill-switch, TPU backend, single
-    device (the kernel has no GSPMD partitioning rule — sharded runs
-    must keep the XLA solver so the SPMD partitioner can insert halo
-    collectives), and configuration coverage."""
+def kernel_available(cfg: HydroStatic, shape, bc_faces, dtype,
+                     ndev: int = 1) -> bool:
+    """Full availability gate: env kill-switch, TPU backend, a state
+    that lives on ONE device (the kernel has no GSPMD partitioning rule
+    — a state sharded over ``ndev`` > 1 devices must keep the XLA solver
+    so the SPMD partitioner can insert halo collectives), and
+    configuration coverage.  ``ndev`` is how many devices the CALLER's
+    arrays span, not how many the host has: a one-device simulation on
+    a four-chip host keeps its kernel."""
     if DISABLED:
         return False
-    if jax.default_backend() != "tpu" or jax.device_count() != 1:
+    if jax.default_backend() != "tpu" or ndev != 1:
         return False
     kinds = tuple((lo.kind, hi.kind) for lo, hi in bc_faces)
     return supports(cfg, shape, kinds, dtype)
@@ -321,14 +325,20 @@ def _make_kernel(cfg: HydroStatic, dx: float, bx: int, by: int,
     return kernel
 
 
+# device op names of the two callers' kernels: a trace tells the
+# per-shard call of the slab path from the whole-level one by them
+SHARD_KERNEL_NAME = "fused_step_shard"
+
+
 @partial(jax.jit,
          static_argnames=("cfg", "dx", "shape", "courant", "interpret",
-                          "want_flux"))
+                          "want_flux", "name"))
 def fused_step_padded(u_pad, dt, cfg: HydroStatic, dx: float,
                       shape: Tuple[int, int, int],
                       ok_pad: Optional[jnp.ndarray] = None,
                       courant: bool = False, interpret: bool = False,
-                      want_flux: bool = False):
+                      want_flux: bool = False,
+                      name: Optional[str] = None):
     """Run the fused kernel on an x/y-ghost-padded state.
 
     u_pad: [5, nx+4, ny+8, nz] from :func:`pad_xy` (x: 2-cell ghosts
@@ -383,6 +393,7 @@ def fused_step_padded(u_pad, dt, cfg: HydroStatic, dx: float,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,           # CPU parity tests
+        name=name,                     # None: the kernel body's own
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
     )(*args)
@@ -463,7 +474,8 @@ def fused_step_shard(up, okp, dt, cfg: HydroStatic, dx: float,
                       mode="edge")
     shape_rel = (loc[a0], loc[a1], loc[az])
     out = fused_step_padded(ur, dt, cfg, dx, shape_rel, ok_pad=okr,
-                            want_flux=want_flux, interpret=interpret)
+                            want_flux=want_flux, interpret=interpret,
+                            name=SHARD_KERNEL_NAME)
     un = out[0] if want_flux else out
     du = un - ur[:, NG:-NG, NG:NG + shape_rel[1], :]
     du = jnp.transpose(du[jnp.asarray(ivp)], isp)

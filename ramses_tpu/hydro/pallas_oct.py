@@ -54,13 +54,14 @@ FORCE_INTERPRET = bool(__import__("os").environ
                        .get("RAMSES_PALLAS_OCT_INTERPRET"))
 
 
-def _in_scope(cfg: HydroStatic, dtype) -> bool:
+def _in_scope(cfg: HydroStatic, dtype, ndev: int = 1) -> bool:
     """Platform + physics scope shared by both kernels' gates (see the
-    module docstring)."""
-    if DISABLED:
+    module docstring).  ``ndev``: devices the caller's level rows span
+    (not the host's device count); row-sharded levels stay off the
+    kernels, interpreted or not, so GSPMD can partition the sweep."""
+    if DISABLED or ndev != 1:
         return False
-    if not FORCE_INTERPRET and (jax.default_backend() != "tpu"
-                                or jax.device_count() != 1):
+    if not FORCE_INTERPRET and jax.default_backend() != "tpu":
         return False
     if getattr(cfg, "physics", "hydro") != "hydro":
         return False
@@ -77,12 +78,14 @@ def _in_scope(cfg: HydroStatic, dtype) -> bool:
     return True
 
 
-def available(cfg: HydroStatic, noct_pad: int, dtype) -> bool:
+def available(cfg: HydroStatic, noct_pad: int, dtype,
+              ndev: int = 1) -> bool:
     """Availability gate for the oct-batch kernel (see module docstring;
-    the single-device restriction mirrors ``pallas_muscl.kernel_available``
-    — sharded levels keep the XLA formulation so GSPMD can partition;
-    with blocking on they still get the compact tile batch)."""
-    return _in_scope(cfg, dtype) and noct_pad % 128 == 0
+    the one-device restriction mirrors ``pallas_muscl.kernel_available``
+    — levels sharded over ``ndev`` > 1 devices keep the XLA formulation
+    so GSPMD can partition; with blocking on they still get the compact
+    tile batch)."""
+    return _in_scope(cfg, dtype, ndev) and noct_pad % 128 == 0
 
 
 def _tile(noct_pad: int) -> int:

@@ -332,7 +332,7 @@ def sweep_correct_explicit(u_l, u_lm1, unew_lm1, d: dict, dt, dx: float,
                                 itype=spec.itype)
         du, corr = K.level_sweep(u_ext, interp, lsten,
                                  vsgn_loc if has_vsgn else None, ok_loc,
-                                 None, dt_r, dx, cfg)
+                                 None, dt_r, dx, cfg, ndev=ndev)
 
         # P3: deterministic owner-fold — own first, then offsets
         # ascending (sorted segment order is fixed by the schedule)

@@ -64,6 +64,10 @@ FORCE_INTERPRET = bool(os.environ.get("RAMSES_DMA_HALO_INTERPRET"))
 # telemetry.sim_run_info snapshots them into every run_header.
 TRAFFIC = {"bytes": 0, "exchanges": 0, "overlap_frac": 0.0}
 
+# device op name of the exchange kernel: the only thing a trace keeps
+# to tell halo traffic from the sweep kernels (both are custom calls)
+KERNEL_NAME = "halo_dma_exchange"
+
 # distinct barrier-semaphore ids for kernels that may run concurrently
 # inside one program (e.g. the state and mask exchanges of a split
 # sweep); trace order is deterministic SPMD so every device agrees
@@ -190,7 +194,7 @@ def _dma_exchange(slabs, dsts, interpret: bool):
         out_shape=tuple(jax.ShapeDtypeStruct(s.shape, s.dtype)
                         for s in slabs),
         scratch_shapes=[pltpu.SemaphoreType.DMA] * (2 * n),
-        interpret=interpret,
+        interpret=interpret, name=KERNEL_NAME,
         **kwargs)(dst_arr, *slabs)
     return list(outs)
 

@@ -11,6 +11,7 @@ communication — the idiomatic TPU answer to two-sided MPI halos.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional, Sequence
 
 import jax
@@ -33,6 +34,9 @@ class ShardedSim:
                  dtype=jnp.float32):
         self.inner = Simulation(params, dtype=dtype)
         self.mesh = make_mesh(params.ndim, devices)
+        # the step's kernel gate asks how many devices the state spans
+        self.inner.grid = dataclasses.replace(
+            self.inner.grid, ndev=int(self.mesh.devices.size))
         self.sharding = spatial_sharding(self.mesh, n_leading=1)
         self.u = jax.device_put(self.inner.state.u, self.sharding)
         self.inner.state.u = None  # drop the unsharded copy (memory)
