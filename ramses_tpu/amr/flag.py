@@ -7,35 +7,35 @@ top-down nesting sweep that is the constructive form of the reference's
 any flagged cell x at level l+1 has a father-neighbourhood cell
 ``(x+e)>>1`` equal to it — this guarantees every surviving oct's 3^ndim
 father-cell stencil exists.
+
+The tree build works on sorted Morton cell keys end to end
+(``compute_new_tree``): a level's flagged cells are the keys of its
+flagged flat-cell indices, a 3^ndim dilation is ``ndim`` separable
+passes of +-1 key arithmetic (``keys.neighbor_keys``) with a
+sort-and-dedupe after each, nesting is one more dilation and
+``>> ndim``, and the cell keys of level l are the oct keys of level
+l+1.  No coordinate array of a whole level is formed; coordinates are
+decoded only for the new partial levels' ``og``.
 """
 
 from __future__ import annotations
 
-import itertools
 from typing import Dict
 
 import numpy as np
 
 from ramses_tpu.amr import keys as kmod
-from ramses_tpu.amr.tree import Octree, map_coords
+from ramses_tpu.amr.tree import Octree, cell_offsets
 from ramses_tpu.config import Params
 
 
-def _neighbor_offsets(ndim: int) -> np.ndarray:
-    return np.array(list(itertools.product((-1, 0, 1), repeat=ndim)),
-                    dtype=np.int64)
-
-
-def dilate(flag_coords: np.ndarray, lvl: int, bc_kinds, ndim: int,
-           dims=None) -> np.ndarray:
-    """One smoothing pass: the 3^ndim dilation of the flagged cell set."""
-    if len(flag_coords) == 0:
-        return flag_coords
-    offs = _neighbor_offsets(ndim)
-    ex = (flag_coords[:, None, :] + offs[None, :, :]).reshape(-1, ndim)
-    ex, _ = map_coords(ex, lvl, bc_kinds, ndim, dims=dims)
-    ks = np.unique(kmod.encode(ex, ndim))
-    return kmod.decode(ks, ndim)
+def _dilate_keys(ks: np.ndarray, dims, periodic, ndim: int) -> np.ndarray:
+    """One smoothing pass: the 3^ndim dilation of a cell-key set, as one
+    (keep, -1, +1) pass per axis; returns sorted unique keys."""
+    for d in range(ndim):
+        dn, up = kmod.neighbor_keys(ks, d, ndim, dims[d], periodic[d])
+        ks = np.unique(np.concatenate([ks, dn, up]))
+    return ks
 
 
 def geometry_flags(centers: np.ndarray, lvl: int, p: Params) -> np.ndarray:
@@ -68,54 +68,45 @@ def compute_new_tree(tree: Octree, crit_flags: Dict[int, np.ndarray],
 
     ``crit_flags[l]``: bool [ncell_flat(l)] on the CURRENT tree.  Returns a
     tree whose level-(l+1) oct set is exactly the flagged cell set of level
-    l after smoothing + nesting.
+    l after smoothing + nesting.  Its base level is ``tree``'s own
+    ``OctLevel`` (complete and never mutated in place).
     """
     ndim = tree.ndim
     lmin, lmax = tree.levelmin, tree.levelmax
     nexpand = params.amr.nexpand
+    periodic = [tuple(bc_kinds[d]) == (0, 0) for d in range(ndim)]
+    # flat-cell offset (x slowest) -> Morton child bits (x is bit 0)
+    child = kmod.encode(cell_offsets(ndim), ndim)
 
-    # flagged cell coordinate sets per level, smoothed
-    fcoords: Dict[int, np.ndarray] = {}
+    # flagged cell keys per level (sorted), smoothed
+    fkeys: Dict[int, np.ndarray] = {}
     for l in range(lmin, lmax + 1):
-        if not tree.has(l):
-            fcoords[l] = np.zeros((0, ndim), dtype=np.int64)
-            continue
-        cc = tree.cell_coords(l)
         f = crit_flags.get(l)
-        coords = cc[f] if f is not None and f.any() else \
-            np.zeros((0, ndim), dtype=np.int64)
+        if f is None or not tree.has(l):
+            fkeys[l] = np.zeros(0, dtype=np.int64)
+            continue
+        i = np.flatnonzero(f)
+        ks = np.sort((tree.levels[l].keys[i >> ndim] << ndim)
+                     | child[i & ((1 << ndim) - 1)])
         ne = nexpand[l - 1] if l - 1 < len(nexpand) else 1
         for _ in range(max(int(ne), 0)):
-            coords = dilate(coords, l, bc_kinds, ndim,
-                            dims=tree.cell_dims(l))
-        fcoords[l] = coords
+            ks = _dilate_keys(ks, tree.cell_dims(l), periodic, ndim)
+        fkeys[l] = ks
 
     # top-down nesting: project fine flags into father-neighbourhood flags
-    offs = _neighbor_offsets(ndim)
     for l in range(lmax, lmin, -1):
-        x = fcoords[l]
-        if len(x) == 0:
-            continue
-        ex = (x[:, None, :] + offs[None, :, :]).reshape(-1, ndim)
-        ex, _ = map_coords(ex, l, bc_kinds, ndim, dims=tree.cell_dims(l))
-        up = ex >> 1
-        ks = np.unique(kmod.encode(up, ndim))
-        prev = kmod.encode(fcoords[l - 1], ndim) if len(fcoords[l - 1]) \
-            else np.zeros(0, dtype=np.int64)
-        allk = np.unique(np.concatenate([prev, ks]))
-        fcoords[l - 1] = kmod.decode(allk, ndim)
+        up = _dilate_keys(fkeys[l], tree.cell_dims(l), periodic, ndim)
+        fkeys[l - 1] = np.unique(np.concatenate([fkeys[l - 1],
+                                                 up >> ndim]))
 
     # flags only refine existing cells: intersect with current cell sets
     new = Octree(ndim, lmin, lmax, root=tree.root)
-    new.set_level(lmin, tree.levels[lmin].og)          # base stays complete
+    new.levels[lmin] = tree.levels[lmin]               # base stays complete
     for l in range(lmin, lmax):
-        coords = fcoords[l]
-        if len(coords) == 0:
-            break
+        ks = fkeys[l]
         # a flagged cell must exist on the (new) level l to spawn an oct
-        parent = new.lookup(l, coords >> 1)
-        coords = coords[parent >= 0]
-        if len(coords) == 0:
+        ks = ks[new.lookup_keys(l, ks >> ndim) >= 0]
+        if len(ks) == 0:
             break
-        new.set_level(l + 1, coords)                   # cell coords = oct
+        new.set_level_keys(l + 1, ks)                  # cell keys = oct keys
     return new

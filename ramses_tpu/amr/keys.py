@@ -12,6 +12,8 @@ sorted Morton array is the whole "tree": membership = ``searchsorted``.
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 
@@ -84,3 +86,30 @@ def decode(keys: np.ndarray, ndim: int) -> np.ndarray:
                         axis=1).astype(np.int64)
     return np.stack([_compact3(k), _compact3(k >> np.uint64(1)),
                      _compact3(k >> np.uint64(2))], axis=1).astype(np.int64)
+
+
+@functools.lru_cache(maxsize=256)
+def axis_bits(x: int, axis: int, ndim: int) -> np.int64:
+    """Key bits of the single coordinate ``x`` on ``axis``."""
+    row = np.zeros((1, ndim), dtype=np.int64)
+    row[0, axis] = x
+    return encode(row, ndim)[0]
+
+
+def neighbor_keys(keys: np.ndarray, axis: int, ndim: int, n: int,
+                  periodic: bool):
+    """Keys of the -1 and +1 neighbours along ``axis`` of in-domain
+    cells on an ``n``-wide axis, by dilated-integer arithmetic on the
+    axis's interleaved bits (the other axes' bits pass through).  At a
+    face a periodic axis wraps and any other saturates — what
+    ``tree.map_coords`` gives a +-1 offset for reflecting and outflow
+    alike.  ``n`` need not be a power of two (non-unit root)."""
+    mask = axis_bits((1 << (63 // ndim)) - 1, axis, ndim)
+    top = axis_bits(n - 1, axis, ndim)
+    ax = keys & mask
+    rest = keys ^ ax
+    dn = ((ax - 1) & mask) | rest
+    up = (((keys | ~mask) + 1) & mask) | rest
+    dn = np.where(ax == 0, rest | top if periodic else keys, dn)
+    up = np.where(ax == top, rest if periodic else keys, up)
+    return dn, up

@@ -89,6 +89,10 @@ class Octree:
         order = np.argsort(ks, kind="stable")
         self.levels[lvl] = OctLevel(lvl, ks[order], og[order])
 
+    def set_level_keys(self, lvl: int, ks: np.ndarray) -> None:
+        """``set_level`` from Morton keys already sorted and unique."""
+        self.levels[lvl] = OctLevel(lvl, ks, kmod.decode(ks, self.ndim))
+
     def has(self, lvl: int) -> bool:
         return lvl in self.levels and self.levels[lvl].noct > 0
 
