@@ -87,9 +87,6 @@ class FusedSpec(NamedTuple):
     # static cooling config; None disables the in-step cooling source
     # (``cooling_fine`` after ``godunov_fine``, amr/amr_step.f90:448-474)
     cool: Optional[object] = None
-    # per-level explicit comm schedule (SweepCommSpec or None); empty
-    # tuple = global-view GSPMD everywhere (the default)
-    comm: tuple = ()
     # capture per-cell face mass fluxes for the MC gas tracers
     # (godunov_fine.f90:685-715); hydro single-device path only
     want_flux: bool = False
@@ -134,13 +131,6 @@ def _advance_traced(u, dev, fg, dt, spec: FusedSpec, cool_tables=None):
     phi = ({l: jnp.zeros((u[l].shape[0], cfg.ndim, 2), u[l].dtype)
             for l in levels} if spec.want_flux else None)
 
-    def dx(l):
-        return spec.boxlen / (1 << l)
-
-    def shape(l):
-        root = spec.root or (1,) * cfg.ndim
-        return tuple(r << l for r in root[:cfg.ndim])
-
     def advance(i, dtl):
         from ramses_tpu.poisson.amr_solve import kick_flat
 
@@ -152,64 +142,9 @@ def _advance_traced(u, dev, fg, dt, spec: FusedSpec, cool_tables=None):
         if i + 1 < len(levels):
             advance(i + 1, 0.5 * dtl)
             advance(i + 1, 0.5 * dtl)
-        if spec.complete[i]:
-            sl = spec.slab[i] if spec.slab else None
-            if sl is not None:
-                # explicit slab-sharded formulation: shard-local bitperm
-                # + backend-dispatched ring halos with DMA overlap
-                # (parallel/dense_slab.py, dma_halo.py) — the GSPMD
-                # partitioner never sees the bit-interleaved transpose,
-                # so no involuntary full rematerialization
-                from ramses_tpu.parallel import dense_slab
-                out = dense_slab.dense_sweep_slab(
-                    u[l], d.get("ok_flat"), dtl, dx(l), sl, cfg,
-                    ret_flux=spec.want_flux)
-            else:
-                out = K.dense_sweep(u[l], d.get("inv_perm"),
-                                    d.get("perm"), d["ok_dense"], dtl,
-                                    dx(l), shape(l), spec.bspec, cfg,
-                                    ret_flux=spec.want_flux,
-                                    ndev=spec.ndev)
-            du = out[0] if spec.want_flux else out
-            if spec.want_flux:
-                phi[l] = phi[l] + out[1]
-            corr = None
-        elif spec.comm and spec.comm[i] is not None:
-            # explicit per-shard schedule (shard_map + backend-
-            # dispatched ring halos, deterministic owner-fold) —
-            # parallel/amr_comm.py
-            from ramses_tpu.parallel import amr_comm
-            du, unew[l - 1] = amr_comm.sweep_correct_explicit(
-                u[l], u[l - 1], unew[l - 1], d, dtl, dx(l), cfg,
-                spec.comm[i])
-            corr = None
-        elif spec.blocked and spec.blocked[i]:
-            # gather-fused blocked tile path: the compact Morton-tile
-            # batch replaces the ~(3^d)x-duplicated stencil gather
-            interp = K.interp_cells(u[l - 1], d["b_interp_cell"],
-                                    d["b_interp_nb"], d["b_interp_sgn"],
-                                    cfg, itype=spec.itype)
-            out = K.tile_sweep(
-                u[l], interp, d["tile_src"], d["tile_vsgn"], d["tile_ok"],
-                d["cell_tile"], d["cell_slot"], d["oct_tile"],
-                d["oct_slot"], dtl, dx(l), cfg, spec.block_shift,
-                ret_flux=spec.want_flux, pallas_ok=spec.pallas_tiles)
-            # pad cell rows index the kernels' appended zero column
-            # (maps.py), so du/phi pad rows are exactly 0 — no masking
-            du, corr = out[0], out[1]
-            if spec.want_flux:
-                phi[l] = phi[l] + out[2]
-        else:
-            interp = K.interp_cells(u[l - 1], d["interp_cell"],
-                                    d["interp_nb"], d["interp_sgn"], cfg,
-                                    itype=spec.itype)
-            out = K.level_sweep(
-                u[l], interp, d["stencil_src"], d["vsgn"], d["ok_ref"],
-                None, dtl, dx(l), cfg, ret_flux=spec.want_flux,
-                ndev=spec.ndev)
-            du, corr = out[0], out[1]
-            if spec.want_flux:
-                phi[l] = phi[l] + out[2]
+        du, corr, dphi = K.sweep_level(spec, i, u[l], u.get(l - 1), d, dtl)
+        if spec.want_flux:
+            phi[l] = phi[l] + dphi
         unew[l] = unew[l] + du
         if corr is not None and l > spec.lmin:
             unew[l - 1] = K.scatter_corrections(unew[l - 1], corr,
@@ -320,54 +255,9 @@ def _fused_flags(u, dev, spec: FusedSpec, eg, fls, itype: int):
     """Every level's gradient refinement criteria in ONE dispatch (the
     per-level ``hydro_refine`` kernels of ``flag_fine``); the host
     fetches the whole tuple with a single device round-trip."""
-    cfg = spec.cfg
-    root = spec.root or (1,) * cfg.ndim
-    out = []
-    for i, l in enumerate(spec.levels):
-        d = dev[l]
-        if spec.complete[i]:
-            sl = spec.slab[i] if spec.slab else None
-            if sl is not None:
-                from functools import partial as _partial
-
-                from ramses_tpu.parallel import dense_slab
-                fn = _partial(K._flags_fn(cfg), err_grad=eg, floors=fls,
-                              spatial0=0, cfg=cfg)
-                fl = dense_slab.dense_flags_slab(u[l], sl, fn,
-                                                 2 ** cfg.ndim)
-            else:
-                shp = tuple(r << l for r in root[:cfg.ndim])
-                fl = K.dense_refine_flags(u[l], d.get("inv_perm"),
-                                          d.get("perm"), eg,
-                                          fls, shp,
-                                          spec.bspec, cfg,
-                                          dx=spec.boxlen / (1 << l))
-        elif spec.blocked and spec.blocked[i]:
-            # flags reuse the blocked shared gather (tile batch)
-            if l == spec.lmin:
-                interp = jnp.zeros((d["b_interp_cell"].shape[0],
-                                    cfg.nvar), u[l].dtype)
-            else:
-                interp = K.interp_cells(u[l - 1], d["b_interp_cell"],
-                                        d["b_interp_nb"],
-                                        d["b_interp_sgn"],
-                                        cfg, itype=itype)
-            fl = K.tile_refine_flags(u[l], interp, d["tile_src"],
-                                     d["tile_vsgn"], d["cell_tile"],
-                                     d["cell_slot"], eg, fls, cfg,
-                                     spec.block_shift)
-        else:
-            if l == spec.lmin:
-                interp = jnp.zeros((d["interp_cell"].shape[0], cfg.nvar),
-                                   u[l].dtype)
-            else:
-                interp = K.interp_cells(u[l - 1], d["interp_cell"],
-                                        d["interp_nb"], d["interp_sgn"],
-                                        cfg, itype=itype)
-            fl = K.refine_flags(u[l], interp, d["stencil_src"], d["vsgn"],
-                                eg, fls, cfg)
-        out.append(fl)
-    return tuple(out)
+    return tuple(K.flags_level(spec, i, u[l], u.get(l - 1), dev[l], eg, fls,
+                               itype)
+                 for i, l in enumerate(spec.levels))
 
 
 @partial(jax.jit, static_argnames=("spec", "nsteps", "trace"),
@@ -541,9 +431,7 @@ class AmrSim:
     # gather-fused blocked tile sweep on partial levels: the universal
     # default — hydro, MHD (XLA tile formulation), load-balance layouts
     # (tables layout-composed at emission time), and row-sharded meshes
-    # all take it; only explicit-comm schedules keep the stencil path
-    # (their per-shard owner-fold owns the gather).  Attr so a solver
-    # family can still opt out wholesale.
+    # all take it.  Attr so a solver family can still opt out wholesale.
     _oct_blocked = True
     # solver families whose state layout differs from the hydro
     # [rho, mom, E, ...] convention opt out of the shared SF/sink passes
@@ -973,32 +861,19 @@ class AmrSim:
         row-permutation layouts at table-emission time
         (``balance.apply_layout_blocks``), and row-sharded meshes run the
         XLA tile formulation GSPMD can partition
-        (``FusedSpec.pallas_tiles``).  Documented carve-out: explicit
-        comm schedules keep the 6^d stencil path —
-        ``amr_comm.sweep_correct_explicit`` owns both the per-shard
-        gather and the deterministic owner-fold, and ``_advance_traced``
-        dispatches the comm branch before the blocked one."""
-        if not self._oct_blocked:
-            return False
-        if not bool(getattr(self.params.amr, "oct_blocking", True)):
-            return False
-        if getattr(self, "_comm_specs", {}):
-            return False
-        return True
+        (``FusedSpec.pallas_tiles``).  Off only for a family that opts
+        out (``_oct_blocked``) or ``&AMR_PARAMS oct_blocking=.false.``."""
+        return self._oct_blocked and bool(
+            getattr(self.params.amr, "oct_blocking", True))
 
     def _reads_stencil(self, l: int) -> bool:
         """Will anything that runs read PARTIAL level ``l``'s 6^d
         per-oct tables (``LevelMaps.stencil_src`` and company)?  The
         stencil sweep and flags do wherever the tile path is not
-        taken; explicit comm schedules are cut from them
-        (``amr_comm.build_sweep_comm`` — asked of the constructor's
-        flag, since the first build precedes the schedules
-        ``_block_level_ok`` looks at); the RT transport gathers its
-        partial-level rows through them (``rt/amr.py`` — asked of the
-        namelist, since ``rt_amr`` is attached once the first maps
-        exist)."""
+        taken; the RT transport gathers its partial-level rows through
+        them (``rt/amr.py`` — asked of the namelist, since ``rt_amr``
+        is attached once the first maps exist)."""
         return (not self._block_level_ok(l)
-                or bool(getattr(self, "_explicit_comm", False))
                 or (bool(self.params.run.rt)
                     and self._pm_family(self.cfg)))
 
@@ -1565,7 +1440,6 @@ class AmrSim:
     def _fused_spec(self) -> FusedSpec:
         if self._spec is None:
             lv = tuple(self.levels())
-            cspecs = getattr(self, "_comm_specs", {})
             self._spec = FusedSpec(
                 cfg=self.cfg, bspec=self.bspec, lmin=self.lmin,
                 boxlen=self.boxlen, levels=lv,
@@ -1573,13 +1447,10 @@ class AmrSim:
                 gravity=self.gravity,
                 itype=int(self.params.refine.interpol_type),
                 root=self.root, cool=self.cool_spec,
-                comm=(tuple(cspecs.get(l) for l in lv) if cspecs
-                      else ()),
                 want_flux=(self.tracer_x is not None
                            and len(self.tracer_x) > 0
                            and getattr(self.cfg, "physics",
-                                       "hydro") == "hydro"
-                           and not cspecs),
+                                       "hydro") == "hydro"),
                 ndev=int(self.ndev))
             slab = tuple(self._slab_spec(l) if self.maps[l].complete
                          else None for l in lv)
@@ -1595,35 +1466,31 @@ class AmrSim:
 
     def level_formulations(self) -> list:
         """[(level, name, on its Pallas kernel)] for the CURRENT fused
-        spec: the same gates, asked with the same arguments, as the
-        traced step (``_advance_traced`` → ``amr/kernels.py``,
-        ``parallel/dense_slab.py``)."""
+        spec: the formulation the traced step takes
+        (``K.level_kind``, as ``K.sweep_level`` asks it) and its
+        kernel gate, asked with the same arguments."""
         from ramses_tpu.hydro import pallas_muscl as pk
         from ramses_tpu.hydro import pallas_oct as po
         spec = self._fused_spec()
         cfg, dtype = spec.cfg, self.dtype
         out = []
         for i, l in enumerate(spec.levels):
-            if spec.complete[i]:
-                sl = spec.slab[i] if spec.slab else None
-                if sl is not None:
-                    cut = tuple(p is not None for p in sl.perms)
-                    kax = pk.shard_axes(cfg, sl.loc, cut, dtype)
-                    out.append((l, f"dense slab-sharded sweep (grid "
-                                f"{sl.grid}, halo {sl.backend}, per-shard "
-                                + (f"fused kernel axes {kax}" if kax
-                                   else "XLA update") + ")",
-                                kax is not None))
-                    continue
-                root = spec.root or (1,) * cfg.ndim
-                shape = tuple(r << l for r in root[:cfg.ndim])
-                k = pk.kernel_available(cfg, shape, spec.bspec.faces,
-                                        dtype, spec.ndev)
+            kind = K.level_kind(spec, i)
+            if kind == "slab":
+                sl = spec.slab[i]
+                cut = tuple(p is not None for p in sl.perms)
+                kax = pk.shard_axes(cfg, sl.loc, cut, dtype)
+                out.append((l, f"dense slab-sharded sweep (grid "
+                            f"{sl.grid}, halo {sl.backend}, per-shard "
+                            + (f"fused kernel axes {kax}" if kax
+                               else "XLA update") + ")",
+                            kax is not None))
+            elif kind == "dense":
+                k = pk.kernel_available(cfg, K.dense_shape(spec, l),
+                                        spec.bspec.faces, dtype, spec.ndev)
                 out.append((l, "dense fused kernel (pallas_muscl)" if k
                             else "dense XLA sweep", bool(k)))
-            elif spec.comm and spec.comm[i] is not None:
-                out.append((l, "explicit-comm stencil sweep (XLA)", False))
-            elif spec.blocked and spec.blocked[i]:
+            elif kind == "tile":
                 nt = self.blocks[l].ntile_pad
                 k = spec.pallas_tiles and po.tile_available(
                     cfg, nt, dtype, spec.block_shift)
@@ -1939,8 +1806,8 @@ class AmrSim:
                     # the fused step captured this step's face fluxes
                     ap.mc_tracer_amr(self)
                 else:
-                    # no flux capture on this path (MHD hierarchy,
-                    # explicit-comm sharding): velocity tracers
+                    # no flux capture on this path (MHD hierarchy):
+                    # velocity tracers
                     ap.tracer_drift_amr(self, dt)
         if self.movie is not None and self.nstep % self.movie_imov == 0:
             with self.timers.section("movie"):

@@ -771,3 +771,114 @@ def _grad_flags(uloc, err_grad, floors, spatial0: int, cfg: HydroStatic):
                 err = jnp.maximum(err, 2.0 * jnp.maximum(e1, e2))
             ok = ok | (err > egu)
     return ok
+
+
+# ----------------------------------------------------------------------
+# how is level l swept: the one place that decides.  Plain Python, not
+# jitted — the callers' traces (hierarchy._advance_traced /
+# _fused_flags, offload._seg_sweep / _seg_flags) inline them, and the
+# kernels they pick between keep their own jax.jit.  ``spec`` is a
+# hierarchy.FusedSpec, ``i`` an index into ``spec.levels``, ``d`` the
+# level's device maps (``sim.dev[l]``).
+# ----------------------------------------------------------------------
+def level_kind(spec, i: int) -> str:
+    """The formulation level ``spec.levels[i]`` takes.  A COMPLETE
+    level (it covers the box) runs as a dense grid: ``"slab"`` where
+    the mesh cut it into slabs (``spec.slab[i]``,
+    parallel/dense_slab.py), else ``"dense"``.  A PARTIAL level runs
+    the Morton-tile batch, ``"tile"`` (``spec.blocked[i]``), or the
+    per-oct 6^d stencil, ``"stencil"``."""
+    if spec.complete[i]:
+        return ("slab" if spec.slab and spec.slab[i] is not None
+                else "dense")
+    return "tile" if spec.blocked and spec.blocked[i] else "stencil"
+
+
+def dense_shape(spec, l: int) -> Tuple[int, ...]:
+    """Cells per dim of complete level ``l``: ``root[d]·2^l``."""
+    nd = spec.cfg.ndim
+    return tuple(r << l for r in (spec.root or (1,) * nd)[:nd])
+
+
+def _ghost_cells(spec, i: int, u_l, u_lm1, d, itype: int):
+    """Ghost values of a partial level's gather, prolonged from level
+    l-1 through the tile batch's ``b_interp_*`` tables or the
+    stencil's ``interp_*``; a base level has nothing coarser and reads
+    zeros (a base level is complete wherever it is swept)."""
+    pre = "b_" if level_kind(spec, i) == "tile" else ""
+    if spec.levels[i] == spec.lmin:
+        return jnp.zeros((d[pre + "interp_cell"].shape[0], spec.cfg.nvar),
+                         u_l.dtype)
+    return interp_cells(u_lm1, d[pre + "interp_cell"],
+                        d[pre + "interp_nb"], d[pre + "interp_sgn"],
+                        spec.cfg, itype=itype)
+
+
+def sweep_level(spec, i: int, u_l, u_lm1, d, dtl):
+    """One godunov sweep of level ``spec.levels[i]`` over ``dtl``:
+    ``(du, corr, phi)``.  ``corr`` is the coarse flux correction
+    (None on a complete level: nothing coarser borders it), ``phi``
+    the MC-tracer face mass flux (None unless ``spec.want_flux``).
+    ``u_lm1`` is read on partial levels only."""
+    cfg, l = spec.cfg, spec.levels[i]
+    dx = spec.boxlen / (1 << l)
+    kind = level_kind(spec, i)
+    if spec.complete[i]:
+        if kind == "slab":
+            # shard-local bitperm + ring halos with DMA overlap
+            # (parallel/dense_slab.py, dma_halo.py): the GSPMD
+            # partitioner never sees the bit-interleaved transpose,
+            # so no involuntary full rematerialization
+            from ramses_tpu.parallel import dense_slab
+            out = dense_slab.dense_sweep_slab(
+                u_l, d.get("ok_flat"), dtl, dx, spec.slab[i], cfg,
+                ret_flux=spec.want_flux)
+        else:
+            out = dense_sweep(u_l, d.get("inv_perm"), d.get("perm"),
+                              d["ok_dense"], dtl, dx, dense_shape(spec, l),
+                              spec.bspec, cfg, ret_flux=spec.want_flux,
+                              ndev=spec.ndev)
+        return (out[0], None, out[1]) if spec.want_flux \
+            else (out, None, None)
+    interp = _ghost_cells(spec, i, u_l, u_lm1, d, spec.itype)
+    if kind == "tile":
+        # the compact Morton-tile batch replaces the ~(3^d)x-duplicated
+        # stencil gather.  Pad cell rows index the kernels' appended
+        # zero column (maps.py), so du/phi pad rows are exactly 0
+        out = tile_sweep(
+            u_l, interp, d["tile_src"], d["tile_vsgn"], d["tile_ok"],
+            d["cell_tile"], d["cell_slot"], d["oct_tile"], d["oct_slot"],
+            dtl, dx, cfg, spec.block_shift, ret_flux=spec.want_flux,
+            pallas_ok=spec.pallas_tiles)
+    else:
+        out = level_sweep(u_l, interp, d["stencil_src"], d["vsgn"],
+                          d["ok_ref"], None, dtl, dx, cfg,
+                          ret_flux=spec.want_flux, ndev=spec.ndev)
+    return out[0], out[1], (out[2] if spec.want_flux else None)
+
+
+def flags_level(spec, i: int, u_l, u_lm1, d, eg, fls, itype: int):
+    """Gradient refinement flags ``[noct_pad, 2^d]`` of level
+    ``spec.levels[i]`` (``hydro_refine``), on the gather its sweep
+    takes (:func:`level_kind`)."""
+    cfg, l = spec.cfg, spec.levels[i]
+    kind = level_kind(spec, i)
+    if kind == "slab":
+        from ramses_tpu.parallel import dense_slab
+        fn = partial(_flags_fn(cfg), err_grad=eg, floors=fls, spatial0=0,
+                     cfg=cfg)
+        return dense_slab.dense_flags_slab(u_l, spec.slab[i], fn,
+                                           2 ** cfg.ndim)
+    if kind == "dense":
+        return dense_refine_flags(u_l, d.get("inv_perm"), d.get("perm"),
+                                  eg, fls, dense_shape(spec, l),
+                                  spec.bspec, cfg,
+                                  dx=spec.boxlen / (1 << l))
+    interp = _ghost_cells(spec, i, u_l, u_lm1, d, itype)
+    if kind == "tile":
+        return tile_refine_flags(u_l, interp, d["tile_src"],
+                                 d["tile_vsgn"], d["cell_tile"],
+                                 d["cell_slot"], eg, fls, cfg,
+                                 spec.block_shift)
+    return refine_flags(u_l, interp, d["stencil_src"], d["vsgn"], eg, fls,
+                        cfg)

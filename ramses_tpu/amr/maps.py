@@ -38,8 +38,8 @@ class LevelMaps:
     and the ``interp_*`` requests of their missing cells) exist only
     where something reads them: a partial level built with
     ``build_level_maps(stencil=True)``, i.e. one whose sweep and flags
-    run the stencil formulation (``oct_blocking=.false.``, explicit comm
-    schedules) or whose radiation transport gathers through it.  A
+    run the stencil formulation (``oct_blocking=.false.``) or whose
+    radiation transport gathers through it.  A
     level swept through the Morton tile tables (:class:`BlockMaps`) and
     a COMPLETE level carry them empty (``_no_stencil``: ``ni == 0``)."""
     lvl: int

@@ -173,38 +173,13 @@ def plan_schedule(spec) -> tuple:
 @partial(jax.jit, static_argnames=("spec", "i", "scale"))
 def _seg_sweep(u_l, u_lm1, unew_l, unew_lm1, d, dt, spec, i: int,
                scale: float):
-    cfg = spec.cfg
     l = spec.levels[i]
     dtl = dt * scale       # static power-of-two: bitwise == 0.5*dtl chain
-    dxl = spec.boxlen / (1 << l)
-    if spec.complete[i]:
-        root = spec.root or (1,) * cfg.ndim
-        shp = tuple(r << l for r in root[:cfg.ndim])
-        du = K.dense_sweep(u_l, d.get("inv_perm"), d.get("perm"),
-                           d["ok_dense"], dtl, dxl, shp, spec.bspec, cfg,
-                           ndev=spec.ndev)
-        corr = None
-    elif spec.blocked and spec.blocked[i]:
-        interp = K.interp_cells(u_lm1, d["b_interp_cell"],
-                                d["b_interp_nb"], d["b_interp_sgn"], cfg,
-                                itype=spec.itype)
-        out = K.tile_sweep(
-            u_l, interp, d["tile_src"], d["tile_vsgn"], d["tile_ok"],
-            d["cell_tile"], d["cell_slot"], d["oct_tile"], d["oct_slot"],
-            dtl, dxl, cfg, spec.block_shift,
-            pallas_ok=spec.pallas_tiles)
-        du, corr = out[0], out[1]
-    else:
-        interp = K.interp_cells(u_lm1, d["interp_cell"], d["interp_nb"],
-                                d["interp_sgn"], cfg, itype=spec.itype)
-        out = K.level_sweep(u_l, interp, d["stencil_src"], d["vsgn"],
-                            d["ok_ref"], None, dtl, dxl, cfg,
-                            ndev=spec.ndev)
-        du, corr = out[0], out[1]
+    du, corr, _ = K.sweep_level(spec, i, u_l, u_lm1, d, dtl)
     unew_l = unew_l + du
     if corr is not None and l > spec.lmin:
         unew_lm1 = K.scatter_corrections(unew_lm1, corr, d["corr_idx"],
-                                         cfg)
+                                         spec.cfg)
     return unew_l, unew_lm1
 
 
@@ -227,36 +202,7 @@ def _seg_courant(u_l, d, spec, i: int):
 def _seg_flags(u_l, u_lm1, d, spec, i: int, eg, fls, itype: int,
                ttd: int):
     """One level of ``hierarchy._fused_flags`` + the uint8 bitpack."""
-    cfg = spec.cfg
-    l = spec.levels[i]
-    if spec.complete[i]:
-        root = spec.root or (1,) * cfg.ndim
-        shp = tuple(r << l for r in root[:cfg.ndim])
-        fl = K.dense_refine_flags(u_l, d.get("inv_perm"), d.get("perm"),
-                                  eg, fls, shp, spec.bspec, cfg,
-                                  dx=spec.boxlen / (1 << l))
-    elif spec.blocked and spec.blocked[i]:
-        if l == spec.lmin:
-            interp = jnp.zeros((d["b_interp_cell"].shape[0], cfg.nvar),
-                               u_l.dtype)
-        else:
-            interp = K.interp_cells(u_lm1, d["b_interp_cell"],
-                                    d["b_interp_nb"], d["b_interp_sgn"],
-                                    cfg, itype=itype)
-        fl = K.tile_refine_flags(u_l, interp, d["tile_src"],
-                                 d["tile_vsgn"], d["cell_tile"],
-                                 d["cell_slot"], eg, fls, cfg,
-                                 spec.block_shift)
-    else:
-        if l == spec.lmin:
-            interp = jnp.zeros((d["interp_cell"].shape[0], cfg.nvar),
-                               u_l.dtype)
-        else:
-            interp = K.interp_cells(u_lm1, d["interp_cell"],
-                                    d["interp_nb"], d["interp_sgn"], cfg,
-                                    itype=itype)
-        fl = K.refine_flags(u_l, interp, d["stencil_src"], d["vsgn"], eg,
-                            fls, cfg)
+    fl = K.flags_level(spec, i, u_l, u_lm1, d, eg, fls, itype)
     shifts = jnp.arange(ttd, dtype=jnp.uint32)
     return (fl.astype(jnp.uint32) << shifts[None, :]).sum(
         axis=1).astype(jnp.uint8)
@@ -331,8 +277,7 @@ class OffloadEngine:
         """
         if not getattr(sim, "_offload_capable", False):
             return "solver family has its own step driver"
-        if getattr(sim, "ndev", 1) != 1 or getattr(sim, "_comm_specs",
-                                                   None):
+        if getattr(sim, "ndev", 1) != 1:
             return "multi-device mesh"
         checks = [(sim.gravity, "self-gravity"), (sim.pic, "particles"),
                   (sim.cosmo is not None, "cosmology"),

@@ -207,9 +207,7 @@ def _check_nondet_scatter(program) -> List[Finding]:
                  "unique_indices=false in a GSPMD-partitioned "
                  "program — the partitioner may reassociate the "
                  "float adds across shards (the MHD mesh-of-8 ~1-ulp "
-                 "drift); route through the deterministic owner-fold "
-                 "(amr_comm.sweep_correct_explicit) or mark indices "
-                 "unique"),
+                 "drift); mark indices unique or fold per owner"),
         key=ty, detail={"count": n, "result": ty})
         for ty, n in sorted(hits.items())]
 

@@ -236,13 +236,12 @@ def _build_hydro_amr_sharded() -> Optional[Program]:
     from ramses_tpu.config import params_from_string
     from ramses_tpu.parallel.amr_sharded import ShardedAmrSim
 
-    # default GSPMD mode on purpose (explicit_comm=False): this is the
-    # shape a plain multi-device run compiles, and it KEEPS one accepted
-    # nondeterministic-scatter finding — the blocked tile sweep folds
-    # the partial level's coarse corrections through a scatter-add the
-    # partitioner may reassociate.  The explicit_comm=True schedule
-    # routes that fold deterministically (amr_comm.sweep_correct_
-    # explicit) and is opted into per run, not audited here.
+    # the shape a plain multi-device run compiles; it KEEPS one
+    # accepted nondeterministic-scatter finding — the blocked tile
+    # sweep folds the partial level's coarse corrections through a
+    # scatter-add the partitioner may reassociate (it has repeated
+    # bitwise wherever asked: tests/test_sharding.py, the four-chip
+    # benchmark cell's laps).
     sim = ShardedAmrSim(
         params_from_string(SEDOV2D.format(blk=".true."), ndim=2),
         devices=jax.devices(), dtype=jnp.float32)

@@ -114,8 +114,7 @@ class AmrParams:
     # into Morton-aligned tiles of 2^oct_block_shift octs per side so
     # the stencil gather is one compact tile batch instead of a
     # ~(3^ndim)x duplicated per-oct batch (universal: hydro/rhd/MHD,
-    # load-balance layouts, and row-sharded meshes; explicit-comm
-    # schedules keep the stencil path)
+    # load-balance layouts, and row-sharded meshes)
     oct_blocking: bool = True
     oct_block_shift: int = 2
     # device-resident regrid migration (amr/device_regrid.py): derive

@@ -807,18 +807,8 @@ def _mhd_advance_traced(u, bf, dev, fg, dt, spec: FusedSpec):
                 # cell variables — exclude them; degenerate components
                 # (c >= ndim) are genuinely conserved and keep theirs
                 corr = corr.at[..., IBX:IBX + min(nd, NCOMP)].set(0.0)
-                if spec.comm and spec.comm[i] is not None:
-                    # sharded mesh with an explicit schedule: the CT
-                    # sweep stays global-view (staggered faces + child
-                    # EMF), but the coarse fold goes through the
-                    # deterministic owner-fold instead of a GSPMD
-                    # scatter-add (parallel/amr_comm.py)
-                    from ramses_tpu.parallel import amr_comm
-                    unew[l - 1] = amr_comm.fold_corrections_explicit(
-                        corr, unew[l - 1], d, spec.comm[i])
-                else:
-                    unew[l - 1] = K.scatter_corrections(
-                        unew[l - 1], corr, d["corr_idx"], cfg)
+                unew[l - 1] = K.scatter_corrections(
+                    unew[l - 1], corr, d["corr_idx"], cfg)
             bf[l] = bfn
         u[l] = unew[l]
         if spec.gravity:
@@ -1197,19 +1187,12 @@ class MhdAmrSim(AmrSim):
     def _fused_spec(self) -> FusedSpec:
         if self._spec is None:
             lv = tuple(self.levels())
-            cspecs = getattr(self, "_comm_specs", {})
             self._spec = FusedSpec(
                 cfg=self.mcfg, bspec=self.bspec, lmin=self.lmin,
                 boxlen=self.boxlen, levels=lv,
                 complete=tuple(self.maps[l].complete for l in lv),
                 gravity=self.gravity,
-                itype=int(self.params.refine.interpol_type),
-                # explicit-comm meshes: partial levels route the coarse
-                # correction fold through the deterministic owner-fold
-                # (fold_corrections_explicit) — the CT sweep itself
-                # stays global-view
-                comm=(tuple(cspecs.get(l) for l in lv) if cspecs
-                      else ()))
+                itype=int(self.params.refine.interpol_type))
             # slab-sharded complete levels: gradient flags AND the CT
             # advance (mhd_ct_slab — the EMF override scatters into
             # flat rows via emf_flat_idx, so no global index scatter

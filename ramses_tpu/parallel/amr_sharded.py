@@ -22,15 +22,11 @@ trailing-pad remainder of skewed partial levels.  The opt-in
 closes that: at regrid time each partial level's rows are re-laid-out
 as per-device contiguous Hilbert-key ranges whose summed cost
 (solver sweeps + particle counts) is balanced within the
-bucket-padding bound, and the explicit comm schedules below are
-rebuilt against the new cuts.
+bucket-padding bound.
 
-Two comm backends coexist: the default global-view formulation (GSPMD
-inserts the collectives) and, with ``explicit_comm=True``, precomputed
-per-shard halo schedules for partial levels — ring-offset halos plus a
-deterministic owner-fold, rebuilt at regrid like the reference's
-``build_comm`` (:mod:`ramses_tpu.parallel.amr_comm`; the uniform
-path's analogue is :mod:`ramses_tpu.parallel.halo`).  Complete levels
+Partial levels run the global-view formulation: the Morton-tile sweep
+over row-sharded tables, GSPMD inserting the collectives, the coarse
+flux corrections folded by its scatter-add.  Complete levels
 take the EXPLICIT slab-sharded dense path whenever the level is a
 fully periodic unpadded power-of-two cube on a power-of-two device
 count (:mod:`ramses_tpu.parallel.dense_slab`): shard-local bitperm +
@@ -39,7 +35,7 @@ transpose that previously degenerated to involuntary full
 rematerialization (MULTICHIP_r05).  Levels outside that envelope keep
 the global-view sweep with compiler-inserted collectives.
 
-Every explicit ring halo above rides the backend-dispatched exchange
+Every slab ring halo above rides the backend-dispatched exchange
 engine (:mod:`ramses_tpu.parallel.dma_halo`): Pallas async
 remote-copy DMA kernels with comm/compute overlap on TPU,
 ``lax.ppermute`` elsewhere, selected by the ``&AMR_PARAMS
@@ -60,7 +56,6 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ramses_tpu.amr.hierarchy import AmrSim
@@ -75,18 +70,15 @@ class ShardedAmrSim(AmrSim):
     # sweep too: tile tables are row-sharded like the stencil ones and
     # FusedSpec.pallas_tiles=False forces the XLA tile formulation, so
     # GSPMD partitions the compact tile batch the same way it used to
-    # partition the 6^d gather (explicit-comm schedules still take the
-    # stencil path — see AmrSim._block_level_ok)
+    # partition the 6^d gather
     _oct_blocked = True
 
     def __init__(self, params: Params,
                  devices: Optional[Sequence[jax.Device]] = None,
                  dtype=jnp.float32, particles=None, init_tree=None,
-                 init_dense_u=None, seed_tracers: bool = True,
-                 explicit_comm: bool = False):
+                 init_dense_u=None, seed_tracers: bool = True):
         devices = list(devices if devices is not None else jax.devices())
         self.ndev = len(devices)
-        self._explicit_comm = explicit_comm and len(devices) > 1
         self.mesh = oct_mesh(devices)
         self._row_sharding = NamedSharding(self.mesh, P("oct"))
         self._row2_sharding = NamedSharding(self.mesh, P("oct", None))
@@ -159,44 +151,6 @@ class ShardedAmrSim(AmrSim):
             b += self.ndev - (b % self.ndev)
             self._pad_hist[lvl] = b
         return b
-
-    def _rebuild_maps(self, old_tree=None, old_maps=None, old_dev=None):
-        """Base maps + the explicit per-shard comm schedules (the
-        ``build_comm`` analogue, parallel/amr_comm.py) for partial
-        levels when ``explicit_comm=True``."""
-        super()._rebuild_maps(old_tree, old_maps, old_dev)
-        if not self._explicit_comm:
-            return
-        from ramses_tpu.parallel import amr_comm
-        specs = getattr(self, "_comm_specs", {})
-        self._comm_specs = {}
-        for l, m in self.maps.items():
-            if m.complete or l <= self.lmin or l - 1 not in self.maps:
-                continue
-            if "comm" in self.dev[l] and l in specs:
-                self._comm_specs[l] = specs[l]     # reused with the maps
-                continue
-            built = amr_comm.build_sweep_comm(
-                m, self.maps[l - 1], self.ndev, self.mesh,
-                int(self.params.refine.interpol_type),
-                halo_backend=getattr(self.params.amr, "halo_backend",
-                                     "auto"))
-            if built is None:
-                # build_sweep_comm bails only for a 1-device mesh, and
-                # _explicit_comm requires ndev > 1 — anything else here
-                # would be a silent GSPMD fallback, so refuse loudly
-                raise RuntimeError(
-                    f"explicit comm schedule missing for partial level "
-                    f"{l} on a {self.ndev}-device mesh")
-            spec, arrays = built
-            self._comm_specs[l] = spec
-            sh = NamedSharding(self.mesh, P("oct"))
-            self.dev[l]["comm"] = {
-                k: jax.device_put(
-                    jnp.asarray(v, self.dtype if v.dtype == np.float64
-                                else None), sh)
-                for k, v in arrays.items()}
-        self._spec = None                          # comm is part of the key
 
     def _place(self, arr, kind: str):
         if kind == "rep":

@@ -16,9 +16,9 @@ so per-device summed cost is balanced within the bucket-padding bound.
 Layouts are applied *after* the tree-order map builders
 (`amr/maps.py`) as a pure index transform — ``apply_layout_level`` /
 ``apply_layout_gravity`` permute oct/cell rows and remap stored row
-values.  Because `parallel/amr_comm.py` derives ownership purely from
-``row // rows_per_device``, halo schedules built from transformed maps
-are automatically correct against the new cuts — no comm-layer changes.
+values.  Ownership is purely ``row // rows_per_device`` (the row
+sharding), so the programs GSPMD partitions from transformed maps are
+correct against the new cuts — no comm-layer changes.
 
 Complete levels always keep the identity layout: their dense bit-permute
 sweep path depends on lexicographic row order.

@@ -217,7 +217,7 @@ def _split_axis(spec: SlabSpec, ng: int) -> Optional[int]:
 
 def dense_sweep_slab(u_flat, ok_flat, dt, dx: float, spec: SlabSpec,
                      cfg, ret_flux: bool = False):
-    """Slab-sharded complete-level hydro sweep — the explicit-comm
+    """Slab-sharded complete-level hydro sweep — the shard_map
     formulation of :func:`ramses_tpu.amr.kernels.dense_sweep` (same
     physics, bitwise-identical du/phi).  ``ok_flat``: flat-row refined
     mask or None; ``dt`` traced scalar.  Returns du rows (+ phi rows

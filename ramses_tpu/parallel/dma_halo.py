@@ -20,8 +20,8 @@ flight) and thin boundary strips that wait for the ghosts
 (:func:`ramses_tpu.parallel.dense_slab.dense_sweep_slab`,
 :func:`ramses_tpu.parallel.halo.run_steps_halo`).
 
-Backend contract: :func:`permute` / :func:`exchange_slabs` are drop-in
-replacements for ``lax.ppermute`` with identical ring semantics —
+Backend contract: :func:`exchange_slabs` is a drop-in
+replacement for ``lax.ppermute`` with identical ring semantics —
 device ``dst`` receives ``src``'s operand for every ``(src, dst)`` pair
 — and the two backends agree BITWISE (pure data movement; asserted in
 ``tests/test_dma_halo.py`` under interpret mode).  Selection rides the
@@ -223,15 +223,6 @@ def exchange_slabs(sends: Sequence, perms: Sequence, axis_name: str,
         interpret = _interpret()
     dsts = [_dst_from_perm(p, axis_name) for p in perms]
     return _dma_exchange(list(sends), dsts, interpret)
-
-
-def permute(x, axis_name: str, perm, backend: str = "ppermute",
-            interpret=None):
-    """Drop-in ``lax.ppermute`` with backend dispatch + traffic
-    accounting (the single-direction form the explicit AMR comm
-    schedules use, :mod:`ramses_tpu.parallel.amr_comm`)."""
-    return exchange_slabs([x], [perm], axis_name, backend,
-                          interpret=interpret)[0]
 
 
 def exchange_pair(lo_send, hi_send, axis_name: str, fwd, bwd,

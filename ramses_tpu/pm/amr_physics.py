@@ -20,7 +20,7 @@ Level semantics:
   * gas tracers use the flux-probability MC scheme on the hierarchy
     (:func:`mc_tracer_amr`, ``pm/move_tracer.f90``) wherever the fused
     step captures face mass fluxes (hydro family); the MHD hierarchy
-    and explicit-comm sharded runs fall back to CIC velocity tracers
+    falls back to CIC velocity tracers
     (:func:`tracer_drift_amr`).
 """
 

@@ -111,11 +111,13 @@ def test_oct_sweep_compiles(one_chip, cfg, no_cache, noct):
              u, ok, dt)
 
 
-@pytest.mark.parametrize("ntile", [8, 64, 128, 1024])
+@pytest.mark.parametrize("ntile", [8, 64, 128, 256, 512, 1024])
 def test_tile_sweep_compiles(one_chip, cfg, no_cache, ntile):
     """Default ``oct_block_shift``; the tile buckets are powers of two
     >= 8, so 8/64 cover the whole-axis lane tile and 128/1024 the
-    128-lane tile (one and many grid steps)."""
+    128-lane tile (one and many grid steps); 256 and 512 are the
+    padded tile counts of levels 8 and 9 in the benchmark's AMR window
+    (PERF.md section 4)."""
     from ramses_tpu.config import AmrParams
     shift = AmrParams().oct_block_shift
     assert po.tile_shape_ok(ntile, shift)

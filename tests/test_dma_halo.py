@@ -535,62 +535,6 @@ def test_mhd_sim_refined_dma_vs_ppermute(dma):
 
 
 @needs8
-@pytest.mark.slow
-def test_mhd_sim_refined_explicit_fold_bitwise(dma):
-    """Refined 2D MHD with ``explicit_comm=True``: the partial level's
-    coarse correction fold routes through the deterministic owner-fold
-    (``amr_comm.fold_corrections_explicit``) instead of the GSPMD
-    scatter-add, so — unlike the default path pinned above at
-    ulp-tightness only — the sharded run is bitwise REPEATABLE and
-    bitwise identical across halo backends, while staying ulp-tight
-    against the mesh-of-1 serial fold."""
-    from ramses_tpu.config import load_params
-    from ramses_tpu.mhd.amr import MhdAmrSim
-    from ramses_tpu.parallel.amr_sharded import ShardedMhdAmrSim
-
-    def mk(cls, backend="dma", **kw):
-        p = load_params("namelists/tube_mhd.nml", ndim=2)
-        p.amr.levelmin, p.amr.levelmax = 4, 5
-        p.boundary.nboundary = 0
-        p.refine.err_grad_d = 0.02
-        p.refine.err_grad_p = 0.05
-        p.amr.halo_backend = backend
-        return cls(p, dtype=jnp.float64, **kw)
-
-    s1 = mk(MhdAmrSim)
-    s8d = mk(ShardedMhdAmrSim, "dma", devices=jax.devices(),
-             explicit_comm=True)
-    s8p = mk(ShardedMhdAmrSim, "ppermute", devices=jax.devices(),
-             explicit_comm=True)
-    s8r = mk(ShardedMhdAmrSim, "dma", devices=jax.devices(),
-             explicit_comm=True)                  # repeatability twin
-    for _ in range(3):
-        dt = min(s1.coarse_dt(), s8d.coarse_dt(), s8p.coarse_dt(),
-                 s8r.coarse_dt())
-        s1.step_coarse(dt)
-        s8d.step_coarse(dt)
-        s8p.step_coarse(dt)
-        s8r.step_coarse(dt)
-    assert s1.tree.noct(5) > 0
-    # the explicit fold is actually live on the partial level
-    spec = s8d._fused_spec()
-    assert spec.comm and any(c is not None for c in spec.comm)
-    for l in s1.levels():
-        np.testing.assert_array_equal(np.asarray(s8d.u[l]),
-                                      np.asarray(s8r.u[l]))
-        np.testing.assert_array_equal(np.asarray(s8d.u[l]),
-                                      np.asarray(s8p.u[l]))
-        np.testing.assert_array_equal(np.asarray(s8d.bfs[l]),
-                                      np.asarray(s8p.bfs[l]))
-        np.testing.assert_allclose(np.asarray(s1.u[l]),
-                                   np.asarray(s8d.u[l]),
-                                   rtol=1e-12, atol=1e-14)
-        np.testing.assert_allclose(np.asarray(s1.bfs[l]),
-                                   np.asarray(s8d.bfs[l]),
-                                   rtol=1e-12, atol=1e-14)
-
-
-@needs8
 def test_hydro_sim_shard_invariance_dma(dma):
     """The hydro precedent (tests/test_dense_slab.py) on the DMA
     backend: complete-level sedov, two coarse steps, bitwise."""

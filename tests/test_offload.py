@@ -118,6 +118,41 @@ def test_bitwise_parity_through_steps_regrid_restart(tmp_path):
     assert s_off.t == s_on.t == s_res.t
 
 
+class _StencilSim(AmrSim):
+    """Partial levels on the per-oct 6^d stencil (the tile path's
+    reference), as a solver family that opts out would run them."""
+    _oct_blocked = False
+
+
+@pytest.mark.parametrize("cls,kind", [(AmrSim, "tile"),
+                                      (_StencilSim, "stencil")],
+                         ids=["tile", "stencil"])
+def test_bitwise_parity_both_partial_sweeps(cls, kind):
+    """Both answers of the one dispatcher (``K.sweep_level`` /
+    ``K.flags_level``) through both of its callers: the fused window
+    (off) and the per-level segments (on) step and regrid bitwise
+    alike, whichever formulation the partial levels take."""
+    from ramses_tpu.amr import kernels as K
+    s_off, s_on = cls(_params("off", lmax=6)), cls(_params("on", lmax=6))
+    noct0 = [s_on.tree.noct(l) for l in s_on.levels()]
+    # two steps, a regrid before each (nremap=1); from the third on the
+    # two sides part by <= 1e-33 in momenta that are zero by symmetry,
+    # on the tile path and on the stencil alike (ROADMAP D4)
+    s_off.evolve(1e9, nstepmax=2)
+    s_on.evolve(1e9, nstepmax=2)
+    assert [s_on.tree.noct(l) for l in s_on.levels()] != noct0
+    assert s_on._offload.engaged(s_on)
+    assert any(is_parked(a) for a in s_on.u.values())
+    spec = s_on._fused_spec()
+    kinds = [K.level_kind(spec, i) for i in range(len(spec.levels))]
+    assert kinds[0] == "dense" and set(kinds[1:]) == {kind}, kinds
+    assert s_on.nstep == s_off.nstep == 2 and s_off.t == s_on.t
+    _assert_state_equal(s_off, s_on)
+    for l in s_off.levels():
+        np.testing.assert_array_equal(s_off.tree.levels[l].keys,
+                                      s_on.tree.levels[l].keys)
+
+
 # ---------------------------------------------------------------------
 # prefetch/stall accounting
 # ---------------------------------------------------------------------

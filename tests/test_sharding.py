@@ -143,3 +143,48 @@ def test_sharded_pm_matches_single_device():
                                rtol=1e-12, atol=1e-14)
     np.testing.assert_allclose(np.asarray(pp1.x), np.asarray(ss.p.x),
                                rtol=1e-12)
+
+
+# ------------------------------------------------- the sharded AMR fold
+
+def _params():
+    """2-D two-state problem on levels 3→5: a partial level whose
+    coarse flux corrections cross shard boundaries."""
+    nml = "\n".join([
+        "&RUN_PARAMS", "hydro=.true.", "/",
+        "&AMR_PARAMS", "levelmin=3", "levelmax=5", "boxlen=1.0", "/",
+        "&INIT_PARAMS", "nregion=2",
+        "region_type(1)='square'", "region_type(2)='square'",
+        "x_center=0.25,0.75", "length_x=0.5,0.5",
+        "exp_region=10.0,10.0", "d_region=1.0,0.125",
+        "p_region=1.0,0.1", "/",
+        "&HYDRO_PARAMS", "riemann='hllc'", "/",
+        "&REFINE_PARAMS", "err_grad_d=0.05", "err_grad_p=0.05", "/",
+        "&OUTPUT_PARAMS", "tend=0.01", "/",
+    ])
+    return params_from_string(nml, ndim=2)
+
+
+def test_default_fold_repeats_bitwise():
+    """The mesh folds a partial level's coarse flux corrections through
+    a GSPMD-partitioned scatter-add (the one fold there is): built and
+    stepped twice, every level's bytes are the same."""
+    from ramses_tpu.parallel.amr_sharded import ShardedAmrSim
+
+    assert len(jax.devices()) >= 8, "conftest must provide 8 CPU devices"
+
+    def run():
+        sim = ShardedAmrSim(_params(), devices=jax.devices()[:8],
+                            dtype=jnp.float64)
+        for _ in range(3):
+            sim.step_coarse(sim.coarse_dt())
+        return sim
+
+    a, b = run(), run()
+    partial = [l for l in a.levels()
+               if not a.maps[l].complete and l > a.lmin]
+    assert partial, "config must produce partial levels"
+    assert a.t == b.t and list(a.levels()) == list(b.levels())
+    for l in a.levels():
+        assert (np.asarray(a.u[l]).tobytes()
+                == np.asarray(b.u[l]).tobytes()), l

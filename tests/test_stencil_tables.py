@@ -3,7 +3,7 @@
 ``maps.build_level_maps`` builds ``stencil_src / vsgn / ok_ref /
 interp_*`` for a partial level when asked; ``AmrSim._rebuild_maps``
 asks iff the level's sweep and flags run the stencil formulation
-(``oct_blocking=.false.``, explicit comm schedules) or the RT transport
+(``oct_blocking=.false.``) or the RT transport
 gathers through it.  A level on the Morton-tile path has none of them,
 on the host or on the device, and steps bitwise as if it had.
 """
@@ -99,13 +99,6 @@ def _blocking_off():
     return _sedov(".false.", lmin=3, lmax=5)
 
 
-def _explicit_comm():
-    from ramses_tpu.parallel.amr_sharded import ShardedAmrSim
-    from tests.test_amr_comm import _devices, _params
-    return ShardedAmrSim(_params(), devices=_devices(), dtype=jnp.float64,
-                         explicit_comm=True)
-
-
 def _rt_coupled():
     from tests.test_rt_amr import _rt_groups
     refine = {"r_refine": [0.15] * 8, "x_refine": [0.5] * 8,
@@ -120,9 +113,8 @@ def _rt_coupled():
 # regrids build nothing; its step is cut short (the RT subcycle count
 # grows with dt) and is there to run the transport through the tables
 @pytest.mark.parametrize("make,dt_max,tree_changes", [
-    (_blocking_off, np.inf, True), (_explicit_comm, np.inf, True),
-    (_rt_coupled, 1e-4, False)],
-    ids=["oct_blocking_off", "explicit_comm", "rt"])
+    (_blocking_off, np.inf, True), (_rt_coupled, 1e-4, False)],
+    ids=["oct_blocking_off", "rt"])
 def test_stencil_readers_keep_their_tables(make, dt_max, tree_changes):
     sim = make()
     _assert_tables(sim, present=True)
