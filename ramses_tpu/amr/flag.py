@@ -8,6 +8,11 @@ any flagged cell x at level l+1 has a father-neighbourhood cell
 ``(x+e)>>1`` equal to it — this guarantees every surviving oct's 3^ndim
 father-cell stencil exists.
 
+The device hands the criteria over as one byte per oct (bit ``j`` = the
+oct's flat-offset cell ``j``); ``flagged_cells`` decodes only the bytes
+that are non-zero into the ascending flat-cell indices of the flagged
+cells, so no per-cell array of a whole level is formed on the host.
+
 The tree build works on sorted Morton cell keys end to end
 (``compute_new_tree``): a level's flagged cells are the keys of its
 flagged flat-cell indices, a 3^ndim dilation is ``ndim`` separable
@@ -20,7 +25,7 @@ decoded only for the new partial levels' ``og``.
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Optional
 
 import numpy as np
 
@@ -62,11 +67,33 @@ def geometry_flags(centers: np.ndarray, lvl: int, p: Params) -> np.ndarray:
     return rr < float(r.r_refine[i])
 
 
+def flagged_cells(packed: np.ndarray, ndim: int, noct: int,
+                  oct_row: Optional[np.ndarray] = None):
+    """Sparse decode of one level's bitpacked criteria flags.
+
+    ``packed``: uint8, one byte per oct ROW as fetched from the device
+    (bit ``j`` = flat-offset cell ``j`` of the oct; rows past ``noct`` are
+    padding).  ``oct_row``: tree oct -> row of a layout-permuted level,
+    else the first ``noct`` rows are the octs in tree order.  Returns
+    ``(cells, nocts)``: the ascending int64 flat-cell indices
+    ``oct << ndim | j`` of the flagged cells (``np.flatnonzero`` of the
+    level's per-cell mask) and how many octs held one.  Only the
+    non-zero bytes are unpacked."""
+    b = packed[oct_row] if oct_row is not None else packed[:noct]
+    o = np.flatnonzero(b)
+    ttd = 1 << ndim
+    bits = np.unpackbits(b[o, None], axis=1, count=ttd,
+                         bitorder="little").astype(bool)
+    cells = ((o[:, None] << ndim) | np.arange(ttd))[bits]
+    return cells, len(o)
+
+
 def compute_new_tree(tree: Octree, crit_flags: Dict[int, np.ndarray],
                      bc_kinds, params: Params) -> Octree:
-    """New octree from per-level per-cell criteria flags.
+    """New octree from per-level flagged cells.
 
-    ``crit_flags[l]``: bool [ncell_flat(l)] on the CURRENT tree.  Returns a
+    ``crit_flags[l]``: the ascending flat-cell indices (int64) of the
+    flagged cells of level l on the CURRENT tree.  Returns a
     tree whose level-(l+1) oct set is exactly the flagged cell set of level
     l after smoothing + nesting.  Its base level is ``tree``'s own
     ``OctLevel`` (complete and never mutated in place).
@@ -81,11 +108,13 @@ def compute_new_tree(tree: Octree, crit_flags: Dict[int, np.ndarray],
     # flagged cell keys per level (sorted), smoothed
     fkeys: Dict[int, np.ndarray] = {}
     for l in range(lmin, lmax + 1):
-        f = crit_flags.get(l)
-        if f is None or not tree.has(l):
+        i = crit_flags.get(l)
+        if i is None or not tree.has(l):
             fkeys[l] = np.zeros(0, dtype=np.int64)
             continue
-        i = np.flatnonzero(f)
+        if i.dtype == bool:    # a mask would index cells 0 and 1 silently
+            raise TypeError("compute_new_tree takes flagged-cell indices "
+                            "(np.flatnonzero of a mask), not the mask")
         ks = np.sort((tree.levels[l].keys[i >> ndim] << ndim)
                      | child[i & ((1 << ndim) - 1)])
         ne = nexpand[l - 1] if l - 1 < len(nexpand) else 1

@@ -243,8 +243,9 @@ def _mig_consts(ndim: int):
 @partial(jax.jit, static_argnames=("ttd",))
 def _pack_flag_bits(flags, ttd: int):
     """Bitpack per-oct refinement flags ([n, 2^d] bool each) into one
-    uint8 per oct, so the regrid flag fetch moves 2^d× fewer bytes over
-    the device-to-host link."""
+    uint8 per oct (bit j = cell j), so the regrid flag fetch moves 2^d×
+    fewer bytes over the device-to-host link and the host decodes only
+    the non-zero ones (``flag.flagged_cells``)."""
     shifts = jnp.arange(ttd, dtype=jnp.uint32)
     return tuple((fl.astype(jnp.uint32) << shifts[None, :])
                  .sum(axis=1).astype(jnp.uint8) for fl in flags)
@@ -1165,7 +1166,8 @@ class AmrSim:
         ttd = 2 ** self.tree_ndim
         # flags bitpacked on device (one uint8 per oct) so the single
         # flag fetch — the only device→host copy of a steady regrid —
-        # moves 2^d× fewer bytes; unpacked to per-cell bools below
+        # moves 2^d× fewer bytes; only the non-zero bytes are decoded
+        # below (``flagmod.flagged_cells``)
         if self._offload is not None and self._offload.engaged(self):
             # out-of-core: per-level flag segments so parked levels are
             # fetched one (plus interp source) at a time
@@ -1183,20 +1185,24 @@ class AmrSim:
         # owes (the previous coarse step only dispatched): its own span
         with self.timers.section("regrid: flag fetch"):
             flags = jax.device_get(flags)               # ONE trip
+        # flagged cells per level: ascending flat-cell indices
         crit: Dict[int, np.ndarray] = {}
-        for fl, l in zip(flags, spec.levels):
+        stats = {"octs_fetched": 0, "octs_flagged": 0, "cells_flagged": 0}
+        for packed, l in zip(flags, spec.levels):
             m = self.maps[l]
-            fl = ((np.asarray(fl)[:, None] >> np.arange(ttd)) & 1) \
-                .astype(bool)
-            if l in self.layouts:      # rows → tree oct order first
-                fl = fl[self.layouts[l].oct_row]
-            else:
-                fl = fl[:m.noct]
-            fl = fl.reshape(-1)                        # flat-cell order
+            ncell = m.noct * ttd
+            lay = self.layouts.get(l)      # rows → tree oct order first
+            fl, nocts = flagmod.flagged_cells(
+                packed, self.tree_ndim, m.noct,
+                lay.oct_row if lay is not None else None)
+            stats["octs_fetched"] += len(packed)
+            stats["octs_flagged"] += nocts
+            stats["cells_flagged"] += len(fl)
             i = l - 1                                  # 1-based level lists
             if i < len(r.r_refine) and r.r_refine[i] > 0.0:
-                fl = fl | flagmod.geometry_flags(
-                    self.tree.cell_centers(l, self.boxlen), l, self.params)
+                fl = np.union1d(fl, np.flatnonzero(flagmod.geometry_flags(
+                    self.tree.cell_centers(l, self.boxlen), l,
+                    self.params)))
             if self.pic and i < len(r.m_refine) and r.m_refine[i] >= 0.0:
                 # quasi-Lagrangian refinement (``flag_utils.f90``
                 # m_refine): flag cells holding more than m_refine mean
@@ -1204,21 +1210,22 @@ class AmrSim:
                 # density when available; deposit on demand otherwise
                 # (m_refine must not silently require poisson=.true.)
                 rho_dev = self._rho_dev.get(l)
-                if rho_dev is None or rho_dev.shape[0] < len(fl):
+                if rho_dev is None or rho_dev.shape[0] < ncell:
                     if not self._pm_dev:
                         self._build_pm()
                     if l in self._pm_dev:
                         rho_dev = (self.u[l][:, 0]
                                    + self._pm_rho(l).astype(
                                        self.u[l].dtype))
-                if rho_dev is not None and rho_dev.shape[0] >= len(fl):
+                if rho_dev is not None and rho_dev.shape[0] >= ncell:
                     mp = float(jnp.sum(self.p.m * self.p.active)) \
                         / max(int(jnp.sum(self.p.active)), 1)
                     thr = r.m_refine[i] * mp \
                         / self.dx(l) ** self.tree_ndim
-                    rho_np = self.tree_order_cells(rho_dev, l)[:len(fl)]
-                    fl = fl | (rho_np > thr)
+                    rho_np = self.tree_order_cells(rho_dev, l)[:ncell]
+                    fl = np.union1d(fl, np.flatnonzero(rho_np > thr))
             crit[l] = fl
+        self.flag_stats = stats
         with self.timers.section("regrid: tree build"):
             return flagmod.compute_new_tree(self.tree, crit, self.bc_kinds,
                                             self.params)

@@ -413,6 +413,11 @@ class Telemetry:
         if bst and "blocked_frac" in bst:
             # fraction of partial-level octs on the blocked tile sweep
             rec["blocked_frac"] = round(float(bst["blocked_frac"]), 4)
+        fst = getattr(sim, "flag_stats", None)
+        if fst:
+            # the newest regrid's flag decode: octs (bytes) fetched,
+            # non-zero ones decoded, flagged-cell indices produced
+            rec["flag_stats"] = dict(fst)
         off = getattr(sim, "_offload", None)
         ost = getattr(off, "last_step_stats", None)
         if ost is not None:

@@ -138,7 +138,7 @@ def _graded_tree(ndim, bc_kinds, seed, lmin=3, depth=2):
         ndim=ndim)
     tree = Octree.base(ndim, lmin, lmin + depth)
     for _ in range(depth):
-        flags = {l: rng.random(tree.noct(l) << ndim) < 0.04
+        flags = {l: np.flatnonzero(rng.random(tree.noct(l) << ndim) < 0.04)
                  for l in range(lmin, lmin + depth) if tree.has(l)}
         tree = flagmod.compute_new_tree(tree, flags, bc_kinds, params)
     assert tree.has(lmin + depth)

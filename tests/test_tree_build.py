@@ -102,6 +102,12 @@ def _flags(tree, mode, rng):
     return fl
 
 
+def _cells(flags):
+    """The program's input form: per level the ascending flat-cell
+    indices of the flagged cells (the oracle keeps taking the masks)."""
+    return {l: np.flatnonzero(f) for l, f in flags.items()}
+
+
 def _tree_and_flags(c):
     """A ``depth``-deep tree grown by the ORACLE from the base level
     (random flags, or flags on the faces), and the flags to compare."""
@@ -121,7 +127,7 @@ def _tree_and_flags(c):
 def test_tree_is_the_oracles(c):
     tree, flags, p = _tree_and_flags(c)
     want = oracle.compute_new_tree(tree, flags, c["bc"], p)
-    got = flagmod.compute_new_tree(tree, flags, c["bc"], p)
+    got = flagmod.compute_new_tree(tree, _cells(flags), c["bc"], p)
     assert sorted(got.levels) == sorted(want.levels)
     assert (got.ndim, got.levelmin, got.levelmax, got.root) == \
         (want.ndim, want.levelmin, want.levelmax, want.root)
@@ -140,7 +146,7 @@ def test_tree_is_the_oracles(c):
 @pytest.mark.parametrize("c", CASES)
 def test_tree_invariants(c):
     tree, flags, p = _tree_and_flags(c)
-    new = flagmod.compute_new_tree(tree, flags, c["bc"], p)
+    new = flagmod.compute_new_tree(tree, _cells(flags), c["bc"], p)
     ndim, lmin = tree.ndim, tree.levelmin
     # the complete base level is shared, not rebuilt: whoever mutates a
     # level's arrays in place breaks the OLD tree too, and fails here
