@@ -452,6 +452,10 @@ def run(args):
     tel = getattr(sim, "telemetry", None)
     if tel is not None:
         tel.close(sim)
+    if solver == "hydro":
+        from ramses_tpu.hydro import pallas_muscl
+        from ramses_tpu.telemetry import screen
+        print(screen.kernel_line(pallas_muscl.block_stats()))
     return sim
 
 

@@ -78,34 +78,69 @@ def supports(cfg: HydroStatic, shape, bc_kinds, dtype) -> bool:
             return False
     if dtype not in (jnp.float32, jnp.dtype("float32")):
         return False
-    nx, ny, nz = shape
-    if nz % 128 != 0 or nz > 1024:    # lane dim whole + VMEM budget
-        return False
-    bx, by = _pick_block(shape)
-    return bx is not None and by is not None
+    return _pick_block(shape)[0] is not None
 
 
 WY = 16  # y window: by + 4-cell halo, padded to the 8-sublane rule
 BY = 8   # y tile
 
+# The block budget, stated once: a tile is admitted when LIVE_WINDOWS
+# f32 arrays of its window ((bx+4) x WY x nz) fit the scoped VMEM that
+# every call asks Mosaic for (VMEM_LIMIT_BYTES: the ``CompilerParams``
+# value below).  LIVE_WINDOWS is a calibration, not a count read from
+# Mosaic: 384 keeps every pick that has run on the chip (nz 128 -> bx
+# 16, 256 -> 8, 384 -> 4: windows of 160-192 KiB a variable), admits
+# nz = 512 at bx 4 (256 KiB) and nothing wider.  Mosaic compiles
+# windows 2.5x larger under the same limit (bx 16 at 512^3); whether a
+# larger tile is FASTER is a measured choice for a perf issue with a
+# claim in every cell that runs the kernel (ROADMAP A5).
+VMEM_LIMIT_BYTES = 100 * 1024 * 1024
+LIVE_WINDOWS = 384
+
+
+def _window_fits(bx: int, nz: int) -> bool:
+    return (bx + 2 * NG) * WY * nz * 4 * LIVE_WINDOWS <= VMEM_LIMIT_BYTES
+
 
 def _pick_block(shape) -> Tuple[Optional[int], Optional[int]]:
-    """x/y tile sizes, sized to the VMEM budget.
+    """x/y tile sizes, or (None, None) where the kernel cannot tile
+    the box.
 
     Mosaic requires the last two block dims divisible by (8, 128): z is
-    always the full extent (lane dim); y uses a fixed 8-cell tile read
-    through a 16-cell window (2 halo + 2 junk per side); x is a free
-    (untiled) dim so its window is exactly bx+4.
+    always the full extent (lane dim, so a multiple of 128); y uses a
+    fixed 8-cell tile read through a 16-cell window (2 halo + 2 junk
+    per side); x is a free (untiled) dim so its window is exactly bx+4,
+    the largest the budget above admits.
     """
     nx, ny, nz = shape
-    if ny % BY:
+    if nz % 128 or ny % BY:
         return None, None
-    # per-variable block bytes ~ (bx+4)*WY*nz*4; ~45 live variables.
-    budget = 11 * 1024 * 1024 // (45 * 4 * nz * WY)     # cap on bx+4
     for bx in (32, 16, 8, 4):
-        if nx % bx == 0 and (bx + 2 * NG) <= budget:
+        if nx % bx == 0 and _window_fits(bx, nz):
             return bx, BY
     return None, None
+
+
+# Trace-time record of what the block rule picked, one per call
+# signature (jit caching: each signature traces once).  Read by
+# telemetry (``run_header.sweep_block``), the ``[kernel]`` screen line
+# and the benchmark's ``sweep_window_ratio``.
+_BLOCKS: dict = {}
+
+
+def _block_record(shape, masked: bool) -> dict:
+    bx, by = _pick_block(shape)
+    return {"shape": list(shape), "masked": masked, "bx": bx, "by": by,
+            "window_cells": (bx + 2 * NG) * WY * shape[2],
+            "written_cells": bx * by * shape[2]}
+
+
+def block_stats() -> list:
+    """``[{shape, masked, bx, by, window_cells, written_cells}]`` for
+    every (shape, masked) the kernel was traced for in this process:
+    each grid step loads and computes ``window_cells`` to write
+    ``written_cells``."""
+    return [dict(b) for b in _BLOCKS.values()]
 
 
 def _slopes(ql, q, qr, st: int, theta: float):
@@ -350,9 +385,10 @@ def fused_step_padded(u_pad, dt, cfg: HydroStatic, dx: float,
     """
     nx, ny, nz = shape
     bx, by = _pick_block(shape)
+    masked = ok_pad is not None
+    _BLOCKS[(shape, masked)] = _block_record(shape, masked)
     dt2 = jnp.asarray(dt, u_pad.dtype).reshape(1, 1)
-    kern = _make_kernel(cfg, dx, bx, by, ok_pad is not None, courant,
-                        want_flux)
+    kern = _make_kernel(cfg, dx, bx, by, masked, courant, want_flux)
     in_specs = [
         pl.BlockSpec(
             (pl.Element(5), pl.Element(bx + 2 * NG), pl.Element(WY), pl.Element(nz)),
@@ -395,7 +431,7 @@ def fused_step_padded(u_pad, dt, cfg: HydroStatic, dx: float,
         interpret=interpret,           # CPU parity tests
         name=name,                     # None: the kernel body's own
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(*args)
 
 
@@ -417,23 +453,12 @@ def shard_axes(cfg: HydroStatic, loc, cut, dtype):
         return None
     if jax.default_backend() != "tpu":
         return None
-    if getattr(cfg, "physics", "hydro") != "hydro" or cfg.ndim != 3:
-        return None
-    if cfg.nener != 0 or cfg.npassive != 0 or cfg.scheme != "muscl" \
-            or cfg.slope_type not in (1, 2, 8) or cfg.pressure_fix \
-            or cfg.riemann not in ("llf", "hllc"):
-        return None
-    if dtype not in (jnp.float32, jnp.dtype("float32")):
-        return None
+    periodic = ((0, 0),) * 3        # the slab path brings its own halos
     for az in (2, 1, 0):
         if cut[az]:
             continue
-        nz = loc[az]
-        if nz % 128 or nz > 1024:
-            continue
         a0, a1 = (d for d in range(3) if d != az)
-        bx, by = _pick_block((loc[a0], loc[a1], nz))
-        if bx is not None:
+        if supports(cfg, (loc[a0], loc[a1], loc[az]), periodic, dtype):
             return (a0, a1, az)
     return None
 

@@ -248,9 +248,12 @@ class Telemetry:
             # refresh the halo traffic counters at header-write time:
             # the header lands lazily with the first record, i.e. after
             # the first step traced, so the per-step traced byte counts
-            # are populated by now (they are zero at sim construction)
+            # are populated by now (they are zero at sim construction);
+            # so is what the fused sweep kernel's block rule picked
+            from ramses_tpu.hydro import pallas_muscl
             from ramses_tpu.parallel import dma_halo
             self.run_info.update(dma_halo.traffic_snapshot())
+            self.run_info["sweep_block"] = pallas_muscl.block_stats()
             header = {
                 "kind": "run_header",
                 "schema_version": SCHEMA_VERSION,

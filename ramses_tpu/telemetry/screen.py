@@ -43,6 +43,20 @@ def step_line(sim, dt: Optional[float] = None, chunk: int = 0,
     return line + ((" " + extra) if extra else "")
 
 
+def kernel_line(blocks) -> str:
+    """The ``[kernel]`` line: what the fused sweep kernel's block rule
+    picked for each signature it was traced for
+    (``pallas_muscl.block_stats()``), and the cells a grid step loads
+    and computes per cell it writes."""
+    if not blocks:
+        return "[kernel] pallas_muscl: not traced (XLA formulation)"
+    return "[kernel] pallas_muscl: " + "; ".join(
+        "{}{} bx={} by={} window/written={:.2f}".format(
+            "x".join(str(n) for n in b["shape"]),
+            " masked" if b["masked"] else "", b["bx"], b["by"],
+            b["window_cells"] / b["written_cells"]) for b in blocks)
+
+
 def control_block(sim, max_rss: float = 0.0,
                   dev_mb: Optional[float] = None,
                   audit: bool = False, extra: str = "") -> str:

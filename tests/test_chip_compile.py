@@ -74,29 +74,86 @@ def _compile(fn, *shapes):
     return compiled
 
 
+def _fits_one_chip(compiled):
+    """What the program keeps resident in HBM (args + outputs + temps)
+    fits one chip; the kernels ask for ``pk.VMEM_LIMIT_BYTES`` of
+    scoped VMEM, which Mosaic has already held them to by compiling."""
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes)
+    assert total < 16 * 2 ** 30
+
+
+PERIODIC = ((0, 0),) * 3
+
+
+@pytest.mark.parametrize("n,bx", [
+    (128, 16), (256, 8), (384, 4),    # the picks that have run on the chip
+    (512, 4),                         # admitted since PR 31
+    (640, None), (768, None), (1024, None), (192, None)])
+def test_block_rule_pinned(cfg, n, bx):
+    """The block budget's picks, pinned: a change to
+    ``LIVE_WINDOWS`` / ``VMEM_LIMIT_BYTES`` that moves one re-compiles
+    an existing benchmark cell's program and is a perf issue with a
+    claim (ROADMAP A5), not a side effect.  The mesh's level-7 slabs
+    relabel an uncut 128-cell axis to the lane: the 128 row's pick."""
+    assert pk._pick_block((n, n, n)) == (bx, pk.BY if bx else None)
+    assert pk.supports(cfg, (n, n, n), PERIODIC, F32) is (bx is not None)
+    if n == 128:
+        assert pk._pick_block((64, 64, 128)) == (16, pk.BY)
+        assert pk._pick_block((128, 64, 128)) == (16, pk.BY)
+
+
 @pytest.mark.parametrize("n,mask,want_flux", [
     (256, False, False),      # uniform sedov3d.nml step (courant fused)
     (128, True, False),       # AMR complete base level 7
     (128, True, True),        # ... with the MC-tracer flux capture
     (256, True, False),       # complete level 8
+    (384, False, False),      # the third lane extent the budget admits
+    (512, False, False),      # uniform levelmin=9 (the 512^3 cell)
+    (512, True, False),       # complete level 9
+    (512, True, True),        # ... with the flux capture
 ])
 def test_fused_step_padded_compiles(one_chip, cfg, no_cache, n, mask,
                                     want_flux):
     shape = (n, n, n)
-    kinds = ((0, 0),) * 3
-    assert pk.supports(cfg, shape, kinds, F32)
+    assert pk.supports(cfg, shape, PERIODIC, F32)
     pad = (n + 2 * pk.NG, n + pk.WY - pk.BY, n)
     u = jax.ShapeDtypeStruct((5,) + pad, F32, sharding=one_chip)
     dt = jax.ShapeDtypeStruct((), F32, sharding=one_chip)
     dx = 0.5 / n
     if mask:
         ok = jax.ShapeDtypeStruct(pad, F32, sharding=one_chip)
-        _compile(lambda u, ok, dt: pk.fused_step_padded(
+        compiled = _compile(lambda u, ok, dt: pk.fused_step_padded(
             u, dt, cfg, dx, shape, ok_pad=ok, want_flux=want_flux),
             u, ok, dt)
     else:
-        _compile(lambda u, dt: pk.fused_step_padded(
+        compiled = _compile(lambda u, dt: pk.fused_step_padded(
             u, dt, cfg, dx, shape, courant=True), u, dt)
+    _fits_one_chip(compiled)
+    rec = [b for b in pk.block_stats()
+           if b["shape"] == list(shape) and b["masked"] is mask]
+    assert len(rec) == 1 and rec[0]["bx"] == pk._pick_block(shape)[0]
+
+
+def test_run_steps_512_fits_one_chip(one_chip, cfg, no_cache):
+    """The whole 16-step program of the 512^3 run (what
+    ``driver.Simulation.evolve`` dispatches on the chip: scan, pad_xy,
+    kernel, in-scan Courant step): one kernel, and state + output +
+    temporaries inside one chip's HBM."""
+    from ramses_tpu.grid import boundary as bmod
+    from ramses_tpu.grid import uniform
+    n = 512
+    grid = uniform.UniformGrid(cfg=cfg, shape=(n, n, n), dx=0.5 / n,
+                               bc=bmod.BoundarySpec.periodic(3))
+    u = jax.ShapeDtypeStruct((5, n, n, n), F32, sharding=one_chip)
+    t = jax.ShapeDtypeStruct((), F32, sharding=one_chip)
+    compiled = _compile(
+        lambda u, t, tend: uniform._run_steps_pallas(grid, u, t, tend, 16),
+        u, t, t)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    _fits_one_chip(compiled)
 
 
 @pytest.mark.parametrize("noct", [128, 256, 512, 4096])
@@ -128,9 +185,4 @@ def test_tile_sweep_compiles(one_chip, cfg, no_cache, ntile):
     dt = jax.ShapeDtypeStruct((), F32, sharding=one_chip)
     compiled = _compile(lambda u, ok, dt: po.tile_sweep(
         u, ok, dt, cfg, 1.0 / 512, shift), u, ok, dt)
-    # the kernel asks for a 100 MiB scoped-VMEM limit; what the program
-    # keeps resident in HBM (args + outputs + temps) must fit one chip
-    ma = compiled.memory_analysis()
-    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-             + ma.temp_size_in_bytes)
-    assert total < 16 * 2 ** 30
+    _fits_one_chip(compiled)

@@ -18,6 +18,10 @@ from ramses_tpu.hydro.core import HydroStatic
 from ramses_tpu.config import Params
 
 SHAPE = (16, 16, 128)
+# a 512-cell lane axis: the widest the block budget admits (bx 4)
+SHAPE512 = (8, 16, 512)
+SHAPES = pytest.mark.parametrize("shape", [SHAPE, SHAPE512],
+                                 ids=["lane128", "lane512"])
 
 
 def _cfg(riemann="llf", slope_type=1):
@@ -27,11 +31,11 @@ def _cfg(riemann="llf", slope_type=1):
     return HydroStatic.from_params(p)
 
 
-def _state(cfg, seed=0):
+def _state(cfg, seed=0, shape=SHAPE):
     rng = np.random.default_rng(seed)
-    r = 1.0 + 0.3 * rng.random(SHAPE)
-    v = 0.2 * rng.standard_normal((3,) + SHAPE)
-    p_ = 0.5 + 0.2 * rng.random(SHAPE)
+    r = 1.0 + 0.3 * rng.random(shape)
+    v = 0.2 * rng.standard_normal((3,) + shape)
+    p_ = 0.5 + 0.2 * rng.random(shape)
     e = p_ / (cfg.gamma - 1.0) + 0.5 * r * (v ** 2).sum(axis=0)
     u = np.stack([r, r * v[0], r * v[1], r * v[2], e])
     return jnp.asarray(u, jnp.float32)
@@ -45,20 +49,27 @@ def _xla_step(u, dt, cfg, bc, dx):
 
 
 @pytest.mark.smoke
+@SHAPES
 @pytest.mark.parametrize("riemann", ["llf", "hllc"])
-def test_fused_step_matches_xla(riemann):
+def test_fused_step_matches_xla(riemann, shape):
     cfg = _cfg(riemann)
     bc = bmod.BoundarySpec.periodic(3)
     kinds = tuple((lo.kind, hi.kind) for lo, hi in bc.faces)
-    assert pk.supports(cfg, SHAPE, kinds, jnp.float32)
-    u = _state(cfg)
-    dx = 1.0 / SHAPE[0]
+    assert pk.supports(cfg, shape, kinds, jnp.float32)
+    u = _state(cfg, shape=shape)
+    dx = 1.0 / shape[0]
     dt = jnp.asarray(1e-3, jnp.float32)
     ref = _xla_step(u, dt, cfg, bc, dx)
     up, _ = pk.pad_xy(u, bc, cfg)
-    got = pk.fused_step_padded(up, dt, cfg, dx, SHAPE, interpret=True)
+    got = pk.fused_step_padded(up, dt, cfg, dx, shape, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
+    rec = [b for b in pk.block_stats()
+           if b["shape"] == list(shape) and not b["masked"]]
+    bx = {128: 16, 512: 4}[shape[2]]
+    assert len(rec) == 1 and (rec[0]["bx"], rec[0]["by"]) == (bx, 8)
+    assert rec[0]["window_cells"] == (bx + 4) * 16 * shape[2]
+    assert rec[0]["written_cells"] == bx * 8 * shape[2]
 
 
 def test_fused_step_reflecting_xy():
@@ -78,17 +89,17 @@ def test_fused_step_reflecting_xy():
                                rtol=2e-5, atol=2e-6)
 
 
-def test_fused_step_masked_matches_dense_sweep():
+@SHAPES
+@pytest.mark.parametrize("riemann", ["llf", "hllc"])
+def test_fused_step_masked_matches_dense_sweep(riemann, shape):
     """Refined-face flux zeroing (the AMR dense path's mask input)."""
-    from ramses_tpu.amr import kernels as K
-
-    cfg = _cfg("llf")
+    cfg = _cfg(riemann)
     bc = bmod.BoundarySpec.periodic(3)
-    u = _state(cfg, seed=7)
-    dx = 1.0 / SHAPE[0]
+    u = _state(cfg, seed=7, shape=shape)
+    dx = 1.0 / shape[0]
     dt = jnp.asarray(5e-4, jnp.float32)
     rng = np.random.default_rng(11)
-    ok = jnp.asarray(rng.random(SHAPE) < 0.1)
+    ok = jnp.asarray(rng.random(shape) < 0.1)
 
     # XLA oracle: the masked branch of dense_sweep
     up = bmod.pad(u, bc, cfg, muscl.NGHOST)
@@ -104,22 +115,23 @@ def test_fused_step_masked_matches_dense_sweep():
     ref = bmod.unpad(un, 3, muscl.NGHOST)
 
     upad, okpad = pk.pad_xy(u, bc, cfg, ok=ok)
-    got = pk.fused_step_padded(upad, dt, cfg, dx, SHAPE, ok_pad=okpad,
+    got = pk.fused_step_padded(upad, dt, cfg, dx, shape, ok_pad=okpad,
                                interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
 
 
-def test_fused_courant_matches_compute_dt():
+@SHAPES
+def test_fused_courant_matches_compute_dt(shape):
     from ramses_tpu.hydro.timestep import compute_dt
 
     cfg = _cfg("llf")
     bc = bmod.BoundarySpec.periodic(3)
-    u = _state(cfg, seed=5)
-    dx = 1.0 / SHAPE[0]
+    u = _state(cfg, seed=5, shape=shape)
+    dx = 1.0 / shape[0]
     dt = jnp.asarray(1e-3, jnp.float32)
     up, _ = pk.pad_xy(u, bc, cfg)
-    un, crt = pk.fused_step_padded(up, dt, cfg, dx, SHAPE, courant=True,
+    un, crt = pk.fused_step_padded(up, dt, cfg, dx, shape, courant=True,
                                    interpret=True)
     dtmax = cfg.courant_factor * dx / cfg.smallc
     want = float(compute_dt(un.astype(jnp.float32), None, dx, cfg))
@@ -292,3 +304,23 @@ def test_fused_step_shard_relabel_parity(want_flux):
         np.testing.assert_allclose(np.asarray(out_k[1]),
                                    np.asarray(out_r[1]),
                                    rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("loc,cut,want", [
+    ((128, 64, 64), (False, True, True), (1, 2, 0)),    # the mesh cell's level 7
+    ((128, 128, 64), (False, False, True), (0, 2, 1)),
+    ((512, 256, 256), (False, True, True), (1, 2, 0)),  # a complete level 9
+    ((640, 320, 320), (False, True, True), None),       # over the budget
+    ((128, 64, 64), (True, True, True), None),          # no uncut axis
+])
+def test_shard_axes_takes_the_kernels_gate(monkeypatch, loc, cut, want):
+    """``shard_axes`` gates to the TPU backend, so the CPU suite never
+    reaches its body: name the backend here and hold its picks — the
+    lane role goes to the last uncut axis whose relabelled box
+    ``supports()`` admits (the same block budget as every other call)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _cfg("llf")
+    assert pk.shard_axes(cfg, loc, cut, jnp.float32) == want
+    assert pk.shard_axes(cfg, loc, cut, jnp.bfloat16) is None
+    assert pk.shard_axes(_cfg("llf", slope_type=3), loc, cut,
+                         jnp.float32) is None
