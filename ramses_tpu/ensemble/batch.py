@@ -15,9 +15,11 @@ compiles exactly once (``platform.enable_compile_cache`` makes even that
 cold-start O(load) for a known namelist).
 
 Per-member time is carried as a batched ``t[B]`` array and completion is
-the per-step ``t < tend`` mask already inside every ``run_steps`` scan —
-under vmap it becomes a per-member ``lax.select``, so finished members
-idle cheaply until their sub-batch drains.
+the per-step ``t < tend`` test already inside every ``run_steps`` loop —
+the scans' mask becomes a per-member ``lax.select`` under vmap, and so
+does the carry of the fused-kernel path's ``while_loop``, which runs
+until the last member is done — so finished members idle cheaply until
+their sub-batch drains.
 """
 
 from __future__ import annotations

@@ -2,10 +2,11 @@
 
 The degenerate one-level octree of SURVEY.md §7 stage 2: the whole grid is
 one dense device array, a full step is one fused XLA program
-(pad → ctoprim → slopes → trace → riemann → update), and N steps run as a
-``lax.scan`` with zero host round-trips — the design replaces the
-per-nvector-batch sweep of ``godunov_fine`` (``hydro/godunov_fine.f90:5-35``)
-with whole-grid fusion.
+(pad → ctoprim → slopes → trace → riemann → update), and N steps run as
+one device loop with zero host round-trips (a ``lax.scan``; on the fused
+Pallas kernel a ``lax.while_loop`` that ends when no step is owed) — the
+design replaces the per-nvector-batch sweep of ``godunov_fine``
+(``hydro/godunov_fine.f90:5-35``) with whole-grid fusion.
 """
 
 from __future__ import annotations
@@ -105,18 +106,22 @@ def run_steps(grid: UniformGrid, u, t, tend, nsteps: int,
     """Advance up to ``nsteps`` steps entirely on device.
 
     dt is recomputed each step (``courant_fine``), clipped to land exactly
-    on ``tend``; steps past ``tend`` are no-ops.  Returns (u, t, n_done);
-    ``trace=True`` (telemetry-instrumented runs) additionally stacks
-    per-step ``(t_after, dt)`` scan outputs so the driver can emit one
-    record per coarse step from a single summary fetch.
+    on ``tend``; steps past ``tend`` are masked no-ops of the scan below
+    and are not run at all on the kernel path (same results).  Returns
+    (u, t, n_done); ``trace=True`` (telemetry-instrumented runs)
+    additionally returns the per-step ``(t_after, dt)`` history,
+    ``[nsteps]`` each (rows past ``n_done``: the final ``t``, ``dt`` 0),
+    so the driver can emit one record per coarse step from a single
+    summary fetch.
 
     ``dt_scale < 1`` shrinks every Courant dt by that factor — the
     redo-step retry ladder (resilience/stepguard) re-runs a tripped
     window at halved dt, mirroring the reference's dtnew halving.
 
-    On the Pallas path the Courant reduction of the updated state comes
-    out of the step kernel itself (free — the primitives are already in
-    VMEM), so each iteration is exactly one kernel launch.
+    On the Pallas path (:func:`_run_steps_pallas`) the Courant reduction
+    of the updated state comes out of the step kernel itself (free — the
+    primitives are already in VMEM), so each iteration is ``pad_xy`` and
+    exactly one kernel launch.
     """
     if _pallas_ok(grid, u.dtype):
         return _run_steps_pallas(grid, u, t, tend, nsteps, trace=trace,
@@ -144,32 +149,43 @@ def run_steps(grid: UniformGrid, u, t, tend, nsteps: int,
 @partial(jax.jit, static_argnames=("grid", "nsteps", "trace", "dt_scale"))
 def _run_steps_pallas(grid: UniformGrid, u, t, tend, nsteps: int,
                       trace: bool = False, dt_scale: float = 1.0):
+    """:func:`run_steps` on the fused kernel: a ``lax.while_loop`` that
+    runs a step only while one is owed (``ndone < nsteps`` and
+    ``t < tend``), so no pass over the state masks a step out — the
+    loop body is ``pad_xy`` and the kernel, nothing else of the state's
+    size.  Same results as the scan form, bit for bit; with
+    ``trace=True`` the history rows past ``ndone`` hold the final ``t``
+    and a zero ``dt``."""
     from ramses_tpu.hydro import pallas_muscl as pk
 
     cfg = grid.cfg
     dtmax = cfg.courant_factor * grid.dx / cfg.smallc
     dt0 = compute_dt(u, None, grid.dx, cfg) * dt_scale
 
-    def body(carry, _):
-        u, t, ndone, dtc = carry
+    def owed(carry):
+        _, t, ndone = carry[:3]
+        return (ndone < nsteps) & (t < tend)
+
+    def body(carry):
+        u, t, ndone, dtc = carry[:4]
         dt = jnp.minimum(dtc, jnp.maximum(tend - t, 0.0))
-        active = t < tend
         up, _ = pk.pad_xy(u, grid.bc, cfg)
-        un, crt = pk.fused_step_padded(up, jnp.where(active, dt, 0.0),
-                                       cfg, grid.dx, grid.shape,
+        un, crt = pk.fused_step_padded(up, dt, cfg, grid.dx, grid.shape,
                                        courant=True)
         dtn = jnp.minimum(dtmax, crt[0, 0] * dt_scale)
-        u = jnp.where(active, un, u)
-        t = jnp.where(active, t + dt, t)
-        dtc = jnp.where(active, dtn, dtc)
-        ndone = ndone + jnp.where(active, 1, 0)
-        ys = (t, jnp.where(active, dt, 0.0)) if trace else None
-        return (u, t, ndone, dtc), ys
+        t = t + dt
+        hist = tuple(h.at[ndone].set(v)
+                     for h, v in zip(carry[4:], (t, dt)))
+        return (un, t, ndone + 1, dtn) + hist
 
-    (u, t, ndone, _), hist = jax.lax.scan(
-        body, (u, t, jnp.array(0), dt0), None, length=nsteps)
+    carry = (u, t, jnp.array(0), dt0)
+    if trace:       # (t_after, dt) of each step, written by index
+        carry += (jnp.zeros(nsteps, t.dtype),
+                  jnp.zeros(nsteps, jnp.result_type(dt0, tend, t)))
+    u, t, ndone, _, *hist = jax.lax.while_loop(owed, body, carry)
     if trace:
-        return u, t, ndone, hist
+        t_hist = jnp.where(jnp.arange(nsteps) < ndone, hist[0], t)
+        return u, t, ndone, (t_hist, hist[1])
     return u, t, ndone
 
 
@@ -230,8 +246,10 @@ def run_steps_batch(grid: UniformGrid, u, t, tend, nsteps: int,
     ``u`` is ``[B, nvar, *sp]``, ``t``/``tend`` are ``[B]`` — one
     compiled program advances every member; the per-step
     ``active = t < tend`` masking inside :func:`run_steps` becomes a
-    per-member ``lax.select`` under vmap, so members that reach their
-    own ``tend`` idle cheaply until the batch drains.  Returns
+    per-member ``lax.select`` under vmap (the kernel path's
+    ``while_loop`` runs until the last member is done and vmap masks
+    each finished member's carry the same way), so members that reach
+    their own ``tend`` idle cheaply until the batch drains.  Returns
     ``(u, t, ndone)`` with ``ndone[B]`` counting each member's real
     steps.  ``summarize=True`` (batched step-guard armed) additionally
     returns the :func:`batch_summary` ``[B, 3]``.  The batch shares

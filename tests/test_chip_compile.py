@@ -17,6 +17,7 @@ them (a described-device entry cannot be read back without a chip).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -136,23 +137,58 @@ def test_fused_step_padded_compiles(one_chip, cfg, no_cache, n, mask,
     assert len(rec) == 1 and rec[0]["bx"] == pk._pick_block(shape)[0]
 
 
-def test_run_steps_512_fits_one_chip(one_chip, cfg, no_cache):
-    """The whole 16-step program of the 512^3 run (what
-    ``driver.Simulation.evolve`` dispatches on the chip: scan, pad_xy,
-    kernel, in-scan Courant step): one kernel, and state + output +
-    temporaries inside one chip's HBM."""
+def _computation(hlo: str, name: str) -> str:
+    """The text of one named computation of a compiled module."""
+    m = re.search(r"^(?:ENTRY )?%s \(.*?^}" % re.escape(name), hlo,
+                  re.M | re.S)
+    assert m, name
+    return m.group(0)
+
+
+def _state_ops(comp: str, n: int) -> list:
+    """Opcodes of a computation's instructions whose result is ONE
+    array of the state's shape ``f32[5,n,n,n]`` (the kernel's result is
+    a tuple with the Courant scalar: it is not among them)."""
+    return re.findall(r"^\s*(?:ROOT )?%%\S+ = f32\[5,%d,%d,%d\]\S* ([\w-]+)\("
+                      % (n, n, n), comp, re.M)
+
+
+@pytest.mark.parametrize("n,trace", [(256, False), (256, True),
+                                     (512, False)])
+def test_run_steps_loop_body_is_pad_and_kernel(one_chip, cfg, no_cache, n,
+                                               trace):
+    """The whole 16-step program of a uniform run (what
+    ``driver.Simulation.evolve`` dispatches on the chip; both uniform
+    benchmark cells): ONE kernel, and inside the while body no pass
+    over the state but ``pad_xy`` and that kernel — no ``select``
+    masking a step out, no ``copy`` of the carry (the kernel's output
+    IS the next carry).  At entry the not-donated argument is copied
+    into the carry once.  The temporaries are then the padded state and
+    little else, and state + output + temporaries fit one chip."""
     from ramses_tpu.grid import boundary as bmod
     from ramses_tpu.grid import uniform
-    n = 512
     grid = uniform.UniformGrid(cfg=cfg, shape=(n, n, n), dx=0.5 / n,
                                bc=bmod.BoundarySpec.periodic(3))
     u = jax.ShapeDtypeStruct((5, n, n, n), F32, sharding=one_chip)
     t = jax.ShapeDtypeStruct((), F32, sharding=one_chip)
     compiled = _compile(
-        lambda u, t, tend: uniform._run_steps_pallas(grid, u, t, tend, 16),
+        lambda u, t, tend: uniform._run_steps_pallas(grid, u, t, tend, 16,
+                                                     trace=trace),
         u, t, t)
-    assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"') == 1
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    entry = _computation(hlo, re.search(r"^ENTRY (%\S+)", hlo, re.M)[1])
+    whiles = re.findall(r" while\(.*?body=(%[\w.-]+)", entry)
+    assert len(whiles) == 1
+    body = _computation(hlo, whiles[0])
+    assert "tpu_custom_call" in body
+    assert set(_state_ops(body, n)) <= {"get-tuple-element"}
+    entry_ops = _state_ops(entry, n)
+    assert entry_ops.count("copy") <= 1
+    assert set(entry_ops) <= {"parameter", "copy", "get-tuple-element"}
+    padded = 5 * (n + 2 * pk.NG) * (n + pk.WY - pk.BY) * n * 4
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.1 * padded + 16 * 2 ** 20
     _fits_one_chip(compiled)
 
 

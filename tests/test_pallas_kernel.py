@@ -7,14 +7,18 @@ whole-grid XLA pipeline (``grid.uniform.step`` internals) that the TPU
 kernel replaces — both implement ``hydro/umuscl.f90:22-171``.
 """
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from ramses_tpu.grid import boundary as bmod
+from ramses_tpu.grid import uniform
 from ramses_tpu.hydro import muscl, pallas_muscl as pk
 from ramses_tpu.hydro.core import HydroStatic
+from ramses_tpu.hydro.timestep import compute_dt
 from ramses_tpu.config import Params
 
 SHAPE = (16, 16, 128)
@@ -123,8 +127,6 @@ def test_fused_step_masked_matches_dense_sweep(riemann, shape):
 
 @SHAPES
 def test_fused_courant_matches_compute_dt(shape):
-    from ramses_tpu.hydro.timestep import compute_dt
-
     cfg = _cfg("llf")
     bc = bmod.BoundarySpec.periodic(3)
     u = _state(cfg, seed=5, shape=shape)
@@ -324,3 +326,145 @@ def test_shard_axes_takes_the_kernels_gate(monkeypatch, loc, cut, want):
     assert pk.shard_axes(cfg, loc, cut, jnp.bfloat16) is None
     assert pk.shard_axes(_cfg("llf", slope_type=3), loc, cut,
                          jnp.float32) is None
+
+
+# ---------------------------------------------------------------------------
+# the fused uniform step program (grid/uniform._run_steps_pallas): the
+# loop round the kernel.  The CPU suite never reaches it through
+# ``run_steps`` (``kernel_available`` is false off the TPU), so it is
+# driven directly with the kernel patched to interpreter mode, against
+# the scan-with-masks form it replaced (kept here, nowhere else).
+# ---------------------------------------------------------------------------
+
+LOOP_SHAPE = (8, 16, 128)
+
+
+@pytest.fixture()
+def interpreted_kernel(monkeypatch):
+    real = pk.fused_step_padded
+    monkeypatch.setattr(
+        pk, "fused_step_padded",
+        lambda *a, **kw: real(*a, interpret=True, **kw))
+
+
+def _loop_grid(riemann):
+    return uniform.UniformGrid(
+        cfg=_cfg(riemann), shape=LOOP_SHAPE, dx=1.0 / LOOP_SHAPE[0],
+        bc=bmod.BoundarySpec.periodic(3))
+
+
+@partial(jax.jit, static_argnames=("grid", "nsteps", "trace", "dt_scale"))
+def _run_steps_scan(grid, u, t, tend, nsteps, trace=False, dt_scale=1.0):
+    """The masked ``lax.scan`` form of the fused step program as it
+    stood through PR 31: every step runs, an inactive one is selected
+    away."""
+    cfg = grid.cfg
+    dtmax = cfg.courant_factor * grid.dx / cfg.smallc
+    dt0 = compute_dt(u, None, grid.dx, cfg) * dt_scale
+
+    def body(carry, _):
+        u, t, ndone, dtc = carry
+        dt = jnp.minimum(dtc, jnp.maximum(tend - t, 0.0))
+        active = t < tend
+        up, _ = pk.pad_xy(u, grid.bc, cfg)
+        un, crt = pk.fused_step_padded(up, jnp.where(active, dt, 0.0),
+                                       cfg, grid.dx, grid.shape,
+                                       courant=True)
+        dtn = jnp.minimum(dtmax, crt[0, 0] * dt_scale)
+        u = jnp.where(active, un, u)
+        t = jnp.where(active, t + dt, t)
+        dtc = jnp.where(active, dtn, dtc)
+        ndone = ndone + jnp.where(active, 1, 0)
+        ys = (t, jnp.where(active, dt, 0.0)) if trace else None
+        return (u, t, ndone, dtc), ys
+
+    (u, t, ndone, _), hist = jax.lax.scan(
+        body, (u, t, jnp.array(0), dt0), None, length=nsteps)
+    if trace:
+        return u, t, ndone, hist
+    return u, t, ndone
+
+
+def _same_bits(got, want):
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def _first_dt(grid, u, dt_scale=1.0):
+    return float(compute_dt(u, None, grid.dx, grid.cfg)) * dt_scale
+
+
+# (id, nsteps, tend in units of the first step's dt or None = far away,
+#  steps expected, dt_scale, dtype of the time axis)
+LOOP_CASES = [
+    ("all16", 16, None, 16, 1.0, jnp.float32),
+    ("tend-after-3-of-8", 8, 2.5, 3, 1.0, jnp.float32),
+    ("tend-not-after-t", 8, 0.0, 0, 1.0, jnp.float32),
+    ("half-dt", 8, None, 8, 0.5, jnp.float32),
+    ("f64-time-3-of-8", 8, 2.5, 3, 1.0, jnp.float64),
+]
+
+
+@pytest.mark.parametrize("trace", [False, True], ids=["plain", "trace"])
+@pytest.mark.parametrize("riemann", ["llf", "hllc"])
+@pytest.mark.parametrize("case", LOOP_CASES, ids=[c[0] for c in LOOP_CASES])
+def test_run_steps_pallas_matches_scan(interpreted_kernel, case, riemann,
+                                       trace):
+    """``_run_steps_pallas`` (a ``while_loop`` that stops when no step
+    is owed) against the scan that masked such steps out: ``(u, t,
+    ndone)`` and the ``(t_after, dt)`` history bit for bit."""
+    _, nsteps, tend_dts, want_n, dt_scale, tdtype = case
+    grid = _loop_grid(riemann)
+    u = _state(grid.cfg, seed=13, shape=LOOP_SHAPE)
+    t0 = 0.25
+    tend = 1e9 if tend_dts is None else t0 + tend_dts * _first_dt(
+        grid, u, dt_scale)
+    t, tend = jnp.asarray(t0, tdtype), jnp.asarray(tend, tdtype)
+    got = uniform._run_steps_pallas(grid, u, t, tend, nsteps, trace=trace,
+                                    dt_scale=dt_scale)
+    want = _run_steps_scan(grid, u, t, tend, nsteps, trace=trace,
+                           dt_scale=dt_scale)
+    _same_bits(got, want)
+    assert int(got[2]) == want_n
+    if tend_dts is not None:
+        # the clip lands on tend exactly; no step: the input comes back
+        assert got[1] == (tend if want_n else t)
+    if want_n == 0:
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(u))
+    if trace:
+        t_hist, dt_hist = (np.asarray(h) for h in got[3])
+        assert t_hist.shape == dt_hist.shape == (nsteps,)
+        assert (dt_hist[:want_n] > 0).all() and (dt_hist[want_n:] == 0).all()
+        assert (t_hist[want_n:] == np.asarray(got[1])).all()
+
+
+@pytest.mark.parametrize("riemann", ["llf", "hllc"])
+def test_run_steps_pallas_vmap_members_equal_solo(interpreted_kernel,
+                                                  riemann):
+    """``run_steps_batch``'s use: ``jax.vmap`` of the loop over members
+    with different ``tend``s runs until the last member is done and
+    leaves each member what its solo run gives (and what the vmapped
+    scan gave)."""
+    grid = _loop_grid(riemann)
+    nsteps = 8
+    us = jnp.stack([_state(grid.cfg, seed=s, shape=LOOP_SHAPE)
+                    for s in (31, 32, 33)])
+    ts = jnp.asarray([0.0, 0.5, 0.25], jnp.float32)
+    # member 0 owes all 8 steps, member 1 none, member 2 three
+    tends = jnp.asarray([1e9, 0.5, 0.25 + 2.5 * _first_dt(grid, us[2])],
+                        jnp.float32)
+
+    def batch(fn):
+        return jax.vmap(lambda u, t, te: fn(grid, u, t, te, nsteps))(
+            us, ts, tends)
+
+    got = batch(uniform._run_steps_pallas)
+    _same_bits(got, batch(_run_steps_scan))
+    assert list(np.asarray(got[2])) == [8, 0, 3]
+    for i in range(3):
+        solo = uniform._run_steps_pallas(grid, us[i], ts[i], tends[i],
+                                         nsteps)
+        _same_bits(tuple(g[i] for g in got), solo)
