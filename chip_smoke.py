@@ -10,6 +10,11 @@ hold the Pallas kernels):
 * uniform: ``namelists/sedov3d.nml`` as committed (256³ f32, 10 steps);
 * AMR: ``namelists/sedov3d_amr.nml`` (levels 7→9, regrid every step).
 
+Then the uniform MHD run (``benchmark/configs/mhd-blast3d-uniform-256.nml``,
+256³ f32, 10 steps, the same entry) and the parity of its tiled CT kernel
+with the XLA step it re-spells (``mhd/uniform.step``), at 128³ on the
+chip: the sandbox can only hold the two together interpreted.
+
 ``--chips 4`` runs ONLY the sharded path and its comparison
 (``ShardedSim`` over four devices against one; the AMR side through
 ``ramses_tpu.__main__.build_amr_sim``, the command line's own choice:
@@ -40,6 +45,8 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(ROOT, "chip_out")
 NML_UNI = os.path.join(ROOT, "namelists", "sedov3d.nml")
 NML_AMR = os.path.join(ROOT, "namelists", "sedov3d_amr.nml")
+NML_MHD = os.path.join(ROOT, "benchmark", "configs",
+                       "mhd-blast3d-uniform-256.nml")
 KERNEL = 'custom_call_target="tpu_custom_call"'
 
 # f32 state, totals audited in f64.  Early Sedov keeps nearly all the
@@ -55,6 +62,12 @@ CONS_RTOL = 1e-4
 # the states agree to a few ulp per step.  L1(diff)/L1(ref) over <= 12
 # steps stays below 1e-5; a wrong halo or a dropped shard is O(1).
 SHARD_L1_RTOL = 1e-5
+# the tiled CT kernel against ``mu.step``: one expression graph spelt
+# twice.  On the chip the two have agreed to the bit (PERF.md, PR 34);
+# what is held is a few f32 ulps of the largest value (the interpreted
+# rehearsal on the CPU differs by FMA contraction, 7e-7).  A floor, a
+# sign or a stencil offset that drifts between the two is >= 1e-3.
+CT_PARITY_TOL = 2e-6
 
 
 def say(msg):
@@ -110,11 +123,11 @@ def shrunk(nml, lmin, lmax, nstep):
     return dst
 
 
-def run_cli(nml):
+def run_cli(nml, *more):
     """The command line's own path: parse like ``python -m ramses_tpu
     <nml> --ndim 3`` and hand back the sim it ran."""
     from ramses_tpu.__main__ import build_parser, run
-    return run(build_parser().parse_args([nml, "--ndim", "3"]))
+    return run(build_parser().parse_args([nml, "--ndim", "3", *more]))
 
 
 def check_finite(name, arrays):
@@ -225,6 +238,97 @@ def phase_amr(nml, rehearse):
 # ----------------------------------------------------------------------
 # four-chip phases (builder-run): sharded path vs one device
 # ----------------------------------------------------------------------
+def phase_mhd(nml, rehearse):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from ramses_tpu.config import load_params
+    from ramses_tpu.mhd import pallas_ct as pc
+    from ramses_tpu.mhd import uniform as mu
+    from ramses_tpu.mhd.core import MhdStatic
+    from ramses_tpu.mhd.driver import MhdSimulation, mhd_condinit
+
+    with Phase("mhd"):
+        params = load_params(nml, ndim=3)
+        def totals(u):          # f64 on the host: rows 0 (mass), 4 (energy)
+            return [float(np.asarray(u[k], np.float64).sum())
+                    for k in (0, 4)]
+
+        m0, e0 = totals(MhdSimulation(params, dtype=jnp.float32).u)
+        sim = run_cli(nml, "--solver", "mhd", "--dtype", "float32")
+        n = sim.grid.shape[0]
+        assert sim.nstep == params.run.nstepmax, sim.nstep
+        assert sim.u.dtype == jnp.float32 and sim.u.shape == (8, n, n, n)
+        check_finite("mhd", [("u", sim.u), ("bf", sim.bf)])
+        m1, e1 = totals(sim.u)
+        dm, de = rel(m1, m0), rel(e1, e0)
+        divb = sim.divb()
+        say(f"[mhd] {n}^3 f32 nstep={sim.nstep} t={sim.t:.6e} "
+            f"mass_rel_err={dm:.3e} energy_rel_err={de:.3e} "
+            f"(tol {CONS_RTOL:g}) divb={divb:.3e}")
+        assert dm < CONS_RTOL and de < CONS_RTOL and divb < 1e-5
+        tiled = mu.kernel_ok(sim.grid, sim.u.dtype)
+        tdt = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+        tend = float(params.output.tout[-1])
+        ncall = mu.run_steps.lower(
+            sim.grid, sim.u, sim.bf, jnp.asarray(sim.t, tdt),
+            jnp.asarray(tend, tdt), 16).compile().as_text().count(KERNEL)
+        say(f"[mhd] step program: "
+            f"{'tiled Pallas CT kernel' if tiled else 'XLA formulation'}, "
+            f"tpu_custom_calls={ncall}")
+        if not rehearse:
+            assert tiled and ncall == 1, \
+                "uniform MHD step program holds no tiled CT kernel"
+
+    with Phase("mhd-parity"):
+        # two steps of the blast, each taken by both spellings from the
+        # SAME input and dt: every cell row, every face, the next dt
+        shape = (8, 8, 128) if rehearse else (128, 128, 128)
+        dx = 1.0 / 128
+        for key, ext in zip(("x_center", "y_center", "z_center"), shape):
+            getattr(params.init, key)[1] = 0.5 * ext * dx
+        cfg = MhdStatic.from_params(params)
+        grid = mu.MhdGrid(cfg=cfg, shape=shape, dx=dx,
+                          bc_kinds=((0, 0),) * 3)
+        u, bf = (jnp.asarray(a, jnp.float32)
+                 for a in mhd_condinit(shape, dx, params, cfg))
+        u0, bf0 = u, bf
+        worst = 0.0
+        for _ in range(2):
+            dt = mu.cfl_dt(grid, u, bf)
+            un, bfn = mu._jit_step(grid, u, bf, dt)
+            uh, bc, bfk, rate = pc.ct_step_tiled(
+                pc.pad_xy(u[:pc.NHYDRO]), pc.pad_xy(bf), dt, cfg, dx,
+                shape, interpret=rehearse)
+            scale = float(jnp.max(jnp.abs(un)))
+            gaps = [float(jnp.max(jnp.abs(jnp.concatenate([uh, bc]) - un))),
+                    float(jnp.max(jnp.abs(bfk - bfn))),
+                    abs(float(cfg.courant_factor / rate[0, 0])
+                        - float(mu.cfl_dt(grid, un, bfn)))
+                    / float(dt) * scale]
+            worst = max([worst] + [g / scale for g in gaps])
+            u, bf = un, bfn
+        say(f"[mhd-parity] {'x'.join(map(str, shape))} kernel vs mu.step, "
+            f"2 steps: largest gap {worst:.3e} of the largest value "
+            f"(cells, faces, next dt; tol {CT_PARITY_TOL:g})")
+        assert worst <= CT_PARITY_TOL, "the CT kernel left mu.step"
+        # the vmapped step program (``run_steps_batch``: on the chip the
+        # batch is a grid axis of the one kernel): each member of an
+        # ensemble is its solo run
+        t0, t1 = jnp.zeros(2, jnp.float32), jnp.ones(2, jnp.float32)
+        ub, bfb, tb, nb = mu.run_steps_batch(
+            grid, jnp.stack([u0, u]), jnp.stack([bf0, bf]), t0, t1, 4)
+        for i, (us, bs) in enumerate(((u0, bf0), (u, bf))):
+            us, bs, ts, ns = mu.run_steps(grid, us, bs, t0[i], t1[i], 4)
+            scale = float(jnp.max(jnp.abs(us)))
+            gap = max(float(jnp.max(jnp.abs(ub[i] - us))),
+                      float(jnp.max(jnp.abs(bfb[i] - bs)))) / scale
+            say(f"[mhd-parity] batch member {i} vs its solo run, 4 steps: "
+                f"gap {gap:.3e} t {float(tb[i]):.6e} vs {float(ts):.6e}")
+            assert int(nb[i]) == int(ns) == 4 and gap <= CT_PARITY_TOL \
+                and abs(float(tb[i]) - float(ts)) <= 1e-6 * float(ts)
+
+
 def assert_spans(name, arrays, ndev):
     for key, a in arrays:
         got = len(a.sharding.device_set)
@@ -356,18 +460,20 @@ def main():
         f"count={len(devs)} jax={jax.__version__} chips={args.chips} "
         f"rehearse={args.rehearse}")
 
-    uni, amr, amr_steps = NML_UNI, NML_AMR, 3
+    uni, amr, mhd, amr_steps = NML_UNI, NML_AMR, NML_MHD, 3
     if args.rehearse:
         from ramses_tpu.hydro import pallas_oct
         pallas_oct.FORCE_INTERPRET = True
         uni = shrunk(NML_UNI, 5, 5, 4)
         amr = shrunk(NML_AMR, 4, 6, 4)
+        mhd = shrunk(NML_MHD, 5, 5, 4)
         amr_steps = 2
     t0 = time.perf_counter()
     if args.chips == 1:
         phase_native()
         phase_uniform(uni, args.rehearse)
         phase_amr(amr, args.rehearse)
+        phase_mhd(mhd, args.rehearse)
     else:
         phase_sharded_uniform(uni, devs[:4])
         phase_sharded_amr(amr, devs[:4], amr_steps)

@@ -452,10 +452,11 @@ def run(args):
     tel = getattr(sim, "telemetry", None)
     if tel is not None:
         tel.close(sim)
-    if solver == "hydro":
-        from ramses_tpu.hydro import pallas_muscl
-        from ramses_tpu.telemetry import screen
-        print(screen.kernel_line(pallas_muscl.block_stats()))
+    from ramses_tpu.telemetry import screen
+    kernel = screen.sweep_kernel(sim)
+    # hydro runs, and a driver that names its kernel (the uniform MHD run)
+    if solver == "hydro" or kernel != "pallas_muscl":
+        print(screen.kernel_line(screen.sweep_blocks(kernel), kernel))
     return sim
 
 

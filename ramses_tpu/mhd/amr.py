@@ -926,9 +926,10 @@ class MhdAmrSim(AmrSim):
     def _mhd_region_state(self, lvl: int):
         """(u rows, bf rows) from &INIT_PARAMS regions (driver.py
         ``mhd_condinit`` semantics per arbitrary cell list)."""
-        from ramses_tpu.mhd.driver import _region_mask
+        from ramses_tpu.mhd.driver import _region_mask, region_periods
         init = self.params.init
         cfg = self.mcfg
+        period = region_periods(self.params, [self.boxlen] * cfg.ndim)
         m = self.maps[lvl]
         centers = self.tree.cell_centers(lvl, self.boxlen)
         x = [centers[:, d] for d in range(cfg.ndim)]
@@ -942,7 +943,7 @@ class MhdAmrSim(AmrSim):
         for k in range(init.nregion):
             if str(init.region_type[k]).strip() != "square":
                 raise NotImplementedError("mhd ICs: square regions only")
-            msk = _region_mask(x, k, init, cfg.ndim)
+            msk = _region_mask(x, k, init, cfg.ndim, period)
             q[0][msk] = init.d_region[k]
             for c in range(NCOMP):
                 q[1 + c][msk] = vels[c][k]

@@ -88,6 +88,14 @@ def cell_center_b(bf: Sequence, ndim: int) -> list:
 
 def ctoprim(u, cfg: MhdStatic):
     """Conservative → primitive (``mhd/umuscl.f90`` ctoprim equivalent)."""
+    return jnp.stack(ctoprim_rows(u, cfg))
+
+
+def ctoprim_rows(u, cfg: MhdStatic) -> list:
+    """:func:`ctoprim` row by row: ``u`` is anything indexable by row
+    (an array or a list of arrays), the result a list — the form the
+    tiled CT kernel (``mhd/pallas_ct``) traces, where a stacked 4D
+    value would cost a VMEM copy.  Same operations, same bits."""
     r = jnp.maximum(u[IRHO], cfg.smallr)
     inv_r = 1.0 / r
     v = [u[1 + c] * inv_r for c in range(NCOMP)]
@@ -100,10 +108,14 @@ def ctoprim(u, cfg: MhdStatic):
     comps = [r] + v + [p] + b
     for s in range(cfg.npassive):
         comps.append(u[8 + s] * inv_r)
-    return jnp.stack(comps)
+    return comps
 
 
 def prim_to_cons(q, cfg: MhdStatic):
+    return jnp.stack(prim_to_cons_rows(q, cfg))
+
+
+def prim_to_cons_rows(q, cfg: MhdStatic) -> list:
     r = jnp.maximum(q[IRHO], cfg.smallr)
     v = [q[1 + c] for c in range(NCOMP)]
     b = [q[IBX + c] for c in range(NCOMP)]
@@ -113,7 +125,7 @@ def prim_to_cons(q, cfg: MhdStatic):
     comps = [r] + [r * vc for vc in v] + [e] + b
     for s in range(cfg.npassive):
         comps.append(r * q[8 + s])
-    return jnp.stack(comps)
+    return comps
 
 
 def fast_speed(q, d: int, cfg: MhdStatic):
@@ -129,6 +141,10 @@ def fast_speed(q, d: int, cfg: MhdStatic):
 
 
 def flux_along(q, d: int, cfg: MhdStatic):
+    return jnp.stack(flux_along_rows(q, d, cfg))
+
+
+def flux_along_rows(q, d: int, cfg: MhdStatic) -> list:
     """Ideal-MHD physical flux along component d from primitives.
 
     F(ρ)    = ρ v_d
@@ -160,7 +176,7 @@ def flux_along(q, d: int, cfg: MhdStatic):
             comps.append(vd * b[c] - v[c] * b[d])
     for s in range(cfg.npassive):
         comps.append(comps[0] * q[8 + s])
-    return jnp.stack(comps)
+    return comps
 
 
 def div_b(bf: Sequence, dx: Sequence[float], ndim: int):

@@ -23,20 +23,39 @@ from ramses_tpu.mhd import core, uniform as mu
 from ramses_tpu.mhd.core import IBX, IP, MhdStatic, NCOMP
 from ramses_tpu.telemetry import make_telemetry, sim_run_info
 from ramses_tpu.telemetry import screen as telemetry_screen
+from ramses_tpu.utils.timers import NullTimers, Timers
 
 
-def _region_mask(x, k, init, ndim):
+def _region_mask(x, k, init, ndim, period=None):
+    """Cells of region ``k``.  ``period``: per dimension the box length
+    where the box is periodic there, else None — a region that crosses
+    a periodic face continues on the other side (its nearest image
+    counts), so a region moved through a periodic box is the same region
+    translated."""
     centers = [init.x_center, init.y_center, init.z_center]
     lengths = [init.length_x, init.length_y, init.length_z]
+
+    def reach(d):
+        r = np.abs(x[d] - centers[d][k])
+        if period is not None and period[d]:
+            r = np.mod(r, period[d])
+            r = np.minimum(r, period[d] - r)
+        return 2.0 * r / lengths[d][k]
+
     en = float(init.exp_region[k])
     if en < 10.0:
-        r = sum((2.0 * np.abs(x[d] - centers[d][k]) / lengths[d][k]) ** en
-                for d in range(ndim)) ** (1.0 / en)
+        r = sum(reach(d) ** en for d in range(ndim)) ** (1.0 / en)
     else:
-        r = np.maximum.reduce(
-            [2.0 * np.abs(x[d] - centers[d][k]) / lengths[d][k]
-             for d in range(ndim)])
+        r = np.maximum.reduce([reach(d) for d in range(ndim)])
     return r < 1.0
+
+
+def region_periods(p: Params, lengths):
+    """What :func:`_region_mask` takes as ``period``: per dimension the
+    box length where both faces are periodic, else None."""
+    faces = bmod.BoundarySpec.from_params(p).faces
+    return [ext if faces[d][0].kind == faces[d][1].kind == bmod.PERIODIC
+            else None for d, ext in enumerate(lengths)]
 
 
 def mhd_condinit(shape, dx: float, p: Params, cfg: MhdStatic):
@@ -67,10 +86,11 @@ def mhd_condinit(shape, dx: float, p: Params, cfg: MhdStatic):
     # (including the domain edge) unset
     bf = np.zeros((NCOMP,) + tuple(shape))
     xc = np.meshgrid(*axes_c, indexing="ij")
+    period = region_periods(p, [n * dx for n in shape])
     for k in range(init.nregion):
         if str(init.region_type[k]).strip() != "square":
             raise NotImplementedError("mhd ICs: square regions only")
-        m = _region_mask(xc, k, init, ndim)
+        m = _region_mask(xc, k, init, ndim, period)
         q[0][m] = init.d_region[k]
         for c in range(NCOMP):
             q[1 + c][m] = vels[c][k]
@@ -88,6 +108,10 @@ def mhd_condinit(shape, dx: float, p: Params, cfg: MhdStatic):
 
 class MhdSimulation:
     """Uniform-grid MHD run (CT solver, SURVEY.md §7 stage 7)."""
+
+    # the kernel whose block picks the ``[kernel]`` line and
+    # ``run_header.sweep_block`` show (``telemetry/screen.sweep_kernel``)
+    sweep_kernel = "pallas_ct"
 
     def __init__(self, params: Params, dtype=jnp.float64):
         self.params = params
@@ -120,6 +144,9 @@ class MhdSimulation:
         self.cell_updates = 0
         self.wall_s = 0.0
         self.telemetry = make_telemetry(params)
+        # phase spans as driver.Simulation has them: ``evolve`` holds
+        # ``evolve: dispatch`` and ``evolve: wait``
+        self.timers = Timers() if self.telemetry.enabled else NullTimers()
         from ramses_tpu.resilience.faultinject import FaultInjector
         from ramses_tpu.resilience.stepguard import StepGuard
         self._sguard = StepGuard.from_params(params,
@@ -143,49 +170,63 @@ class MhdSimulation:
         if telem.enabled:
             telem.run_info.update(sim_run_info(self))
         while self.t < tend * (1.0 - 1e-12) and self.nstep < nstepmax:
-            if guard is not None and not guard.check():
+            with self.timers.section("evolve"):
+                if guard is not None and not guard.check():
+                    break
+                ndone = self._window(min(chunk, nstepmax - self.nstep),
+                                     tend, tdtype, verbose)
+            if ndone == 0:
                 break
-            n = min(chunk, nstepmax - self.nstep)
-            # redo-step guard: run_steps does not donate, so plain
-            # references retain the pre-window state for rollback
-            prev = ((self.u, self.bf, self.t, self.nstep)
-                    if self._sguard is not None else None)
+
+    def _window(self, n: int, tend: float, tdtype, verbose: bool) -> int:
+        """One fused window of up to ``n`` steps towards ``tend``: the
+        dispatch, the blocking fetch, the bookkeeping.  Returns the
+        steps done."""
+        telem = self.telemetry
+        # redo-step guard: run_steps does not donate, so plain
+        # references retain the pre-window state for rollback
+        prev = ((self.u, self.bf, self.t, self.nstep)
+                if self._sguard is not None else None)
+        if self._fault is not None:
+            n = self._fault.clamp_window(self.nstep, n)
+            self._fault.maybe_nan(self)
+        t0 = time.perf_counter()
+        t_before = self.t
+        with (self._wd.guard("step") if self._wd is not None
+                else nullcontext()):
             if self._fault is not None:
-                n = self._fault.clamp_window(self.nstep, n)
-                self._fault.maybe_nan(self)
-            t0 = time.perf_counter()
-            t_before = self.t
-            with (self._wd.guard("step") if self._wd is not None
-                    else nullcontext()):
-                if self._fault is not None:
-                    self._fault.maybe_hang(self.nstep)
+                self._fault.maybe_hang(self.nstep)
+            with self.timers.section("evolve: dispatch"):
                 u, bf, t, ndone = mu.run_steps(
                     self.grid, self.u, self.bf,
                     jnp.asarray(self.t, tdtype),
                     jnp.asarray(tend, tdtype), n)
+            # only dispatched so far: these block until the device has
+            # run the window
+            with self.timers.section("evolve: wait"):
                 u.block_until_ready()
                 ndone = int(ndone)
-            wall = time.perf_counter() - t0
-            self.wall_s += wall
-            self.u, self.bf, self.t = u, bf, float(t)
-            self.nstep += ndone
-            if self._wd is not None:
-                self._wd.note(nstep=self.nstep, t=self.t)
-            self.cell_updates += ndone * self.grid.ncell
-            if prev is not None and not self._sguard.ok(self.t):
-                ndone = self._retry_window(prev, tend, tdtype)
-            if telem.enabled and ndone:
-                telem.record_step(
-                    self, dt=(self.t - t_before) / ndone, wall_s=wall,
-                    steps=ndone, t=self.t, nstep=self.nstep,
-                    chunked=ndone)
-            if verbose:
-                print(telemetry_screen.step_line(
-                    self, dt=((self.t - t_before) / ndone
-                              if ndone else None), chunk=ndone,
-                    extra=f"divb={float(self.max_divb()):.2e}"))
-            if ndone == 0:
-                break
+                t = float(t)
+        wall = time.perf_counter() - t0
+        self.wall_s += wall
+        self.u, self.bf, self.t = u, bf, t
+        self.nstep += ndone
+        if self._wd is not None:
+            self._wd.note(nstep=self.nstep, t=self.t)
+        self.cell_updates += ndone * self.grid.ncell
+        if prev is not None and not self._sguard.ok(self.t):
+            ndone = self._retry_window(prev, tend, tdtype)
+        if telem.enabled and ndone:
+            telem.record_step(
+                self, dt=(self.t - t_before) / ndone, wall_s=wall,
+                steps=ndone, t=self.t, nstep=self.nstep,
+                chunked=ndone, extra={"divb": self.divb()})
+        if verbose:
+            print(telemetry_screen.step_line(
+                self, dt=((self.t - t_before) / ndone
+                          if ndone else None), chunk=ndone,
+                extra=f"divb={float(self.max_divb()):.2e}"))
+        return ndone
 
     def _retry_window(self, prev, tend, tdtype) -> int:
         """Redo-step ladder after a non-finite window (RAMSES redo-step):
@@ -241,6 +282,11 @@ class MhdSimulation:
         return jnp.max(jnp.abs(core.div_b(
             [self.bf[c] for c in range(NCOMP)],
             (self.dx,) * self.cfg.ndim, self.cfg.ndim)))
+
+    def divb(self) -> float:
+        """max |div B| * dx / max |B|: round-off under CT."""
+        bmax = float(jnp.max(jnp.abs(self.bf)))
+        return float(self.max_divb()) * self.dx / bmax if bmax else 0.0
 
     def totals(self):
         return mu.totals(self.u, self.cfg, self.dx)

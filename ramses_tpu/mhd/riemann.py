@@ -129,6 +129,12 @@ def hll(ql, qr, bn, cfg: MhdStatic):
 
 def hlld(ql, qr, bn, cfg: MhdStatic):
     """Miyoshi & Kusano (2005) five-wave solver, fully vectorized."""
+    return jnp.stack(hlld_rows(ql, qr, bn, cfg))
+
+
+def hlld_rows(ql, qr, bn, cfg: MhdStatic) -> list:
+    """:func:`hlld`'s eight flux rows as a list; ``ql``/``qr`` anything
+    indexable by row (the tiled CT kernel passes lists of windows)."""
     g = cfg.gamma
     rl, pl, rr, pr, SL, SR = _wave_bounds(ql, qr, bn, cfg)
     vnl, vt1l, vt2l, bt1l, bt2l = ql[1], ql[2], ql[3], ql[6], ql[7]
@@ -228,4 +234,4 @@ def hlld(ql, qr, bn, cfg: MhdStatic):
                                                     jnp.where(SR > 0.0, fsr,
                                                               fr[k])))))
         out.append(f)
-    return jnp.stack(out)
+    return out

@@ -124,24 +124,32 @@ def corner_emf(states: Dict[Tuple[str, str], Tuple], A_T, A_B, B_R, B_L,
         # x-solve: rotated layout [rho, vn=u, vt1=v, vt2=w, P, Bn, Bt1=B,
         # Bt2=C] on y-averaged side states
         def pack_x(side):
-            return jnp.stack([avg(rs, 0, side), avg(us, 0, side),
-                              avg(vs, 0, side), avg(ws, 0, side),
-                              avg(ps, 0, side), jnp.zeros_like(A_T),
-                              B_of[side], avg(cs, 0, side)])
+            return [avg(rs, 0, side), avg(us, 0, side),
+                    avg(vs, 0, side), avg(ws, 0, side),
+                    avg(ps, 0, side), jnp.zeros_like(A_T),
+                    B_of[side], avg(cs, 0, side)]
 
         def pack_y(side):
-            return jnp.stack([avg(rs, 1, side), avg(vs, 1, side),
-                              avg(us, 1, side), avg(ws, 1, side),
-                              avg(ps, 1, side), jnp.zeros_like(A_T),
-                              A_of[side], avg(cs, 1, side)])
+            return [avg(rs, 1, side), avg(vs, 1, side),
+                    avg(us, 1, side), avg(ws, 1, side),
+                    avg(ps, 1, side), jnp.zeros_like(A_T),
+                    A_of[side], avg(cs, 1, side)]
 
         bn_x = 0.5 * (A_T + A_B)
         bn_y = 0.5 * (B_R + B_L)
-        diss = {"llf": roemod.llf_dissipation,
-                "roe": roemod.roe_dissipation,
-                "upwind": roemod.upwind_dissipation}[kind]
-        dx5 = diss(pack_x("L"), pack_x("R"), bn_x, cfg)[5]
-        dy5 = diss(pack_y("B"), pack_y("T"), bn_y, cfg)[5]
+        if kind == "llf":
+            # the corner assembly reads the Bt1 row alone: row lists,
+            # no stack (the tiled CT kernel traces this branch)
+            def diss5(ql, qr, bn):
+                return roemod.llf_dissipation_bt1(ql, qr, bn, cfg)
+        else:
+            full = {"roe": roemod.roe_dissipation,
+                    "upwind": roemod.upwind_dissipation}[kind]
+
+            def diss5(ql, qr, bn):
+                return full(jnp.stack(ql), jnp.stack(qr), bn, cfg)[5]
+        dx5 = diss5(pack_x("L"), pack_x("R"), bn_x)
+        dy5 = diss5(pack_y("B"), pack_y("T"), bn_y)
         return ebar - dx5 + dy5
 
     if kind == "hlld":

@@ -258,19 +258,17 @@ def upwind(ql, qr, bn, cfg: MhdStatic, zero_flux=1.0):
     return _expand8(f7)
 
 
-def llf_dissipation(ql, qr, bn, cfg: MhdStatic):
-    """0.5 * max(|vn|+cfast) * dU in the 7-row layout (for the 2D corner
-    assembly; the 1D llf lives in mhd.riemann)."""
-    g = cfg.gamma
-    Ul, _ = _flux_cons(ql, bn, g)
-    Ur, _ = _flux_cons(qr, bn, g)
+def llf_dissipation_bt1(ql, qr, bn, cfg: MhdStatic):
+    """Row 5 (Bt1) of ``0.5 * max(|vn|+cfast) * dU`` in the 7-row
+    layout — all the 2D corner assembly reads of it (the 1D llf lives
+    in mhd.riemann).  ``ql``/``qr`` indexable by row."""
     from ramses_tpu.mhd.riemann import _fast
 
     def speed(q):
-        return jnp.abs(q[1]) + _fast(q[0], q[4], bn, q[6], q[7], g,
-                                     cfg.smallc)
+        return jnp.abs(q[1]) + _fast(q[0], q[4], bn, q[6], q[7],
+                                     cfg.gamma, cfg.smallc)
     a = jnp.maximum(speed(ql), speed(qr))
-    return 0.5 * a * (Ur - Ul)
+    return 0.5 * a * (qr[6] - ql[6])
 
 
 def upwind_dissipation(ql, qr, bn, cfg: MhdStatic):

@@ -359,6 +359,9 @@ def step(grid: MhdGrid, u, bf, dt, ok=None, emf_override=None):
     nd = cfg.ndim
     dx = (grid.dx,) * nd
     ng = NGHOST
+    # the time axis may run in f64 while the state is f32: keep the
+    # sweep in the state dtype (as grid/uniform.step)
+    dt = jnp.asarray(dt, u.dtype)
 
     up = _pad(u, nd, grid.bc_kinds)
     # faces get one extra ghost layer so the cell-centred average is valid
@@ -397,11 +400,29 @@ def cfl_dt(grid: MhdGrid, u, bf):
 _jit_step = jax.jit(step, static_argnames=("grid",))
 
 
+def kernel_ok(grid: MhdGrid, dtype) -> bool:
+    """True when the tiled CT kernel (:mod:`ramses_tpu.mhd.pallas_ct`)
+    covers this grid on this backend: derived from platform, shape,
+    dtype and boundaries — there is no option."""
+    from ramses_tpu.mhd import pallas_ct
+    return pallas_ct.kernel_available(grid.cfg, grid.shape, grid.bc_kinds,
+                                      dtype)
+
+
 @partial(jax.jit, static_argnames=("grid", "nsteps", "dt_scale"))
 def run_steps(grid: MhdGrid, u, bf, t, tend, nsteps: int,
               dt_scale: float = 1.0):
     """Advance up to nsteps entirely on device (cf. hydro run_steps).
-    ``dt_scale < 1``: redo-step retry at reduced Courant dt."""
+    ``dt_scale < 1``: redo-step retry at reduced Courant dt.
+
+    Where :func:`kernel_ok` admits the grid the steps run on the tiled
+    CT kernel (:func:`_run_steps_kernel`: the same results, a step run
+    only while one is owed); the masked XLA scan below is the off-chip
+    path."""
+    if kernel_ok(grid, u.dtype):
+        return _run_steps_kernel(grid, u, bf, t, tend, nsteps,
+                                 dt_scale=dt_scale)
+
     def body(carry, _):
         u, bf, t, ndone = carry
         dt = cfl_dt(grid, u, bf) * dt_scale
@@ -417,6 +438,41 @@ def run_steps(grid: MhdGrid, u, bf, t, tend, nsteps: int,
     (u, bf, t, ndone), _ = jax.lax.scan(
         body, (u, bf, t, jnp.array(0)), None, length=nsteps)
     return u, bf, t, ndone
+
+
+@partial(jax.jit, static_argnames=("grid", "nsteps", "dt_scale"))
+def _run_steps_kernel(grid: MhdGrid, u, bf, t, tend, nsteps: int,
+                      dt_scale: float = 1.0):
+    """:func:`run_steps` on the tiled CT kernel: a ``lax.while_loop``
+    that runs a step only while one is owed (``ndone < nsteps`` and
+    ``t < tend``) — the loop body is the ghost pass (``pad_xy`` of the
+    five hydro rows and of the three faces) and the kernel, nothing else
+    of the state's size, and no select masks a step out.  The loop
+    carries the hydro rows and the centred field as two arrays (the
+    kernel writes them so), joined into ``u`` once at the end.  The next
+    step's dt comes from the kernel's Courant output; the first from
+    :func:`cfl_dt`.  Same results as the scan form."""
+    from ramses_tpu.mhd import pallas_ct as pk
+
+    cfg = grid.cfg
+    dt0 = cfl_dt(grid, u, bf) * dt_scale
+
+    def owed(carry):
+        t, ndone = carry[3:5]
+        return (ndone < nsteps) & (t < tend)
+
+    def body(carry):
+        uh, _, bf, t, ndone, dtc = carry
+        dt = jnp.minimum(dtc, jnp.maximum(tend - t, 0.0))
+        uh, bc, bf, rate = pk.ct_step_tiled(
+            pk.pad_xy(uh), pk.pad_xy(bf), dt, cfg, grid.dx, grid.shape)
+        dtn = cfg.courant_factor / rate[0, 0] * dt_scale
+        return uh, bc, bf, t + dt, ndone + 1, dtn
+
+    uh, bc, bf, t, ndone, _ = jax.lax.while_loop(
+        owed, body, (u[:pk.NHYDRO], u[pk.NHYDRO:], bf, t, jnp.array(0),
+                     dt0))
+    return jnp.concatenate([uh, bc]), bf, t, ndone
 
 
 @partial(jax.jit,

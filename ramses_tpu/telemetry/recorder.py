@@ -249,11 +249,12 @@ class Telemetry:
             # the header lands lazily with the first record, i.e. after
             # the first step traced, so the per-step traced byte counts
             # are populated by now (they are zero at sim construction);
-            # so is what the fused sweep kernel's block rule picked
-            from ramses_tpu.hydro import pallas_muscl
+            # so is what the run's sweep kernel's block rule picked
             from ramses_tpu.parallel import dma_halo
+            from ramses_tpu.telemetry import screen
             self.run_info.update(dma_halo.traffic_snapshot())
-            self.run_info["sweep_block"] = pallas_muscl.block_stats()
+            self.run_info["sweep_block"] = screen.sweep_blocks(
+                self.run_info.get("sweep_kernel", "pallas_muscl"))
             header = {
                 "kind": "run_header",
                 "schema_version": SCHEMA_VERSION,
@@ -572,9 +573,11 @@ def make_telemetry(params, run_info: Optional[Dict[str, Any]] = None):
 def sim_run_info(sim) -> Dict[str, Any]:
     """Header metadata shared by all drivers."""
     p = getattr(sim, "params", None)
+    from ramses_tpu.telemetry import screen
     info = {
         "driver": type(sim).__name__,
         "ndev": int(getattr(sim, "ndev", 1)),
+        "sweep_kernel": screen.sweep_kernel(sim),
     }
     if p is not None:
         from ramses_tpu.parallel import dma_halo

@@ -43,17 +43,36 @@ def step_line(sim, dt: Optional[float] = None, chunk: int = 0,
     return line + ((" " + extra) if extra else "")
 
 
-def kernel_line(blocks) -> str:
-    """The ``[kernel]`` line: what the fused sweep kernel's block rule
-    picked for each signature it was traced for
-    (``pallas_muscl.block_stats()``), and the cells a grid step loads
-    and computes per cell it writes."""
+def sweep_kernel(sim) -> str:
+    """The sweep kernel ``sim``'s driver runs on where its gate admits
+    the run: the fused hydro kernel, unless the driver names its own
+    (``MhdSimulation.sweep_kernel``).  The one place ``python -m
+    ramses_tpu`` and ``run_header`` ask."""
+    return getattr(sim, "sweep_kernel", "pallas_muscl")
+
+
+def sweep_blocks(kernel: str) -> list:
+    """``block_stats()`` of the kernel :func:`sweep_kernel` named: what
+    its block rule picked for each signature traced in this process."""
+    if kernel == "pallas_ct":
+        from ramses_tpu.mhd import pallas_ct as mod
+    else:
+        from ramses_tpu.hydro import pallas_muscl as mod
+    return mod.block_stats()
+
+
+def kernel_line(blocks, kernel: str = "pallas_muscl") -> str:
+    """The ``[kernel]`` line: what the block rule of the run's sweep
+    kernel (``pallas_muscl``, or ``pallas_ct`` for the uniform MHD run)
+    picked for each signature it was traced for (its ``block_stats()``),
+    and the cells a grid step loads and computes per cell it writes.
+    No record: the run kept the XLA formulation, and the line says so."""
     if not blocks:
-        return "[kernel] pallas_muscl: not traced (XLA formulation)"
-    return "[kernel] pallas_muscl: " + "; ".join(
+        return f"[kernel] {kernel}: not traced (XLA formulation)"
+    return f"[kernel] {kernel}: " + "; ".join(
         "{}{} bx={} by={} window/written={:.2f}".format(
             "x".join(str(n) for n in b["shape"]),
-            " masked" if b["masked"] else "", b["bx"], b["by"],
+            " masked" if b.get("masked") else "", b["bx"], b["by"],
             b["window_cells"] / b["written_cells"]) for b in blocks)
 
 

@@ -222,3 +222,114 @@ def test_tile_sweep_compiles(one_chip, cfg, no_cache, ntile):
     compiled = _compile(lambda u, ok, dt: po.tile_sweep(
         u, ok, dt, cfg, 1.0 / 512, shift), u, ok, dt)
     _fits_one_chip(compiled)
+
+
+# ----------------------------------------------------------------------
+# the tiled constrained-transport MHD kernel (mhd/pallas_ct) and the
+# uniform MHD run's 16-step program (the benchmark's
+# mhd-blast3d-uniform-256 cell): first Mosaic compiles of any MHD code
+# ----------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def mhd_cfg():
+    from ramses_tpu.mhd.core import MhdStatic
+    nml = os.path.join(os.path.dirname(NML), os.pardir, "benchmark",
+                       "configs", "mhd-blast3d-uniform-256.nml")
+    return MhdStatic.from_params(load_params(nml, ndim=3))
+
+
+@pytest.mark.parametrize("n,bx", [(128, 16), (256, 8), (512, None),
+                                  (384, None), (64, None)])
+def test_ct_kernel_compiles_where_the_gate_admits(one_chip, mhd_cfg,
+                                                  no_cache, n, bx):
+    """Every lane extent ``pallas_ct.supports`` admits (128 and 256)
+    compiles under the 100 MiB of scoped VMEM the call asks for and
+    leaves its ``block_stats()`` record; what has no case here the gate
+    declines (512^3 is 5.9 GB of state: a cell of its own, with its
+    case, when it comes)."""
+    from ramses_tpu.mhd import pallas_ct as pc
+    shape = (n, n, n)
+    assert pc._pick_block(shape) == (bx, pc.BY if bx else None)
+    assert pc.supports(mhd_cfg, shape, PERIODIC, F32) is (bx is not None)
+    if bx is None:
+        return
+    pad = (n + 2 * pc.HALO, n + pc.WY - pc.BY, n)
+    up = jax.ShapeDtypeStruct((pc.NHYDRO,) + pad, F32, sharding=one_chip)
+    bfp = jax.ShapeDtypeStruct((3,) + pad, F32, sharding=one_chip)
+    dt = jax.ShapeDtypeStruct((), F32, sharding=one_chip)
+    compiled = _compile(lambda up, bfp, dt: pc.ct_step_tiled(
+        up, bfp, dt, mhd_cfg, 1.0 / n, shape), up, bfp, dt)
+    assert pc.KERNEL_NAME in compiled.as_text()
+    _fits_one_chip(compiled)
+    rec = [b for b in pc.block_stats() if b["shape"] == list(shape)]
+    assert rec == [{"kernel": "pallas_ct", "shape": list(shape), "bx": bx,
+                    "by": 8, "halo": 3,
+                    "window_cells": (bx + 6) * 16 * n,
+                    "written_cells": bx * 8 * n}]
+
+
+def test_mhd_run_steps_loop_body_is_ghost_pass_and_kernel(one_chip, mhd_cfg,
+                                                          no_cache):
+    """The whole 16-step program of the uniform MHD run at 256^3 (what
+    ``MhdSimulation.evolve`` dispatches on the chip): ONE kernel, and
+    inside the while body nothing of the state's size but the two
+    ghost-pass fusions (hydro rows, faces) and that kernel — no
+    ``select`` masking a step out, no ``slice`` or ``copy`` of the
+    carry.  The temporaries are the padded copies (567 MB) and a third
+    of that again (750 MB read; the XLA scan's: 28.32 GB, no program),
+    and state + output + temporaries fit one chip."""
+    from ramses_tpu.mhd import pallas_ct as pc
+    from ramses_tpu.mhd import uniform as mu
+    n = 256
+    grid = mu.MhdGrid(cfg=mhd_cfg, shape=(n, n, n), dx=1.0 / n,
+                      bc_kinds=PERIODIC)
+    u = jax.ShapeDtypeStruct((8, n, n, n), F32, sharding=one_chip)
+    bf = jax.ShapeDtypeStruct((3, n, n, n), F32, sharding=one_chip)
+    t = jax.ShapeDtypeStruct((), F32, sharding=one_chip)
+    compiled = _compile(
+        lambda u, bf, t, tend: mu._run_steps_kernel(grid, u, bf, t, tend,
+                                                    16), u, bf, t, t)
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    entry = _computation(hlo, re.search(r"^ENTRY (%\S+)", hlo, re.M)[1])
+    whiles = re.findall(r" while\(.*?body=(%[\w.-]+)", entry)
+    assert len(whiles) == 1
+    body = _computation(hlo, whiles[0])
+    assert "tpu_custom_call" in body
+
+    def ops(rows, extents):
+        return re.findall(
+            r"^\s*(?:ROOT )?%%\S+ = f32\[%s,%d,%d,%d\]\S* ([\w-]+)\("
+            % ((rows,) + extents), body, re.M)
+
+    for rows in (3, 5, 8):
+        assert set(ops(rows, (n, n, n))) <= {"get-tuple-element"}
+    padded = (n + 2 * pc.HALO, n + pc.WY - pc.BY, n)
+    assert ops(5, padded) == ["fusion"] and ops(3, padded) == ["fusion"]
+    _fits_one_chip(compiled)
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 1.4 * 8 * padded[0] * padded[1] * n * 4
+
+
+def test_mhd_batched_run_steps_compiles(one_chip, mhd_cfg, no_cache):
+    """``mu.run_steps_batch`` vmaps ``run_steps``, so on the chip every
+    member of an ensemble of admitted boxes takes the kernel path: the
+    batched 16-step program (3 members of 128^3) compiles too — ONE
+    kernel, the batch a grid axis of it, each member its own ``dt`` word
+    and Courant accumulator in SMEM — and fits one chip."""
+    from ramses_tpu.mhd import pallas_ct as pc
+    from ramses_tpu.mhd import uniform as mu
+    n, nb = 128, 3
+    grid = mu.MhdGrid(cfg=mhd_cfg, shape=(n, n, n), dx=1.0 / n,
+                      bc_kinds=PERIODIC)
+    assert pc.supports(mhd_cfg, grid.shape, PERIODIC, F32)
+    u = jax.ShapeDtypeStruct((nb, 8, n, n, n), F32, sharding=one_chip)
+    bf = jax.ShapeDtypeStruct((nb, 3, n, n, n), F32, sharding=one_chip)
+    t = jax.ShapeDtypeStruct((nb,), F32, sharding=one_chip)
+    compiled = _compile(jax.vmap(
+        lambda u, bf, t, tend: mu._run_steps_kernel(grid, u, bf, t, tend,
+                                                    16)), u, bf, t, t)
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert pc.KERNEL_NAME in hlo
+    _fits_one_chip(compiled)
+

@@ -3,6 +3,11 @@
 * the no-fallback guard: with ``JAX_PLATFORMS=cpu`` and default
   arguments the script must exit non-zero and print no ``"ok": true``
   line — a smoke that "passes" on the CPU hides the device;
+* the MHD phases rehearse: the uniform MHD run through the command
+  line at 32³ and the ``mhd-parity`` phase (the tiled CT kernel against
+  ``mu.step``, a vmapped batch against solo runs) on a small box with
+  the kernel interpreted — the control flow of what the chip run holds
+  to the chip's own arithmetic;
 * the native helpers are built from the committed source only: the
   binary's name carries a hash of ``ramses_native.cpp``, an absent
   hashed file is rebuilt, and a stale ``_ramses_native.so`` (the old
@@ -28,6 +33,25 @@ def test_chip_smoke_refuses_cpu():
     assert r.returncode != 0, r.stdout[-2000:]
     assert '"ok": true' not in r.stdout
     assert "no accelerator" in r.stderr
+
+
+def test_mhd_phases_rehearse(tmp_path):
+    code = (
+        "import os, sys\n"
+        "sys.path.insert(0, sys.argv[1])\n"
+        "import chip_smoke as cs\n"
+        "import ramses_tpu\n"
+        "cs.OUT = sys.argv[2]\n"
+        "os.chdir(cs.OUT)\n"
+        "cs.phase_mhd(cs.shrunk(cs.NML_MHD, 5, 5, 4), True)\n")
+    r = subprocess.run([sys.executable, "-c", code, REPO, str(tmp_path)],
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
+    assert "[mhd] 32^3 f32 nstep=4" in r.stdout
+    assert "[kernel] pallas_ct: not traced (XLA formulation)" in r.stdout
+    assert "[mhd-parity] ok" in r.stdout
+    assert r.stdout.count("vs its solo run, 4 steps: gap 0.000e+00") == 2
 
 
 @pytest.mark.skipif(shutil.which("g++") is None, reason="no g++")
