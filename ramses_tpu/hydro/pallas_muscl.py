@@ -8,12 +8,16 @@ at 256³); here every intermediate lives in VMEM and HBM sees exactly one
 read of the (haloed) state and one write of the update, the traffic the
 algorithm actually requires.
 
-Blocking: the grid is tiled over (x, y); each program sees the FULL z
-extent (z is the TPU lane dimension — keeping it whole makes the minor
-dims perfectly tiled and gives the z-direction stencil for free via lane
-rotates).  x/y halos (2 cells) come from overlapping `pl.Element` windows
-into a pre-padded array; z wraps periodically inside the kernel with
-``jnp.roll`` (non-periodic z falls back to the XLA path).
+Blocking: the grid is tiled over (x, y) in tiles of ``bx x by`` cells
+that ONE rule picks per (shape, masked) (:func:`_pick_block`: what
+Mosaic admits, ranked by a cost measured on the chip); each program
+sees the FULL z extent (z is the TPU lane dimension — keeping it whole
+makes the minor dims perfectly tiled and gives the z-direction stencil
+for free via lane rotates).  x/y halos (2 cells) come from overlapping
+`pl.Element` windows of ``(bx+4) x (by+8)`` cells into a pre-padded
+array (y is the sublane dimension: the window is whole 8-row groups);
+z wraps periodically inside the kernel with ``jnp.roll`` (non-periodic
+z falls back to the XLA path).
 
 Scope: ndim=3, nener=0, npassive=0, scheme=muscl, slope_type∈{1,2,8},
 riemann∈{llf, hllc}.  Everything else falls back to
@@ -78,47 +82,101 @@ def supports(cfg: HydroStatic, shape, bc_kinds, dtype) -> bool:
             return False
     if dtype not in (jnp.float32, jnp.dtype("float32")):
         return False
-    return _pick_block(shape)[0] is not None
+    return _pick_block(shape, masked=True)[0] is not None
 
 
-WY = 16  # y window: by + 4-cell halo, padded to the 8-sublane rule
-BY = 8   # y tile
+# The fixed y tile of mhd/pallas_ct (its own kernel, its own rule: by
+# hand its fastest tile is the one it ships), imported from here.  This
+# module's tile is _pick_block's.
+WY = 16
+BY = 8
 
-# The block budget, stated once: a tile is admitted when LIVE_WINDOWS
-# f32 arrays of its window ((bx+4) x WY x nz) fit the scoped VMEM that
-# every call asks Mosaic for (VMEM_LIMIT_BYTES: the ``CompilerParams``
-# value below).  LIVE_WINDOWS is a calibration, not a count read from
-# Mosaic: 384 keeps every pick that has run on the chip (nz 128 -> bx
-# 16, 256 -> 8, 384 -> 4: windows of 160-192 KiB a variable), admits
-# nz = 512 at bx 4 (256 KiB) and nothing wider.  Mosaic compiles
-# windows 2.5x larger under the same limit (bx 16 at 512^3); whether a
-# larger tile is FASTER is a measured choice for a perf issue with a
-# claim in every cell that runs the kernel (ROADMAP A5).
+# y window of a ``by``-row tile: 2 ghost rows each side, rounded up to
+# the 8-sublane rule — 4 junk rows at the high end for EVERY by, so the
+# padded state is (nx+4, ny+8, nz) whatever the pick.
+Y_SLACK = 4
+
+
+def _wy(by: int) -> int:
+    return by + 2 * NG + Y_SLACK
+
+
+# The tile rule, stated once.  A grid step reads a window of
+# (bx+4) x (by+8) x nz cells of each input (five variables, six with
+# the refined mask) to write bx x by x nz.  Both halves are measured on
+# a v5e (PERF.md section 6, PR 35: 16-step slices of the loop form under
+# every tile of TILES_X x TILES_Y at 256^3 and 512^3, the masked kernel
+# at 128^3):
+#
+# Cost.  A slice's kernel time is K * (bx + X_HALO_COST)/bx * (by+8)/by:
+#   y: the kernel computes whole 8-row sublane groups, and the written
+#      rows 2..by+1 touch all (by+8)/8 of the window's — 2.0 x the
+#      written groups at by 8, 1.5 at 16, 1.25 at 32 (measured 1 : 0.754
+#      : 0.641 at 512^3, 1 : 0.753 : 0.623 at 256^3);
+#   x: the leading dim is unrolled plane by plane, and most work on the
+#      four halo planes is dead and dropped: they cost about ONE plane
+#      (X_HALO_COST fitted 0.9-1.2 over both sizes and all three by).
+# Among the admitted tiles this cost ranks every measured pair right.
+#
+# Budget.  The input windows of one grid step may hold WINDOW_BYTES.
+# Every tile up to 960 KiB a variable ran at the cost above; every tile
+# from 1152 KiB a variable up ran SLOWER than its half (512^3: bx 32 at
+# by 8, (32, 16), (16, 32); 256^3: (32, 32)): 5 MiB over the five
+# variables.  Mosaic itself admits more: by its own report the scoped
+# VMEM of a call is the register allocator's spill slots (40.9 windows,
+# 46.3 masked, at (32, 32) x 512 lanes) plus the pipeline's double
+# buffers, under VMEM_LIMIT_BYTES (what every call asks for) up to
+# ~1.7 MiB a variable — so every tile this budget admits compiles.
+# The MASKED signature (a complete level inside a hierarchy) is held to
+# MASKED_WINDOW_BYTES over its six windows, 256 KiB a variable: the
+# kernel is unrolled over its window, its code (0.6 MB at 160 KiB a
+# variable, 2.9 MB at 720) lives in HBM once in EACH whole-hierarchy
+# program that sweeps the level (4 on one chip, 10 on the mesh), and at
+# (32, 32) x 128 lanes that was +9.1 MB = +1.9 % of the AMR run's peak
+# HBM for a sweep that is a few ms of a host-bound step.
+# Each admitted (shape, masked, want_flux) has its compile case for the
+# described chip in tests/test_chip_compile.py, which also pins every
+# pick.  LANES are the lane extents that have those cases; the gate
+# declines the rest.
 VMEM_LIMIT_BYTES = 100 * 1024 * 1024
-LIVE_WINDOWS = 384
+WINDOW_BYTES = 5 * 1024 * 1024
+MASKED_WINDOW_BYTES = 6 * 256 * 1024
+X_HALO_COST = 1.0
+LANES = (128, 256, 384, 512)
+TILES_X = (32, 16, 8, 4)
+TILES_Y = (32, 16, 8)
 
 
-def _window_fits(bx: int, nz: int) -> bool:
-    return (bx + 2 * NG) * WY * nz * 4 * LIVE_WINDOWS <= VMEM_LIMIT_BYTES
+def _window_fits(bx: int, by: int, nz: int, masked: bool = False) -> bool:
+    window = (bx + 2 * NG) * _wy(by) * nz * 4
+    if masked:
+        return 6 * window <= MASKED_WINDOW_BYTES
+    return 5 * window <= WINDOW_BYTES
 
 
-def _pick_block(shape) -> Tuple[Optional[int], Optional[int]]:
-    """x/y tile sizes, or (None, None) where the kernel cannot tile
-    the box.
+def _tile_cost(bx: int, by: int) -> float:
+    return (bx + X_HALO_COST) / bx * _wy(by) / by
+
+
+def _pick_block(shape, masked: bool = False
+                ) -> Tuple[Optional[int], Optional[int]]:
+    """The (bx, by) tile of a call on ``shape`` (``masked``: with the
+    refined-cell mask), or (None, None) where the kernel cannot tile
+    the box: the admitted tile the rule above ranks first.
 
     Mosaic requires the last two block dims divisible by (8, 128): z is
-    always the full extent (lane dim, so a multiple of 128); y uses a
-    fixed 8-cell tile read through a 16-cell window (2 halo + 2 junk
-    per side); x is a free (untiled) dim so its window is exactly bx+4,
-    the largest the budget above admits.
+    always the full extent (lane dim: one of ``LANES``); the y tile is
+    whole 8-row groups read through a ``by+8``-row window (2 halo + 2
+    junk rows per side); x is a free (untiled) dim, so its window is
+    exactly bx+4.
     """
     nx, ny, nz = shape
-    if nz % 128 or ny % BY:
+    if nz not in LANES:
         return None, None
-    for bx in (32, 16, 8, 4):
-        if nx % bx == 0 and _window_fits(bx, nz):
-            return bx, BY
-    return None, None
+    fits = [(bx, by) for bx in TILES_X for by in TILES_Y
+            if nx % bx == 0 and ny % by == 0
+            and _window_fits(bx, by, nz, masked)]
+    return min(fits, key=lambda t: _tile_cost(*t), default=(None, None))
 
 
 # Trace-time record of what the block rule picked, one per call
@@ -129,9 +187,9 @@ _BLOCKS: dict = {}
 
 
 def _block_record(shape, masked: bool) -> dict:
-    bx, by = _pick_block(shape)
+    bx, by = _pick_block(shape, masked)
     return {"shape": list(shape), "masked": masked, "bx": bx, "by": by,
-            "window_cells": (bx + 2 * NG) * WY * shape[2],
+            "window_cells": (bx + 2 * NG) * _wy(by) * shape[2],
             "written_cells": bx * by * shape[2]}
 
 
@@ -238,8 +296,8 @@ def _hllc_flux(ql, qr, d: int, cfg: HydroStatic):
 
 def _make_kernel(cfg: HydroStatic, dx: float, bx: int, by: int,
                  masked: bool, courant: bool, want_flux: bool = False):
-    """Kernel body closure; refs: u_pad [5, bx+4, WY, nz] window,
-    (ok [bx+4, WY, nz] window,) dt [1,1] SMEM → out [5, bx, by, nz]
+    """Kernel body closure; refs: u_pad [5, bx+4, by+8, nz] window,
+    (ok [bx+4, by+8, nz] window,) dt [1,1] SMEM → out [5, bx, by, nz]
     (+ per-block courant dt min [1, 1] SMEM when ``courant``)
     (+ phi [3, 2, bx, by, nz] per-cell (low, high) dt/dx-scaled face
     MASS fluxes when ``want_flux`` — the MC-tracer capture)."""
@@ -378,27 +436,29 @@ def fused_step_padded(u_pad, dt, cfg: HydroStatic, dx: float,
 
     u_pad: [5, nx+4, ny+8, nz] from :func:`pad_xy` (x: 2-cell ghosts
     both sides; y: 2-cell ghosts + 4 junk rows at the high end so the
-    16-cell y windows stay in bounds); ok_pad: optional refined-cell
+    last ``by+8``-row window stays in bounds, whatever ``by`` the block
+    rule picks for ``(shape, masked)``); ok_pad: optional refined-cell
     mask, same spatial shape — faces touching a refined cell get zero
     flux (``godunov_fine.f90:718``).  Returns the UPDATED active grid
     [5, nx, ny, nz].
     """
     nx, ny, nz = shape
-    bx, by = _pick_block(shape)
     masked = ok_pad is not None
+    bx, by = _pick_block(shape, masked)
+    wy = _wy(by)
     _BLOCKS[(shape, masked)] = _block_record(shape, masked)
     dt2 = jnp.asarray(dt, u_pad.dtype).reshape(1, 1)
     kern = _make_kernel(cfg, dx, bx, by, masked, courant, want_flux)
     in_specs = [
         pl.BlockSpec(
-            (pl.Element(5), pl.Element(bx + 2 * NG), pl.Element(WY), pl.Element(nz)),
+            (pl.Element(5), pl.Element(bx + 2 * NG), pl.Element(wy), pl.Element(nz)),
             lambda i, j: (0, i * bx, j * by, 0),
             memory_space=pltpu.VMEM),
     ]
     args = [u_pad]
     if ok_pad is not None:
         in_specs.append(pl.BlockSpec(
-            (pl.Element(bx + 2 * NG), pl.Element(WY), pl.Element(nz)),
+            (pl.Element(bx + 2 * NG), pl.Element(wy), pl.Element(nz)),
             lambda i, j: (i * bx, j * by, 0),
             memory_space=pltpu.VMEM))
         args.append(ok_pad)
@@ -489,14 +549,12 @@ def fused_step_shard(up, okp, dt, cfg: HydroStatic, dx: float,
     sp = (0, 1 + a0, 1 + a1, 1 + az)               # relabel transpose
     isp = (0, 1 + axes.index(0), 1 + axes.index(1), 1 + axes.index(2))
     ur = jnp.transpose(up, sp)[jnp.asarray(vp)]
-    # y window slack: 4 junk rows at the high end (values never used)
-    ur = jnp.pad(ur, ((0, 0), (0, 0), (0, WY - BY - NG * 2), (0, 0)),
-                 mode="edge")
+    # y window slack: junk rows at the high end (values never used)
+    ur = jnp.pad(ur, ((0, 0), (0, 0), (0, Y_SLACK), (0, 0)), mode="edge")
     okr = None
     if okp is not None:
         okr = jnp.transpose(okp, (a0, a1, az))
-        okr = jnp.pad(okr, ((0, 0), (0, WY - BY - NG * 2), (0, 0)),
-                      mode="edge")
+        okr = jnp.pad(okr, ((0, 0), (0, Y_SLACK), (0, 0)), mode="edge")
     shape_rel = (loc[a0], loc[a1], loc[az])
     out = fused_step_padded(ur, dt, cfg, dx, shape_rel, ok_pad=okr,
                             want_flux=want_flux, interpret=interpret,
@@ -516,8 +574,9 @@ def fused_step_shard(up, okp, dt, cfg: HydroStatic, dx: float,
 
 
 def pad_xy(u, bc, cfg: HydroStatic, ok=None):
-    """Ghost-pad x (2/2) and y (2 low / 6 high — window slack) only;
-    z periodic is handled in-kernel."""
+    """Ghost-pad x (2/2) and y (2 low / 6 high: 2 ghosts + ``Y_SLACK``
+    junk rows, the same for every y tile) only; z periodic is handled
+    in-kernel."""
     up = _pad_leading2(u, bc, cfg)
     if ok is None:
         return up, None
@@ -565,7 +624,7 @@ def _pad_leading2(u, bc, cfg: HydroStatic):
             reps[ax] = ng
             return jnp.tile(edge, reps)
 
-        hi_ng = NG if d == 0 else WY - BY - NG         # y: +4 junk rows
+        hi_ng = NG if d == 0 else NG + Y_SLACK         # y: +4 junk rows
         u = jnp.concatenate([ghost(lo_bc, 0, NG), u, ghost(hi_bc, 1, hi_ng)],
                             axis=ax)
     return u

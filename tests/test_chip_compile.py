@@ -88,21 +88,30 @@ def _fits_one_chip(compiled):
 PERIODIC = ((0, 0),) * 3
 
 
-@pytest.mark.parametrize("n,bx", [
-    (128, 16), (256, 8), (384, 4),    # the picks that have run on the chip
-    (512, 4),                         # admitted since PR 31
-    (640, None), (768, None), (1024, None), (192, None)])
-def test_block_rule_pinned(cfg, n, bx):
-    """The block budget's picks, pinned: a change to
-    ``LIVE_WINDOWS`` / ``VMEM_LIMIT_BYTES`` that moves one re-compiles
-    an existing benchmark cell's program and is a perf issue with a
-    claim (ROADMAP A5), not a side effect.  The mesh's level-7 slabs
-    relabel an uncut 128-cell axis to the lane: the 128 row's pick."""
-    assert pk._pick_block((n, n, n)) == (bx, pk.BY if bx else None)
-    assert pk.supports(cfg, (n, n, n), PERIODIC, F32) is (bx is not None)
+# lane extent -> (the plain signature's pick, the masked one's)
+PICKS = {128: ((32, 32), (8, 32)), 256: ((16, 32), (4, 16)),
+         384: ((8, 32), (4, 8)), 512: ((8, 32), (4, 8))}
+
+
+@pytest.mark.parametrize("n", [128, 256, 384, 512, 640, 768, 1024, 192])
+def test_block_rule_pinned(cfg, n):
+    """The block rule's picks, pinned for both signatures: the plain
+    one's is the fastest tile of the by-hand grid on the chip, the
+    masked one's the fastest whose window stays at the 256 KiB a
+    variable its code size in HBM allows (PERF.md section 6, PR 35), so
+    a change to a budget or the cost that moves one re-compiles an
+    existing benchmark cell's program and is a perf issue with a claim,
+    not a side effect.
+    What has no compile case below the gate declines.  The mesh's
+    level-7 slabs relabel an uncut 128-cell axis to the lane: they take
+    the masked 128 pick (``by`` divides their 64 rows)."""
+    plain, masked = PICKS.get(n, ((None, None),) * 2)
+    assert pk._pick_block((n, n, n)) == plain
+    assert pk._pick_block((n, n, n), masked=True) == masked
+    assert pk.supports(cfg, (n, n, n), PERIODIC, F32) is (n in PICKS)
     if n == 128:
-        assert pk._pick_block((64, 64, 128)) == (16, pk.BY)
-        assert pk._pick_block((128, 64, 128)) == (16, pk.BY)
+        assert pk._pick_block((64, 64, 128), masked=True) == masked
+        assert pk._pick_block((128, 64, 128), masked=True) == masked
 
 
 @pytest.mark.parametrize("n,mask,want_flux", [
@@ -119,7 +128,10 @@ def test_fused_step_padded_compiles(one_chip, cfg, no_cache, n, mask,
                                     want_flux):
     shape = (n, n, n)
     assert pk.supports(cfg, shape, PERIODIC, F32)
-    pad = (n + 2 * pk.NG, n + pk.WY - pk.BY, n)
+    bx, by = PICKS[n][mask]
+    assert pk._pick_block(shape, mask) == (bx, by)
+    pad = (n + 2 * pk.NG, n + pk._wy(by) - by, n)
+    assert pad == (n + 4, n + 8, n)       # whatever the pick
     u = jax.ShapeDtypeStruct((5,) + pad, F32, sharding=one_chip)
     dt = jax.ShapeDtypeStruct((), F32, sharding=one_chip)
     dx = 0.5 / n
@@ -134,7 +146,30 @@ def test_fused_step_padded_compiles(one_chip, cfg, no_cache, n, mask,
     _fits_one_chip(compiled)
     rec = [b for b in pk.block_stats()
            if b["shape"] == list(shape) and b["masked"] is mask]
-    assert len(rec) == 1 and rec[0]["bx"] == pk._pick_block(shape)[0]
+    assert rec == [{"shape": list(shape), "masked": mask, "bx": bx, "by": by,
+                    "window_cells": (bx + 4) * (by + 8) * n,
+                    "written_cells": bx * by * n}]
+
+
+@pytest.mark.parametrize("loc", [(128, 64, 64), (128, 128, 64)])
+def test_fused_step_shard_compiles(one_chip, cfg, no_cache, loc):
+    """The mesh's level-7 slabs (the four-chip cell: 128^3 cut (1, 2, 2);
+    cut once: (1, 1, 2)): ``fused_step_shard`` relabels the uncut
+    128-cell axis to the lane and pads the pick's junk rows itself, so
+    the per-shard call compiles at the masked 128 pick too."""
+    axes = (1, 2, 0)
+    rel = tuple(loc[a] for a in axes)
+    bx, by = PICKS[128][True]
+    assert pk._pick_block(rel, True) == (bx, by)
+    ext = (loc[0], loc[1] + 2 * pk.NG, loc[2] + 2 * pk.NG)
+    up = jax.ShapeDtypeStruct((5,) + ext, F32, sharding=one_chip)
+    okp = jax.ShapeDtypeStruct(ext, F32, sharding=one_chip)
+    dt = jax.ShapeDtypeStruct((), F32, sharding=one_chip)
+    compiled = _compile(lambda up, okp, dt: pk.fused_step_shard(
+        up, okp, dt, cfg, 1.0 / 128, loc, axes, want_flux=True),
+        up, okp, dt)
+    assert pk.SHARD_KERNEL_NAME in compiled.as_text()
+    _fits_one_chip(compiled)
 
 
 def _computation(hlo: str, name: str) -> str:
@@ -186,7 +221,8 @@ def test_run_steps_loop_body_is_pad_and_kernel(one_chip, cfg, no_cache, n,
     entry_ops = _state_ops(entry, n)
     assert entry_ops.count("copy") <= 1
     assert set(entry_ops) <= {"parameter", "copy", "get-tuple-element"}
-    padded = 5 * (n + 2 * pk.NG) * (n + pk.WY - pk.BY) * n * 4
+    by = pk._pick_block((n, n, n))[1]
+    padded = 5 * (n + 2 * pk.NG) * (n + pk._wy(by) - by) * n * 4
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 1.1 * padded + 16 * 2 ** 20
     _fits_one_chip(compiled)

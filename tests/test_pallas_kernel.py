@@ -7,6 +7,10 @@ whole-grid XLA pipeline (``grid.uniform.step`` internals) that the TPU
 kernel replaces — both implement ``hydro/umuscl.f90:22-171``.
 """
 
+import contextlib
+import json
+import os
+import sys
 from functools import partial
 
 import jax
@@ -14,18 +18,45 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ramses_tpu.grid import boundary as bmod
-from ramses_tpu.grid import uniform
-from ramses_tpu.hydro import muscl, pallas_muscl as pk
-from ramses_tpu.hydro.core import HydroStatic
-from ramses_tpu.hydro.timestep import compute_dt
-from ramses_tpu.config import Params
+if __name__ == "__main__":      # the no-FMA child runs this file as a script
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+
+import no_fma_child  # noqa: E402  (tests/ is on the path: rootdir conftest)
+from ramses_tpu.grid import boundary as bmod  # noqa: E402
+from ramses_tpu.grid import uniform  # noqa: E402
+from ramses_tpu.hydro import muscl, pallas_muscl as pk  # noqa: E402
+from ramses_tpu.hydro.core import HydroStatic  # noqa: E402
+from ramses_tpu.hydro.timestep import compute_dt  # noqa: E402
+from ramses_tpu.config import Params  # noqa: E402
 
 SHAPE = (16, 16, 128)
-# a 512-cell lane axis: the widest the block budget admits (bx 4)
+# a 512-cell lane axis: the widest the block rule admits
 SHAPE512 = (8, 16, 512)
 SHAPES = pytest.mark.parametrize("shape", [SHAPE, SHAPE512],
                                  ids=["lane128", "lane512"])
+
+
+@contextlib.contextmanager
+def _under_tile(tile):
+    """The block rule overridden to ``tile``.  The pick is read at trace
+    time and ``fused_step_padded`` caches its trace per signature, so its
+    cache is dropped on the way in and out."""
+    kernel = pk.fused_step_padded          # (a test may patch the name)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pk, "_pick_block", lambda shape, masked=False: tile)
+        kernel.clear_cache()
+        try:
+            yield
+        finally:
+            kernel.clear_cache()
+
+
+@pytest.fixture()
+def tile_rule():
+    """``tile_rule((bx, by))``: :func:`_under_tile` until the test ends."""
+    with contextlib.ExitStack() as stack:
+        yield lambda tile: stack.enter_context(_under_tile(tile))
 
 
 def _cfg(riemann="llf", slope_type=1):
@@ -70,10 +101,10 @@ def test_fused_step_matches_xla(riemann, shape):
                                rtol=2e-5, atol=2e-6)
     rec = [b for b in pk.block_stats()
            if b["shape"] == list(shape) and not b["masked"]]
-    bx = {128: 16, 512: 4}[shape[2]]
-    assert len(rec) == 1 and (rec[0]["bx"], rec[0]["by"]) == (bx, 8)
-    assert rec[0]["window_cells"] == (bx + 4) * 16 * shape[2]
-    assert rec[0]["written_cells"] == bx * 8 * shape[2]
+    bx, by = {128: (16, 16), 512: (8, 16)}[shape[2]]
+    assert len(rec) == 1 and (rec[0]["bx"], rec[0]["by"]) == (bx, by)
+    assert rec[0]["window_cells"] == (bx + 4) * (by + 8) * shape[2]
+    assert rec[0]["written_cells"] == bx * by * shape[2]
 
 
 def test_fused_step_reflecting_xy():
@@ -256,9 +287,13 @@ def test_fused_step_want_flux_matches_xla_dense_sweep():
                                    rtol=2e-5, atol=2e-6)
 
 
+@pytest.mark.parametrize("tile", [(16, 8), (8, 16)],
+                         ids=lambda t: "bx%d-by%d" % t)
 @pytest.mark.parametrize("want_flux", [False, True])
-def test_fused_step_shard_relabel_parity(want_flux):
-    """Per-shard relabeled entry == unrelabeled interior kernel.
+def test_fused_step_shard_relabel_parity(tile_rule, want_flux, tile):
+    """Per-shard relabeled entry == unrelabeled interior kernel, under
+    a y tile of 8 and of 16 (the junk rows ``fused_step_shard`` pads are
+    the pick's ``Y_SLACK``, whatever ``by``).
 
     ``shard_axes`` gates to TPU, so drive ``fused_step_shard`` directly
     in interpreter mode: original axis 0 (extent 128) takes the lane
@@ -288,8 +323,12 @@ def test_fused_step_shard_relabel_parity(want_flux):
         padw = [(g, g) if d == 1 + ax else (0, 0) for d in range(4)]
         up = jnp.pad(up, padw, mode="wrap")
         okp = jnp.pad(okp, [w for w in padw[1:]], mode="wrap")
+    tile_rule(tile)
     out_k = pk.fused_step_shard(up, okp, dt, cfg, dx, loc, axes,
                                 want_flux=want_flux, interpret=True)
+    rec = [b for b in pk.block_stats() if b["shape"] == [16, 16, 128]
+           and b["masked"]]
+    assert [(b["bx"], b["by"]) for b in rec] == [tile]
     # reference: fully ghost-padded unrelabeled interior kernel
     upf, okpf = u, okf
     for ax in range(3):
@@ -340,7 +379,14 @@ LOOP_SHAPE = (8, 16, 128)
 
 
 @pytest.fixture()
-def interpreted_kernel(monkeypatch):
+def interpreted_kernel(monkeypatch, tile_rule):
+    # held at the (8, 8) tile: two grid steps a box.  Under the rule's
+    # pick for this small box (one grid step) XLA's CPU backend fuses the
+    # interpreted body into the loop round it and contracts FMAs one way
+    # in the while and another in the scan; without FMA
+    # (``--xla_cpu_max_isa=SSE4_2``, tests/no_fma_child.py) every case
+    # below holds under that pick too.
+    tile_rule((8, 8))
     real = pk.fused_step_padded
     monkeypatch.setattr(
         pk, "fused_step_padded",
@@ -468,3 +514,108 @@ def test_run_steps_pallas_vmap_members_equal_solo(interpreted_kernel,
         solo = uniform._run_steps_pallas(grid, us[i], ts[i], tends[i],
                                          nsteps)
         _same_bits(tuple(g[i] for g in got), solo)
+
+
+# ---------------------------------------------------------------------------
+# the tile: every (bx, by) the block rule chooses among gives the cells,
+# face fluxes and Courant dt of (8, 8).  The tile changes which cells one
+# grid step computes, not one cell's arithmetic, and ``min`` is exact: to
+# the bit where the backend cannot contract FMAs differently per block
+# shape (``tests/no_fma_child.py``), to the file's tolerance in this process.
+# ---------------------------------------------------------------------------
+
+TILE_SHAPE = (32, 32, 128)
+TILES = [(bx, by) for by in (8, 16, 32) for bx in (4, 8, 16, 32)]
+TILE_MODES = ("plain", "masked", "want_flux")
+TILE_CASES = pytest.mark.parametrize(
+    "mode,tile", [(m, t) for m in TILE_MODES for t in TILES],
+    ids=lambda v: v if isinstance(v, str) else "bx%d-by%d" % v)
+
+
+def _tile_call(mode, tile):
+    """One interpreted call on ``TILE_SHAPE`` with the block rule
+    overridden to ``tile``: ``plain`` → (cells, Courant dt), ``masked``
+    → cells, ``want_flux`` → (cells, face fluxes) of the masked call."""
+    cfg = _cfg("hllc")
+    bc = bmod.BoundarySpec.periodic(3)
+    u = _state(cfg, seed=17, shape=TILE_SHAPE)
+    ok = jnp.asarray(np.random.default_rng(19).random(TILE_SHAPE) < 0.1)
+    up, okp = pk.pad_xy(u, bc, cfg, ok=None if mode == "plain" else ok)
+    with _under_tile(tile):
+        out = pk.fused_step_padded(
+            up, jnp.asarray(1e-3, jnp.float32), cfg, 1.0 / TILE_SHAPE[0],
+            TILE_SHAPE, ok_pad=okp, courant=mode == "plain",
+            want_flux=mode == "want_flux", interpret=True)
+        rec = pk._BLOCKS[(TILE_SHAPE, mode != "plain")]
+    return [np.asarray(a) for a in jax.tree.leaves(out)], rec
+
+
+def tile_child_main(modes):
+    """The bitwise cases, in a process without FMA: one JSON object
+    ``{"mode/bxXby": "" when equal to the (8, 8) call to the bit, else
+    why not}``."""
+    out = {}
+    for mode in modes:
+        want, _ = _tile_call(mode, (8, 8))
+        for tile in TILES:
+            got = want if tile == (8, 8) else _tile_call(mode, tile)[0]
+            bad = [i for i, (g, w) in enumerate(zip(got, want))
+                   if g.dtype != w.dtype or g.shape != w.shape
+                   or not np.array_equal(g, w)]
+            out["%s/%dx%d" % ((mode,) + tile)] = (
+                "" if not bad and len(got) == len(want) else
+                "outputs %s differ, max %g" % (bad, max(
+                    float(np.abs(got[i] - want[i]).max()) for i in bad)))
+    print("RESULT " + json.dumps(out))
+
+
+@pytest.fixture(scope="module")
+def tile_children():
+    """One child without FMA per mode, all started at once; a case's
+    answer waits for its own mode's child only."""
+    procs = {m: no_fma_child.start(__file__, m) for m in TILE_MODES}
+    results = {}
+
+    def answer(mode, tile):
+        if mode in procs:
+            results.update(no_fma_child.result(procs.pop(mode)))
+        return results["%s/%dx%d" % ((mode,) + tile)]
+
+    yield answer
+    for proc in procs.values():
+        proc.kill()
+
+
+@pytest.fixture(scope="module")
+def tile_88():
+    return {m: _tile_call(m, (8, 8))[0] for m in TILE_MODES}
+
+
+@TILE_CASES
+def test_tile_changes_no_result(tile_88, mode, tile):
+    """Cells, fluxes and the Courant dt under every candidate tile
+    against the (8, 8) result of the same call, and the record the call
+    leaves says the tile that ran."""
+    got, rec = _tile_call(mode, tile)
+    want = tile_88[mode]
+    assert len(got) == len(want) == {"plain": 2, "masked": 1,
+                                     "want_flux": 2}[mode]
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-6)
+    bx, by = tile
+    assert rec == {"shape": list(TILE_SHAPE), "masked": mode != "plain",
+                   "bx": bx, "by": by,
+                   "window_cells": (bx + 4) * (by + 8) * TILE_SHAPE[2],
+                   "written_cells": bx * by * TILE_SHAPE[2]}
+
+
+@TILE_CASES
+def test_tile_changes_no_bit(tile_children, mode, tile):
+    """The same comparison to the bit, where the backend has no FMA to
+    contract differently per block shape."""
+    assert tile_children(mode, tile) == ""
+
+
+if __name__ == "__main__":
+    tile_child_main(sys.argv[1].split(","))

@@ -125,9 +125,9 @@ def test_unchanged_state_reads_one(config, held_slice):
 
 
 @pytest.mark.parametrize("shape,masked,want", [
-    ((256, 256, 256), False, 3.0),       # bx 8: (8+4)*16 / (8*8)
-    ((512, 512, 512), False, 4.0),       # bx 4: (4+4)*16 / (4*8)
-    ((128, 128, 128), True, 2.5),        # bx 16
+    ((256, 256, 256), False, 1.5625),    # (16, 32): (16+4)*(32+8) / (16*32)
+    ((512, 512, 512), False, 1.875),     # (8, 32): (8+4)*(32+8) / (8*32)
+    ((128, 128, 128), True, 1.875),      # masked (8, 32)
     (None, False, None)])                # no kernel traced: nothing
 def test_sweep_window_ratio_reads_block_stats(monkeypatch, shape, masked,
                                               want):
@@ -143,9 +143,11 @@ def test_sweep_window_ratio_reads_block_stats(monkeypatch, shape, masked,
     assert pk.block_stats() == list(blocks.values())
     got = sweep_window_ratio.read(None, None, {}, {})
     assert got == want
-    if shape == (512, 512, 512):          # two signatures: cell-weighted
+    if shape == (512, 512, 512):          # three signatures: cell-weighted
         blocks[((128,) * 3, True)] = pk._block_record((128,) * 3, True)
-        want2 = (4.0 * 512 ** 3 + 2.5 * 128 ** 3) / (512 ** 3 + 128 ** 3)
+        blocks[((256,) * 3, False)] = pk._block_record((256,) * 3, False)
+        want2 = (1.875 * 512 ** 3 + 1.875 * 128 ** 3 + 1.5625 * 256 ** 3) / (
+            512 ** 3 + 128 ** 3 + 256 ** 3)
         assert sweep_window_ratio.read(None, None, {}, {}) \
             == pytest.approx(want2)
 
@@ -158,12 +160,9 @@ def test_kernel_line_and_run_header(tmp_path):
     from ramses_tpu.driver import Simulation
     from ramses_tpu.hydro import pallas_muscl as pk
     from ramses_tpu.telemetry import screen
-    line = screen.kernel_line([{"shape": [512] * 3, "masked": False,
-                                "bx": 4, "by": 8,
-                                "window_cells": 8 * 16 * 512,
-                                "written_cells": 4 * 8 * 512}])
-    assert line == ("[kernel] pallas_muscl: 512x512x512 bx=4 by=8 "
-                    "window/written=4.00")
+    line = screen.kernel_line([pk._block_record((512,) * 3, False)])
+    assert line == ("[kernel] pallas_muscl: 512x512x512 bx=8 by=32 "
+                    "window/written=1.88")
     assert "not traced" in screen.kernel_line([])
     params = load_params(os.path.join(ROOT, "benchmark", "configs",
                                       "sedov3d-uniform-512.nml"), ndim=3)
