@@ -36,7 +36,9 @@ from ramses_tpu.grid import boundary as bmod
 from ramses_tpu.hydro.core import HydroStatic
 from ramses_tpu.init import regions
 from ramses_tpu.telemetry import make_telemetry, sim_run_info
+from ramses_tpu.telemetry import hlo as _hlo
 from ramses_tpu.telemetry import screen as telemetry_screen
+from ramses_tpu.telemetry.hlo import phase
 from ramses_tpu.utils.timers import NullTimers, Timers
 
 
@@ -137,24 +139,31 @@ def _advance_traced(u, dev, fg, dt, spec: FusedSpec, cool_tables=None):
         l = levels[i]
         d = dev[l]
         if spec.gravity:
-            u[l] = kick_flat(u[l], fg[l], 0.5 * dtl, cfg.ndim, cfg.smallr)
+            with phase("source", l):
+                u[l] = kick_flat(u[l], fg[l], 0.5 * dtl, cfg.ndim,
+                                 cfg.smallr)
         unew[l] = u[l]
         if i + 1 < len(levels):
             advance(i + 1, 0.5 * dtl)
             advance(i + 1, 0.5 * dtl)
-        du, corr, dphi = K.sweep_level(spec, i, u[l], u.get(l - 1), d, dtl)
-        if spec.want_flux:
-            phi[l] = phi[l] + dphi
-        unew[l] = unew[l] + du
-        if corr is not None and l > spec.lmin:
-            unew[l - 1] = K.scatter_corrections(unew[l - 1], corr,
-                                                d["corr_idx"], cfg)
+        with phase("sweep", l):
+            du, corr, dphi = K.sweep_level(spec, i, u[l], u.get(l - 1), d,
+                                           dtl)
             if spec.want_flux:
-                phi[l - 1] = K.scatter_corr_flux(phi[l - 1], corr,
-                                                 d["corr_idx"], cfg)
+                phi[l] = phi[l] + dphi
+            unew[l] = unew[l] + du
+        if corr is not None and l > spec.lmin:
+            with phase("fluxcorr", l):
+                unew[l - 1] = K.scatter_corrections(unew[l - 1], corr,
+                                                    d["corr_idx"], cfg)
+                if spec.want_flux:
+                    phi[l - 1] = K.scatter_corr_flux(phi[l - 1], corr,
+                                                     d["corr_idx"], cfg)
         u[l] = unew[l]
         if spec.gravity:
-            u[l] = kick_flat(u[l], fg[l], 0.5 * dtl, cfg.ndim, cfg.smallr)
+            with phase("source", l):
+                u[l] = kick_flat(u[l], fg[l], 0.5 * dtl, cfg.ndim,
+                                 cfg.smallr)
         if spec.cool is not None:
             # cooling_fine follows godunov_fine at every level substep
             # (amr/amr_step.f90:448-474); pointwise, so the flat cell
@@ -164,11 +173,13 @@ def _advance_traced(u, dev, fg, dt, spec: FusedSpec, cool_tables=None):
             # don't recompile the fused program
             from ramses_tpu.hydro.cooling import cooling_step
             tabs, scl = cool_tables
-            u[l] = cooling_step(u[l].T, tabs, spec.cool, dtl, cfg,
-                                scales=scl).T
+            with phase("source", l):
+                u[l] = cooling_step(u[l].T, tabs, spec.cool, dtl, cfg,
+                                    scales=scl).T
         if i + 1 < len(levels):
-            u[l] = K.restrict_upload(u[l], u[levels[i + 1]], d["ref_cell"],
-                                     d["son_oct"], cfg)
+            with phase("restrict", l):
+                u[l] = K.restrict_upload(u[l], u[levels[i + 1]],
+                                         d["ref_cell"], d["son_oct"], cfg)
 
     advance(0, dt)
     return (u, phi) if spec.want_flux else (u, None)
@@ -206,14 +217,16 @@ def _fused_coarse_step(u, dev, fg, dt, spec: FusedSpec, cool_tables=None):
     per-level MC-tracer flux capture dict.
     """
     u, phi = _advance_traced(u, dev, fg, dt, spec, cool_tables)
-    dtn = jnp.min(_courant_traced(u, dev, spec,
-                                  fg if spec.gravity else None))
+    with phase("courant"):
+        dtn = jnp.min(_courant_traced(u, dev, spec,
+                                      fg if spec.gravity else None))
     return (u, dtn, phi) if spec.want_flux else (u, dtn)
 
 
 @partial(jax.jit, static_argnames=("spec",))
 def _fused_courant(u, dev, spec: FusedSpec, fg=None):
-    return _courant_traced(u, dev, spec, fg)
+    with phase("courant"):
+        return _courant_traced(u, dev, spec, fg)
 
 
 @partial(jax.jit, static_argnames=("ncell_pad", "cfg", "itype"))
@@ -225,11 +238,13 @@ def _migrate_level(old_u, u_coarse, rows_d, rows_s, cell_rep, nb_rep,
     ``amr/refine_utils.f90:590``).  All index arrays are bucket-padded
     with out-of-range targets so jit shapes stay stable; the scatter
     drops them."""
-    buf = jnp.zeros((ncell_pad, old_u.shape[1]), old_u.dtype)
-    buf = buf.at[rows_d].set(old_u[rows_s], mode="drop")
-    vals = K.interp_cells(u_coarse, cell_rep, nb_rep, sgn_rep, cfg,
-                          itype=itype)
-    return buf.at[rows_new].set(vals.astype(buf.dtype), mode="drop")
+    with phase("migrate: copy"):
+        buf = jnp.zeros((ncell_pad, old_u.shape[1]), old_u.dtype)
+        buf = buf.at[rows_d].set(old_u[rows_s], mode="drop")
+    with phase("migrate: interp"):
+        vals = K.interp_cells(u_coarse, cell_rep, nb_rep, sgn_rep, cfg,
+                              itype=itype)
+        return buf.at[rows_new].set(vals.astype(buf.dtype), mode="drop")
 
 
 @lru_cache(maxsize=None)
@@ -256,9 +271,12 @@ def _fused_flags(u, dev, spec: FusedSpec, eg, fls, itype: int):
     """Every level's gradient refinement criteria in ONE dispatch (the
     per-level ``hydro_refine`` kernels of ``flag_fine``); the host
     fetches the whole tuple with a single device round-trip."""
-    return tuple(K.flags_level(spec, i, u[l], u.get(l - 1), dev[l], eg, fls,
-                               itype)
-                 for i, l in enumerate(spec.levels))
+    flags = []
+    for i, l in enumerate(spec.levels):
+        with phase("flags", l):
+            flags.append(K.flags_level(spec, i, u[l], u.get(l - 1), dev[l],
+                                       eg, fls, itype))
+    return tuple(flags)
 
 
 @partial(jax.jit, static_argnames=("spec", "nsteps", "trace"),
@@ -1157,8 +1175,10 @@ class AmrSim:
         eg = (float(r.err_grad_d), float(r.err_grad_u),
               float(r.err_grad_p))
         fls = (float(r.floor_d), float(r.floor_u), float(r.floor_p))
-        return _fused_flags(self.u, self.dev, spec, eg, fls,
-                            int(self.params.refine.interpol_type))
+        args = (self.u, self.dev, spec, eg, fls,
+                int(self.params.refine.interpol_type))
+        _hlo.note_dispatch(_fused_flags, *args)
+        return _fused_flags(*args)
 
     def _flag_and_tree(self) -> Octree:
         r = self.params.refine
@@ -1538,9 +1558,13 @@ class AmrSim:
                 dts = [self._offload.coarse_dt_min(self,
                                                    self._fused_spec())]
             else:
-                dts = [float(jnp.min(_fused_courant(
+                dtmin = jnp.min(_fused_courant(
                     self.u, self.dev, self._fused_spec(),
-                    self.fg if (self.gravity and self.fg) else None)))]
+                    self.fg if (self.gravity and self.fg) else None))
+                # only dispatched so far: the fetch blocks until the
+                # device has run it (and what it still owed before)
+                with self.timers.section("courant: fetch"):
+                    dts = [float(dtmin)]
             dts.extend(self._aux_dts())
             return min(dts)
 
@@ -1767,10 +1791,11 @@ class AmrSim:
                 self.u, self._dt_cache = self._offload.run_step(
                     self, float(dt), spec)
             else:
-                out = _fused_coarse_step(
-                    self.u, self.dev, self.fg if self.gravity else {},
-                    jnp.asarray(float(dt), self.dtype), spec,
-                    self._cool_bundle())
+                args = (self.u, self.dev, self.fg if self.gravity else {},
+                        jnp.asarray(float(dt), self.dtype), spec,
+                        self._cool_bundle())
+                _hlo.note_dispatch(_fused_coarse_step, *args)
+                out = _fused_coarse_step(*args)
                 if spec.want_flux:
                     self.u, self._dt_cache, self._tracer_phi = out
                 else:
@@ -2016,7 +2041,6 @@ class AmrSim:
             telem.run_info.update(sim_run_info(self))
             import os as _os
 
-            from ramses_tpu.telemetry import hlo as _hlo
             if _os.environ.get("RAMSES_TELEMETRY_HLO", "1") != "0":
                 # static gather-traffic inventory of the fused coarse
                 # step for this tree: a lowering (trace, no compile),

@@ -20,6 +20,7 @@ from ramses_tpu.amr import bitperm
 from ramses_tpu.hydro import muscl
 from ramses_tpu.hydro.core import HydroStatic
 from ramses_tpu.hydro.timestep import cell_dt
+from ramses_tpu.telemetry.hlo import phase
 
 
 def pow2_cube(shape) -> bool:
@@ -200,10 +201,11 @@ def level_sweep(u_flat, interp_vals, stencil_src, vsgn, ok_ref, gloc,
     """
     ndim, nvar = cfg.ndim, cfg.nvar
     bcfg = dreplace(cfg, trailing_batch=True)
-    uloc = _gather_uloc(u_flat, interp_vals, stencil_src, vsgn, cfg)
-    noct = uloc.shape[-1]
-    # [noct, 6^d] → [6..., noct]
-    okl = ok_ref.T.reshape((6,) * ndim + (noct,))
+    with phase("gather"):
+        uloc = _gather_uloc(u_flat, interp_vals, stencil_src, vsgn, cfg)
+        noct = uloc.shape[-1]
+        # [noct, 6^d] → [6..., noct]
+        okl = ok_ref.T.reshape((6,) * ndim + (noct,))
 
     from ramses_tpu.hydro import pallas_oct
     if gloc is None and pallas_oct.available(cfg, noct, u_flat.dtype,
@@ -211,82 +213,87 @@ def level_sweep(u_flat, interp_vals, stencil_src, vsgn, ok_ref, gloc,
         # fused TPU oct-batch kernel (same physics, VMEM-resident);
         # self-gravity rides as the hierarchy's separate traced
         # half-kick, so gloc is None on every production path
-        out_k = pallas_oct.oct_sweep(
-            uloc, okl.astype(uloc.dtype), dt, cfg, dx,
-            want_flux=ret_flux)
-        du_k, corr_k = out_k[0], out_k[1]
+        with phase("kernel"):
+            out_k = pallas_oct.oct_sweep(
+                uloc, okl.astype(uloc.dtype), dt, cfg, dx,
+                want_flux=ret_flux)
+        with phase("scatter"):
+            du_k, corr_k = out_k[0], out_k[1]
+            du_flat = jnp.transpose(
+                du_k, (ndim + 1,) + tuple(range(1, ndim + 1)) + (0,)
+            ).reshape(noct * 2 ** ndim, nvar)
+            corr_out = jnp.transpose(corr_k, (3, 1, 2, 0))
+            if not ret_flux:
+                return du_flat, corr_out
+            # phi [3, 2, 2,2,2, N] → flat [ncell, ndim, 2]
+            phi_k = jnp.transpose(out_k[2], (5, 2, 3, 4, 0, 1)).reshape(
+                noct * 2 ** ndim, ndim, 2)
+            return du_flat, corr_out, phi_k
+
+    with phase("kernel"):
+        flux, tmp = _unsplit_fn(cfg)(uloc, gloc, dt, (dx,) * ndim, bcfg)
+        # flux[d]: [nvar, 6..., noct], defined at the LOW face of each cell.
+
+        # Reset flux along direction at refined interfaces
+        # (hydro/godunov_fine.f90:718-747): a face is zeroed when either
+        # adjacent cell is refined — its contribution comes from level+1;
+        # the reference zeroes the tmp (divu/eint-flux) faces the same way.
+        fluxes = []
+        tmps = []
+        for d in range(ndim):
+            keep = ~(okl | jnp.roll(okl, 1, axis=d))       # [6..., noct]
+            fluxes.append(flux[d] * keep[None].astype(flux.dtype))
+            if tmp is not None:
+                tmps.append(tmp[d] * keep[None].astype(flux.dtype))
+        # conservative update over the whole block (outer cells hold
+        # wrapped garbage the interior never consumes), then the optional
+        # dual-energy fix, then the interior extraction
+        un_blk = muscl.apply_fluxes(uloc, jnp.stack(fluxes), bcfg)
+        if tmp is not None and (cfg.pressure_fix or cfg.nener):
+            un_blk = muscl.dual_energy_fix(uloc, un_blk, jnp.stack(tmps),
+                                           dt, (dx,) * ndim, bcfg)
+    with phase("scatter"):
+        interior = (slice(None),) + tuple(slice(2, 4) for _ in range(ndim))
+        du = un_blk[interior] - uloc[interior]
+        # [nvar, 2..., noct] → flat [noct*2^d, nvar]
         du_flat = jnp.transpose(
-            du_k, (ndim + 1,) + tuple(range(1, ndim + 1)) + (0,)
+            du, (ndim + 1,) + tuple(range(1, ndim + 1)) + (0,)
         ).reshape(noct * 2 ** ndim, nvar)
-        corr_out = jnp.transpose(corr_k, (3, 1, 2, 0))
+
+        # boundary fluxes for the coarse correction: low face idx 2, high idx 4
+        corr = []
+        for d in range(ndim):
+            f = fluxes[d]
+            idx_lo = [slice(None)]
+            idx_hi = [slice(None)]
+            for d2 in range(ndim):
+                if d2 == d:
+                    idx_lo.append(2)
+                    idx_hi.append(4)
+                else:
+                    idx_lo.append(slice(2, 4))
+                    idx_hi.append(slice(2, 4))
+            red = tuple(range(1, 1 + ndim - 1))
+            lo, hi = f[tuple(idx_lo)], f[tuple(idx_hi)]
+            if ndim > 1:
+                lo, hi = lo.sum(axis=red), hi.sum(axis=red)
+            corr.append(jnp.stack([lo, hi], axis=-1))  # [nvar, noct, 2]
+        corr = jnp.stack(corr, axis=-2)            # [nvar, noct, ndim, 2]
+        corr = jnp.moveaxis(corr, 0, -1)           # [noct, ndim, 2, nvar]
         if not ret_flux:
-            return du_flat, corr_out
-        # phi [3, 2, 2,2,2, N] → flat [ncell, ndim, 2]
-        phi_k = jnp.transpose(out_k[2], (5, 2, 3, 4, 0, 1)).reshape(
-            noct * 2 ** ndim, ndim, 2)
-        return du_flat, corr_out, phi_k
-
-    flux, tmp = _unsplit_fn(cfg)(uloc, gloc, dt, (dx,) * ndim, bcfg)
-    # flux[d]: [nvar, 6..., noct], defined at the LOW face of each cell.
-
-    # Reset flux along direction at refined interfaces
-    # (hydro/godunov_fine.f90:718-747): a face is zeroed when either
-    # adjacent cell is refined — its contribution comes from level+1;
-    # the reference zeroes the tmp (divu/eint-flux) faces the same way.
-    fluxes = []
-    tmps = []
-    for d in range(ndim):
-        keep = ~(okl | jnp.roll(okl, 1, axis=d))       # [6..., noct]
-        fluxes.append(flux[d] * keep[None].astype(flux.dtype))
-        if tmp is not None:
-            tmps.append(tmp[d] * keep[None].astype(flux.dtype))
-    # conservative update over the whole block (outer cells hold
-    # wrapped garbage the interior never consumes), then the optional
-    # dual-energy fix, then the interior extraction
-    un_blk = muscl.apply_fluxes(uloc, jnp.stack(fluxes), bcfg)
-    if tmp is not None and (cfg.pressure_fix or cfg.nener):
-        un_blk = muscl.dual_energy_fix(uloc, un_blk, jnp.stack(tmps),
-                                       dt, (dx,) * ndim, bcfg)
-    interior = (slice(None),) + tuple(slice(2, 4) for _ in range(ndim))
-    du = un_blk[interior] - uloc[interior]
-    # [nvar, 2..., noct] → flat [noct*2^d, nvar]
-    du_flat = jnp.transpose(
-        du, (ndim + 1,) + tuple(range(1, ndim + 1)) + (0,)
-    ).reshape(noct * 2 ** ndim, nvar)
-
-    # boundary fluxes for the coarse correction: low face idx 2, high idx 4
-    corr = []
-    for d in range(ndim):
-        f = fluxes[d]
-        idx_lo = [slice(None)]
-        idx_hi = [slice(None)]
-        for d2 in range(ndim):
-            if d2 == d:
-                idx_lo.append(2)
-                idx_hi.append(4)
-            else:
-                idx_lo.append(slice(2, 4))
-                idx_hi.append(slice(2, 4))
-        red = tuple(range(1, 1 + ndim - 1))
-        lo = f[tuple(idx_lo)].sum(axis=red) if ndim > 1 else f[tuple(idx_lo)]
-        hi = f[tuple(idx_hi)].sum(axis=red) if ndim > 1 else f[tuple(idx_hi)]
-        corr.append(jnp.stack([lo, hi], axis=-1))      # [nvar, noct, 2]
-    corr = jnp.stack(corr, axis=-2)                    # [nvar, noct, ndim, 2]
-    corr = jnp.moveaxis(corr, 0, -1)                   # [noct, ndim, 2, nvar]
-    if not ret_flux:
-        return du_flat, corr
-    # per-cell (low, high) face mass flux: cell at stencil position i
-    # along d has its low face flux at index i, high face at i+1
-    phis = []
-    for d in range(ndim):
-        f0 = fluxes[d][0]                              # [6..., noct] mass
-        lo_ix = tuple(slice(2, 4) for _ in range(ndim))
-        hi_ix = tuple(slice(3, 5) if dd == d else slice(2, 4)
-                      for dd in range(ndim))
-        phis.append(jnp.stack([_flat_cells(f0[lo_ix], ndim),
-                               _flat_cells(f0[hi_ix], ndim)], axis=-1))
-    phi = jnp.stack(phis, axis=-2)                     # [ncell, ndim, 2]
-    return du_flat, corr, phi
+            return du_flat, corr
+        # per-cell (low, high) face mass flux: cell at stencil position i
+        # along d has its low face flux at index i, high face at i+1
+        phis = []
+        for d in range(ndim):
+            f0 = fluxes[d][0]                              # [6..., noct] mass
+            lo_ix = tuple(slice(2, 4) for _ in range(ndim))
+            hi_ix = tuple(slice(3, 5) if dd == d else slice(2, 4)
+                          for dd in range(ndim))
+            phis.append(jnp.stack([_flat_cells(f0[lo_ix], ndim),
+                                   _flat_cells(f0[hi_ix], ndim)], axis=-1))
+        phi = jnp.stack(phis, axis=-2)                     # [ncell, ndim, 2]
+        return du_flat, corr, phi
 
 
 # ---------------------------------------------------------------------------
@@ -381,72 +388,78 @@ def tile_sweep(u_flat, interp_vals, tile_src, tile_vsgn, tile_ok,
     ndim, nvar = cfg.ndim, cfg.nvar
     c = 1 << (shift + 1)
     td = c + 2 * _NG
-    ut = _gather_utile(u_flat, interp_vals, tile_src, tile_vsgn, cfg, td)
-    ntile = ut.shape[-1]
-    okl = tile_ok.T.reshape((td,) * ndim + (ntile,))
+    with phase("gather"):
+        ut = _gather_utile(u_flat, interp_vals, tile_src, tile_vsgn, cfg, td)
+        ntile = ut.shape[-1]
+        okl = tile_ok.T.reshape((td,) * ndim + (ntile,))
 
     from ramses_tpu.hydro import pallas_oct
     if pallas_ok and pallas_oct.tile_available(cfg, ntile, u_flat.dtype,
                                                 shift):
-        out_k = pallas_oct.tile_sweep(ut, okl.astype(ut.dtype), dt, cfg,
-                                      dx, shift, want_flux=ret_flux)
-        du_t, corrp = out_k[0], out_k[1]
-        planes = [corrp[:, d] for d in range(ndim)]
-        mass = ([out_k[2][d] for d in range(ndim)] if ret_flux else None)
+        with phase("kernel"):
+            out_k = pallas_oct.tile_sweep(ut, okl.astype(ut.dtype), dt, cfg,
+                                          dx, shift, want_flux=ret_flux)
+        with phase("scatter"):
+            du_t, corrp = out_k[0], out_k[1]
+            planes = [corrp[:, d] for d in range(ndim)]
+            mass = ([out_k[2][d] for d in range(ndim)] if ret_flux else None)
     else:
-        bcfg = dreplace(cfg, trailing_batch=True)
-        flux, tmp = _unsplit_fn(cfg)(ut, None, dt, (dx,) * ndim, bcfg)
-        fluxes = []
-        tmps = []
+        with phase("kernel"):
+            bcfg = dreplace(cfg, trailing_batch=True)
+            flux, tmp = _unsplit_fn(cfg)(ut, None, dt, (dx,) * ndim, bcfg)
+            fluxes = []
+            tmps = []
+            for d in range(ndim):
+                keep = ~(okl | jnp.roll(okl, 1, axis=d))
+                fluxes.append(flux[d] * keep[None].astype(flux.dtype))
+                if tmp is not None:
+                    tmps.append(tmp[d] * keep[None].astype(flux.dtype))
+            un_blk = muscl.apply_fluxes(ut, jnp.stack(fluxes), bcfg)
+            if tmp is not None and (cfg.pressure_fix or cfg.nener):
+                un_blk = muscl.dual_energy_fix(ut, un_blk, jnp.stack(tmps),
+                                               dt, (dx,) * ndim, bcfg)
+        with phase("scatter"):
+            interior = (slice(None),) + (slice(_NG, _NG + c),) * ndim
+            du_t = un_blk[interior] - ut[interior]
+            planes = [_face_planes(fluxes[d], d, ndim, c) for d in range(ndim)]
+            mass = ([_mass_planes(fluxes[d][0], d, ndim, c)
+                     for d in range(ndim)] if ret_flux else None)
+
+    with phase("scatter"):
+        # interior update → flat rows.  Pad cell rows carry slot c^d /
+        # tile 0 (maps.py), which flattens one past the interior batch —
+        # an appended zero column — so they come out exactly 0 with no
+        # masking on the real-row dataflow.
+        flat_idx = cell_slot * ntile + cell_tile
+        du_src = jnp.concatenate(
+            [du_t.reshape((nvar, c ** ndim * ntile)),
+             jnp.zeros((nvar, 1), du_t.dtype)], axis=1)
+        du_flat = du_src[:, flat_idx].T                    # [ncell_pad, nvar]
+
+        # boundary fluxes → per-oct corr rows
+        corr = []
         for d in range(ndim):
-            keep = ~(okl | jnp.roll(okl, 1, axis=d))
-            fluxes.append(flux[d] * keep[None].astype(flux.dtype))
-            if tmp is not None:
-                tmps.append(tmp[d] * keep[None].astype(flux.dtype))
-        un_blk = muscl.apply_fluxes(ut, jnp.stack(fluxes), bcfg)
-        if tmp is not None and (cfg.pressure_fix or cfg.nener):
-            un_blk = muscl.dual_energy_fix(ut, un_blk, jnp.stack(tmps),
-                                           dt, (dx,) * ndim, bcfg)
-        interior = (slice(None),) + (slice(_NG, _NG + c),) * ndim
-        du_t = un_blk[interior] - ut[interior]
-        planes = [_face_planes(fluxes[d], d, ndim, c) for d in range(ndim)]
-        mass = ([_mass_planes(fluxes[d][0], d, ndim, c)
-                 for d in range(ndim)] if ret_flux else None)
+            lo, hi = _corr_from_planes(planes[d], d, ndim, c)
+            lo_g = lo[:, oct_slot, oct_tile]
+            hi_g = hi[:, oct_slot, oct_tile]
+            corr.append(jnp.stack([lo_g, hi_g], axis=-1))  # [nvar, noct, 2]
+        corr = jnp.stack(corr, axis=-2)            # [nvar, noct, nd, 2]
+        corr = jnp.moveaxis(corr, 0, -1)           # [noct, nd, 2, nvar]
+        if not ret_flux:
+            return du_flat, corr
 
-    # interior update → flat rows.  Pad cell rows carry slot c^d /
-    # tile 0 (maps.py), which flattens one past the interior batch —
-    # an appended zero column — so they come out exactly 0 with no
-    # masking on the real-row dataflow.
-    flat_idx = cell_slot * ntile + cell_tile
-    du_src = jnp.concatenate(
-        [du_t.reshape((nvar, c ** ndim * ntile)),
-         jnp.zeros((nvar, 1), du_t.dtype)], axis=1)
-    du_flat = du_src[:, flat_idx].T                    # [ncell_pad, nvar]
-
-    # boundary fluxes → per-oct corr rows
-    corr = []
-    for d in range(ndim):
-        lo, hi = _corr_from_planes(planes[d], d, ndim, c)
-        lo_g = lo[:, oct_slot, oct_tile]
-        hi_g = hi[:, oct_slot, oct_tile]
-        corr.append(jnp.stack([lo_g, hi_g], axis=-1))  # [nvar, noct, 2]
-    corr = jnp.stack(corr, axis=-2)                    # [nvar, noct, nd, 2]
-    corr = jnp.moveaxis(corr, 0, -1)                   # [noct, nd, 2, nvar]
-    if not ret_flux:
-        return du_flat, corr
-
-    # per-cell (low, high) face mass flux
-    def _cell_rows(x, d):
-        x = jnp.moveaxis(x, 0, d)                      # [c..., ntile]
-        xf = jnp.concatenate([x.reshape(c ** ndim * ntile),
-                              jnp.zeros((1,), x.dtype)])
-        return xf[flat_idx]
-    phis = []
-    for d in range(ndim):
-        phis.append(jnp.stack([_cell_rows(mass[d][:c], d),
-                               _cell_rows(mass[d][1:c + 1], d)], axis=-1))
-    phi = jnp.stack(phis, axis=-2)                     # [ncell, ndim, 2]
-    return du_flat, corr, phi
+        # per-cell (low, high) face mass flux
+        def _cell_rows(x, d):
+            x = jnp.moveaxis(x, 0, d)                      # [c..., ntile]
+            xf = jnp.concatenate([x.reshape(c ** ndim * ntile),
+                                  jnp.zeros((1,), x.dtype)])
+            return xf[flat_idx]
+        phis = []
+        for d in range(ndim):
+            phis.append(jnp.stack([_cell_rows(mass[d][:c], d),
+                                   _cell_rows(mass[d][1:c + 1], d)], axis=-1))
+        phi = jnp.stack(phis, axis=-2)                     # [ncell, ndim, 2]
+        return du_flat, corr, phi
 
 
 @partial(jax.jit, static_argnames=("cfg", "err_grad", "floors", "shift"))
@@ -461,14 +474,17 @@ def tile_refine_flags(u_flat, interp_vals, tile_src, tile_vsgn,
     nd = cfg.ndim
     c = 1 << (shift + 1)
     td = c + 2 * _NG
-    ut = _gather_utile(u_flat, interp_vals, tile_src, tile_vsgn, cfg, td)
-    ntile = ut.shape[-1]
-    ok = _flags_fn(cfg)(ut, err_grad, floors, spatial0=0, cfg=cfg)
-    interior = (slice(_NG, _NG + c),) * nd
-    okc = jnp.concatenate([ok[interior].reshape(c ** nd * ntile),
-                           jnp.zeros((1,), ok.dtype)])
-    rows = okc[cell_slot * ntile + cell_tile]          # [ncell_pad]
-    return rows.reshape(len(cell_slot) // 2 ** nd, 2 ** nd)
+    with phase("gather"):
+        ut = _gather_utile(u_flat, interp_vals, tile_src, tile_vsgn, cfg, td)
+        ntile = ut.shape[-1]
+    with phase("criteria"):
+        ok = _flags_fn(cfg)(ut, err_grad, floors, spatial0=0, cfg=cfg)
+    with phase("scatter"):
+        interior = (slice(_NG, _NG + c),) * nd
+        okc = jnp.concatenate([ok[interior].reshape(c ** nd * ntile),
+                               jnp.zeros((1,), ok.dtype)])
+        rows = okc[cell_slot * ntile + cell_tile]          # [ncell_pad]
+        return rows.reshape(len(cell_slot) // 2 ** nd, 2 ** nd)
 
 
 def dense_interior_update(up, okp, dt, dx: float, shape: Tuple[int, ...],
@@ -560,47 +576,48 @@ def dense_sweep(u_flat, inv_perm, perm, ok_dense, dt, dx: float,
     ncell = 1
     for s in shape:
         ncell *= s
-    ud = rows_to_dense(u_flat, inv_perm, shape)        # [*shape, nvar]
-    ud = jnp.moveaxis(ud, -1, 0)                       # [nvar, *shape]
+    with phase("gather"):
+        ud = rows_to_dense(u_flat, inv_perm, shape)        # [*shape, nvar]
+        ud = jnp.moveaxis(ud, -1, 0)                       # [nvar, *shape]
     if pk.kernel_available(cfg, shape, bc.faces, ud.dtype, ndev):
         # fused TPU kernel path (same physics, VMEM-resident pipeline);
         # refined-face flux zeroing rides in as the mask input, the
         # MC-tracer face-flux capture as a second kernel output
-        ok = ok_dense.reshape(shape) if ok_dense is not None else None
-        up, okp = pk.pad_xy(ud, bc, cfg, ok=ok)
-        if ret_flux:
-            un, phid = pk.fused_step_padded(up, dt, cfg, dx, shape,
-                                            ok_pad=okp, want_flux=True)
-        else:
-            un = pk.fused_step_padded(up, dt, cfg, dx, shape, ok_pad=okp)
-        du_rows = dense_to_rows(jnp.moveaxis(un - ud, 0, -1), perm, shape)
+        with phase("pad"):
+            ok = ok_dense.reshape(shape) if ok_dense is not None else None
+            up, okp = pk.pad_xy(ud, bc, cfg, ok=ok)
+        with phase("kernel"):
+            if ret_flux:
+                un, phid = pk.fused_step_padded(up, dt, cfg, dx, shape,
+                                                ok_pad=okp, want_flux=True)
+            else:
+                un = pk.fused_step_padded(up, dt, cfg, dx, shape,
+                                          ok_pad=okp)
+        du_dense = un - ud
+        # phid [3, 2, *shape] → flat rows [ncell, ndim, 2]
+        phi_dense = (jnp.moveaxis(phid, (0, 1), (-2, -1)) if ret_flux
+                     else None)
+    else:
+        with phase("pad"):
+            up = bmod.pad(ud, bc, cfg, muscl.NGHOST, dx=dx)
+            okp = (pad_ok_dense(ok_dense, shape, bc, up.dtype, muscl.NGHOST)
+                   if ok_dense is not None else None)
+        with phase("kernel"):
+            out = dense_interior_update(up, okp, dt, dx, shape, cfg,
+                                        ret_flux=ret_flux)
+        du_dense = out[0] if ret_flux else out         # [nvar, *shape]
+        phi_dense = out[1] if ret_flux else None       # [*shape, ndim, 2]
+    with phase("scatter"):
+        du_rows = dense_to_rows(jnp.moveaxis(du_dense, 0, -1), perm, shape)
         if u_flat.shape[0] > ncell:
             du_rows = jnp.zeros_like(u_flat).at[:ncell].set(du_rows)
         if not ret_flux:
             return du_rows
-        # phid [3, 2, *shape] → flat rows [ncell, ndim, 2]
-        phi = dense_to_rows(jnp.moveaxis(phid, (0, 1), (-2, -1)),
-                            perm, shape)
+        phi = dense_to_rows(phi_dense, perm, shape)    # [ncell, ndim, 2]
         if u_flat.shape[0] > ncell:
             phi = jnp.zeros((u_flat.shape[0], nd, 2),
                             phi.dtype).at[:ncell].set(phi)
         return du_rows, phi
-    up = bmod.pad(ud, bc, cfg, muscl.NGHOST, dx=dx)
-    okp = (pad_ok_dense(ok_dense, shape, bc, up.dtype, muscl.NGHOST)
-           if ok_dense is not None else None)
-    out = dense_interior_update(up, okp, dt, dx, shape, cfg,
-                                ret_flux=ret_flux)
-    du_dense = out[0] if ret_flux else out             # [nvar, *shape]
-    du_rows = dense_to_rows(jnp.moveaxis(du_dense, 0, -1), perm, shape)
-    if u_flat.shape[0] > ncell:
-        du_rows = jnp.zeros_like(u_flat).at[:ncell].set(du_rows)
-    if not ret_flux:
-        return du_rows
-    phi = dense_to_rows(out[1], perm, shape)           # [ncell, ndim, 2]
-    if u_flat.shape[0] > ncell:
-        phi = jnp.zeros((u_flat.shape[0], nd, 2),
-                        phi.dtype).at[:ncell].set(phi)
-    return du_rows, phi
 
 
 @partial(jax.jit, static_argnames=("cfg", "shape", "bc", "err_grad",
@@ -618,12 +635,16 @@ def dense_refine_flags(u_flat, inv_perm, perm,
     ncell = 1
     for s in shape:
         ncell *= s
-    ud = jnp.moveaxis(rows_to_dense(u_flat, inv_perm, shape), -1, 0)
-    up = bmod.pad(ud, bc, cfg, 1, dx=dx)
-    ok = _flags_fn(cfg)(up, err_grad, floors, spatial0=0, cfg=cfg)
-    ok = ok[tuple(slice(1, -1) for _ in range(nd))]    # interior
-    flags_flat = dense_to_rows(ok, perm, shape)        # flat cell order
-    return flags_flat.reshape(ncell // 2 ** nd, 2 ** nd)
+    with phase("gather"):
+        ud = jnp.moveaxis(rows_to_dense(u_flat, inv_perm, shape), -1, 0)
+    with phase("pad"):
+        up = bmod.pad(ud, bc, cfg, 1, dx=dx)
+    with phase("criteria"):
+        ok = _flags_fn(cfg)(up, err_grad, floors, spatial0=0, cfg=cfg)
+    with phase("scatter"):
+        ok = ok[tuple(slice(1, -1) for _ in range(nd))]    # interior
+        flags_flat = dense_to_rows(ok, perm, shape)    # flat cell order
+        return flags_flat.reshape(ncell // 2 ** nd, 2 ** nd)
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -711,14 +732,17 @@ def refine_flags(u_flat, interp_vals, stencil_src, vsgn,
 
     Returns bool flags [noct, 2^d] in flat-cell order.
     """
-    uloc = _gather_uloc(u_flat, interp_vals, stencil_src, vsgn, cfg)
+    with phase("gather"):
+        uloc = _gather_uloc(u_flat, interp_vals, stencil_src, vsgn, cfg)
     nd = cfg.ndim
     # fields below are [6..., noct]: spatial axes 0..nd-1, oct axis last
-    ok = _flags_fn(cfg)(uloc, err_grad, floors, spatial0=0, cfg=cfg)
-    interior = tuple(slice(2, 4) for _ in range(nd))
-    okc = ok[interior]                                 # [2..., noct]
-    okc = jnp.moveaxis(okc, -1, 0)                     # [noct, 2...]
-    return okc.reshape(okc.shape[0], 2 ** nd)
+    with phase("criteria"):
+        ok = _flags_fn(cfg)(uloc, err_grad, floors, spatial0=0, cfg=cfg)
+    with phase("scatter"):
+        interior = tuple(slice(2, 4) for _ in range(nd))
+        okc = ok[interior]                             # [2..., noct]
+        okc = jnp.moveaxis(okc, -1, 0)                 # [noct, 2...]
+        return okc.reshape(okc.shape[0], 2 ** nd)
 
 
 def two_sided_rel_err(f, floor, nd: int, spatial0: int):
@@ -840,7 +864,8 @@ def sweep_level(spec, i: int, u_l, u_lm1, d, dtl):
                               ndev=spec.ndev)
         return (out[0], None, out[1]) if spec.want_flux \
             else (out, None, None)
-    interp = _ghost_cells(spec, i, u_l, u_lm1, d, spec.itype)
+    with phase("ghost"):
+        interp = _ghost_cells(spec, i, u_l, u_lm1, d, spec.itype)
     if kind == "tile":
         # the compact Morton-tile batch replaces the ~(3^d)x-duplicated
         # stencil gather.  Pad cell rows index the kernels' appended
@@ -874,7 +899,8 @@ def flags_level(spec, i: int, u_l, u_lm1, d, eg, fls, itype: int):
                                   eg, fls, dense_shape(spec, l),
                                   spec.bspec, cfg,
                                   dx=spec.boxlen / (1 << l))
-    interp = _ghost_cells(spec, i, u_l, u_lm1, d, itype)
+    with phase("ghost"):
+        interp = _ghost_cells(spec, i, u_l, u_lm1, d, itype)
     if kind == "tile":
         return tile_refine_flags(u_l, interp, d["tile_src"],
                                  d["tile_vsgn"], d["cell_tile"],

@@ -96,6 +96,11 @@ def _tile(noct_pad: int) -> int:
     raise AssertionError("gated by available()")
 
 
+# the device ops' names in a trace (``name=`` of the two pallas_calls)
+OCT_KERNEL_NAME = "oct_sweep"
+TILE_KERNEL_NAME = "tile_sweep"
+
+
 def _make_kernel(cfg: HydroStatic, dx: float, want_flux: bool = False):
     """Kernel body; refs: u [5,6,6,6,NT], ok [6,6,6,NT] (state-dtype
     0/1 refined mask), dt [1,1] SMEM → du [5,2,2,2,NT] (interior
@@ -228,7 +233,7 @@ def oct_sweep(uloc, ok, dt, cfg: HydroStatic, dx: float,
         ],
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
-        interpret=interpret,
+        interpret=interpret, name=OCT_KERNEL_NAME,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
     )(uloc, ok, dt2)
@@ -405,7 +410,7 @@ def tile_sweep(ut, ok, dt, cfg: HydroStatic, dx: float, shift: int,
         ],
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
-        interpret=interpret,
+        interpret=interpret, name=TILE_KERNEL_NAME,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
     )(ut, ok, dt2)

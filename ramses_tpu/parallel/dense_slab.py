@@ -58,6 +58,7 @@ from ramses_tpu.amr import bitperm
 from ramses_tpu.hydro import muscl
 from ramses_tpu.parallel import dma_halo
 from ramses_tpu.parallel.mesh import OCT_AXIS
+from ramses_tpu.telemetry.hlo import phase
 
 
 class SlabSpec(NamedTuple):
@@ -189,10 +190,15 @@ def dense_apply_slab(rows, spec: SlabSpec, local_fn, ng: int,
     nd = spec.ndim
 
     def body(r_loc):
-        dense = bitperm.flat_to_dense_slab(r_loc, spec.lvl, nd,
-                                           spec.mbits)
-        out = local_fn(halo_extend(dense, spec, ng, 0))
-        return bitperm.dense_to_flat_slab(out, spec.lvl, nd, spec.mbits)
+        with phase("gather"):
+            dense = bitperm.flat_to_dense_slab(r_loc, spec.lvl, nd,
+                                               spec.mbits)
+        with phase("pad"):
+            ext = halo_extend(dense, spec, ng, 0)
+        out = local_fn(ext)
+        with phase("scatter"):
+            return bitperm.dense_to_flat_slab(out, spec.lvl, nd,
+                                              spec.mbits)
 
     in_spec = P(OCT_AXIS, *([None] * (rows.ndim - 1)))
     out_rank = out_ndim if out_ndim is not None else rows.ndim
@@ -249,25 +255,30 @@ def dense_sweep_slab(u_flat, ok_flat, dt, dx: float, spec: SlabSpec,
             (spec.loc[dsp] - 2 * ng) / spec.loc[dsp])
 
     def _update(up, okp, dt_, shape):
-        return K.dense_interior_update(up, okp, dt_, dx, shape, cfg,
-                                       ret_flux=ret_flux)
+        with phase("kernel"):
+            return K.dense_interior_update(up, okp, dt_, dx, shape, cfg,
+                                           ret_flux=ret_flux)
 
     def body(u_loc, ok_loc, dt_):
-        ud = bitperm.flat_to_dense_slab(u_loc, spec.lvl, nd, spec.mbits)
         ext = None if kaxes is None else kaxes[:2]
         if dsp is not None:
             ext = tuple(d for d in range(nd) if d != dsp)
-        up = halo_extend(jnp.moveaxis(ud, -1, 0), spec, ng, 1, axes=ext)
-        okp = None
-        if masked:
-            # convert on the flat rows (clean shard-local op), halo the
-            # arithmetic mask exactly like the state
-            okd = bitperm.flat_to_dense_slab(
-                ok_loc.astype(u_loc.dtype), spec.lvl, nd, spec.mbits)
-            okp = halo_extend(okd, spec, ng, 0, axes=ext)
+        with phase("gather"):
+            ud = jnp.moveaxis(bitperm.flat_to_dense_slab(
+                u_loc, spec.lvl, nd, spec.mbits), -1, 0)
+            if masked:
+                # convert on the flat rows (clean shard-local op), halo
+                # the arithmetic mask exactly like the state
+                okd = bitperm.flat_to_dense_slab(
+                    ok_loc.astype(u_loc.dtype), spec.lvl, nd, spec.mbits)
+        with phase("pad"):
+            up = halo_extend(ud, spec, ng, 1, axes=ext)
+            okp = (halo_extend(okd, spec, ng, 0, axes=ext) if masked
+                   else None)
         if kaxes is not None:
-            out = pk.fused_step_shard(up, okp, dt_, cfg, dx, spec.loc,
-                                      kaxes, want_flux=ret_flux)
+            with phase("kernel"):
+                out = pk.fused_step_shard(up, okp, dt_, cfg, dx, spec.loc,
+                                          kaxes, want_flux=ret_flux)
         elif dsp is not None:
             # overlap split: start the DMA of the deferred axis' slabs,
             # compute the ghost-free interior band meanwhile, finish
@@ -281,8 +292,9 @@ def dense_sweep_slab(u_flat, ok_flat, dt, dx: float, spec: SlabSpec,
                 sends += [_take(okp, dsp, slice(-ng, None)),
                           _take(okp, dsp, slice(0, ng))]
                 perms += [list(fwd), list(bwd)]
-            ghosts = dma_halo.exchange_slabs(sends, perms, OCT_AXIS,
-                                             backend=spec.backend)
+            with phase("pad"):
+                ghosts = dma_halo.exchange_slabs(sends, perms, OCT_AXIS,
+                                                 backend=spec.backend)
             shape_int = tuple(spec.loc[d] - (2 * ng if d == dsp else 0)
                               for d in range(nd))
             shape_strip = tuple(ng if d == dsp else spec.loc[d]
@@ -312,13 +324,14 @@ def dense_sweep_slab(u_flat, ok_flat, dt, dx: float, spec: SlabSpec,
         else:
             out = _update(up, okp, dt_, spec.loc)
         du = out[0] if ret_flux else out
-        du_rows = bitperm.dense_to_flat_slab(
-            jnp.moveaxis(du, 0, -1), spec.lvl, nd, spec.mbits)
-        if not ret_flux:
-            return du_rows
-        phi_rows = bitperm.dense_to_flat_slab(out[1], spec.lvl, nd,
-                                              spec.mbits)
-        return du_rows, phi_rows
+        with phase("scatter"):
+            du_rows = bitperm.dense_to_flat_slab(
+                jnp.moveaxis(du, 0, -1), spec.lvl, nd, spec.mbits)
+            if not ret_flux:
+                return du_rows
+            phi_rows = bitperm.dense_to_flat_slab(out[1], spec.lvl, nd,
+                                                  spec.mbits)
+            return du_rows, phi_rows
 
     ok_in = P(OCT_AXIS) if masked else P()
     out_specs = ((P(OCT_AXIS, None), P(OCT_AXIS, None, None))
@@ -338,8 +351,10 @@ def dense_flags_slab(u_flat, spec: SlabSpec, flags_fn, twotondim: int):
     nd = spec.ndim
 
     def local_fn(dense_ext):
-        ok = flags_fn(jnp.moveaxis(dense_ext, -1, 0))
-        return ok[tuple(slice(1, -1) for _ in range(nd))]
+        with phase("criteria"):
+            ok = flags_fn(jnp.moveaxis(dense_ext, -1, 0))
+        with phase("scatter"):
+            return ok[tuple(slice(1, -1) for _ in range(nd))]
 
     flags = dense_apply_slab(u_flat, spec, local_fn, ng=1, out_ndim=1)
     return flags.reshape(flags.shape[0] // twotondim, twotondim)

@@ -33,6 +33,11 @@ from ramses_tpu import platform
 _RING: collections.deque = collections.deque(maxlen=1 << 14)
 # labels of the recorded spans open on this thread, outermost first
 _OPEN = threading.local()
+# the spans in which the host blocks on the device (a fetch that waits
+# out what was dispatched): their records say ``wait``.  Decided here,
+# by label, so no call site says it
+WAIT_LABELS = frozenset({"regrid: flag fetch", "evolve: wait",
+                         "courant: fetch"})
 
 
 def span(label: str, timers: Optional["Timers"] = None):
@@ -49,8 +54,10 @@ def span(label: str, timers: Optional["Timers"] = None):
 @contextlib.contextmanager
 def _recorded(label: str, timers: Optional["Timers"], traced: bool):
     """The recording half of :func:`span`: ``{name, parent, depth, t0_ns,
-    t1_ns, compiles, compile_s, traced}`` on ``time.perf_counter_ns`` —
-    ``parent`` the enclosing recorded span's label, ``compiles`` /
+    t1_ns, compiles, compile_s, traced, wait}`` on
+    ``time.perf_counter_ns`` — ``parent`` the enclosing recorded span's
+    label, ``wait`` whether the host blocks on the device in it
+    (:data:`WAIT_LABELS`), ``compiles`` /
     ``compile_s`` what the compile timer (``platform._CACHE_STATS``:
     compile or cache load) counted across it, ``traced`` whether it
     opened under a session.  ``timers`` also gets its label switched
@@ -78,7 +85,7 @@ def _recorded(label: str, timers: Optional["Timers"], traced: bool):
                 "t0_ns": t0, "t1_ns": t1,
                 "compiles": stats["compiles"] - ncomp,
                 "compile_s": stats["compile_s"] - comp_s,
-                "traced": traced})
+                "traced": traced, "wait": label in WAIT_LABELS})
 
 
 def span_records() -> List[dict]:

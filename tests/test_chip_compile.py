@@ -369,3 +369,79 @@ def test_mhd_batched_run_steps_compiles(one_chip, mhd_cfg, no_cache):
     assert pc.KERNEL_NAME in hlo
     _fits_one_chip(compiled)
 
+
+
+# ----------------------------------------------------------------------
+# the op -> phase table on the chip's own layout copies
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("program", ["step", "flags"])
+def test_amr_programs_phase_table(one_chip, no_cache, monkeypatch, program):
+    """The coarse step and the flags program of a small 3-level
+    hierarchy (complete level 4 on the XLA dense sweep: the fused
+    kernel starts at 128 lanes; levels 5 and 6 on the Pallas tile
+    kernel), compiled for the described v5e: every instruction parses,
+    the tile kernels carry their ``name=`` and sit under ``kernel``,
+    and every ``copy`` / ``reshape`` / ``transpose`` the TPU compiler
+    left in the entry computation owns a phase — by its own ``op_name``
+    or by what reads it — or is listed here."""
+    import warnings
+
+    from ramses_tpu.amr import hierarchy as H
+    from ramses_tpu.config import params_from_string
+    from ramses_tpu.telemetry import hlo
+    from tests.test_oct_blocking import SEDOV3D
+    with jax.enable_x64(False):
+        sim = H.AmrSim(params_from_string(SEDOV3D.format(
+            lmin=4, lmax=6, blk=".true.", riemann="llf"), ndim=3), dtype=F32)
+        # the gates ask the backend: steered here, not by an option
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        sim._spec = None
+        spec = sim._fused_spec()
+        u, dev = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            (sim.u, sim.dev))
+        if program == "step":
+            dt = jax.ShapeDtypeStruct((), F32, sharding=one_chip)
+            compiled = H._fused_coarse_step.lower(
+                u, dev, {}, dt, spec, None).compile()
+        else:
+            r = sim.params.refine
+            compiled = H._fused_flags.lower(
+                u, dev, spec,
+                (float(r.err_grad_d), float(r.err_grad_u),
+                 float(r.err_grad_p)),
+                (float(r.floor_d), float(r.floor_u), float(r.floor_p)),
+                int(r.interpol_type)).compile()
+    text = compiled.as_text()
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")      # parsed == present
+        ins = hlo.parse_instructions(text)
+        tab = hlo.phase_table(text)
+    assert hlo.module_name(text) == ("jit__fused_coarse_step"
+                                     if program == "step"
+                                     else "jit__fused_flags")
+    kernels = [n for n, i in ins.items() if i["opcode"] == "custom-call"
+               and n.startswith(po.TILE_KERNEL_NAME)]
+    if program == "step":
+        # one call a sweep: level 5 twice, level 6 four times
+        assert text.count('custom_call_target="tpu_custom_call"') == 6
+        assert len(kernels) == 6
+        assert {tab[n] for n in kernels} == {
+            ("sweep l5/kernel", "kernel"), ("sweep l6/kernel", "kernel")}
+    else:
+        assert not kernels
+    work = [n for n, i in ins.items() if i["opcode"] not in (
+        "parameter", "constant", "tuple", "get-tuple-element", "bitcast")]
+    lost = [n for n in work if tab[n][0] == hlo.UNATTRIBUTED]
+    assert len(lost) <= 0.02 * len(work), lost
+    layout_ops = [n for n, i in ins.items() if i["caller"] == ""
+                  and i["opcode"] in ("copy", "reshape", "transpose")]
+    assert len(layout_ops) > 20
+    listed = ()             # none today: every one owns a phase
+    assert [n for n in layout_ops if tab[n][0] == hlo.UNATTRIBUTED
+            and n not in listed] == []
+    outer = "sweep" if program == "step" else "flags"
+    paths = {tab[n][0] for n in work}
+    assert {f"{outer} l{l}/gather" for l in (4, 5, 6)} <= paths
+    assert {f"{outer} l{l}/ghost" for l in (5, 6)} <= paths

@@ -116,7 +116,7 @@ def test_nesting_parent_and_self_time_under_a_fake_clock(monkeypatch, ring):
     assert [(r["name"], r["parent"], r["depth"], _dur(r)) for r in recs] == [
         ("c", "b", 2, 250_000_000), ("b", "a", 1, 2_250_000_000),
         ("a", None, 0, 3_750_000_000), ("a", None, 0, 4_000_000_000)]
-    assert not any(r["traced"] for r in recs)
+    assert not any(r["traced"] or r["wait"] for r in recs)
     assert all(r["compiles"] == 0 and r["compile_s"] == 0.0 for r in recs)
 
 
@@ -159,6 +159,13 @@ def test_off_is_off_no_record_no_clock_no_fetch(monkeypatch, ring):
     sim.regrid()
     assert sim.tree.noct(sim.lmax) != octs      # the full path, not the
     assert len(calls) == 1                      # early return; ONE trip
+    # the Courant pass after it (its ``courant: fetch`` child is a
+    # section like the others) and a coarse step: still no clock, and
+    # nothing noted for ``telemetry/hlo.device_phases``
+    from ramses_tpu.telemetry import hlo
+    assert sim._dt_cache is None
+    sim.step_coarse(sim.coarse_dt())
+    assert hlo.dispatch_records() == []
     assert ring() == []
     assert sim.timers.snapshot() == {}
 
@@ -213,6 +220,9 @@ def test_regrid_under_a_profiler_session(tmp_path, ring):
     assert all(r["traced"] for r in recs)
     assert {r["name"] for r in recs} == REGRID_LABELS | {
         "hydro - godunov", "evolve: wait"}
+    # the spans in which the host blocks on the device say so
+    assert {r["name"] for r in recs if r["wait"]} == {
+        "regrid: flag fetch", "evolve: wait"}
     regrids = [r for r in recs if r["name"] == "regrid"]
     assert len(regrids) == 2
     assert all(r["parent"] is None and r["depth"] == 0 for r in regrids)
