@@ -145,6 +145,33 @@ def phase_native():
     assert L is not None, f"native build failed: {native.build_error}"
     say(f"[native] ok built from ramses_tpu/native/src/ramses_native.cpp "
         f"-> {os.path.relpath(native.so_path(), ROOT)}")
+    # the tile tables of a blocked level both ways: a machine without
+    # the one-pass builder must fail here, not measure numpy
+    import numpy as np
+    from ramses_tpu.amr import maps as mapmod
+    from ramses_tpu.amr.tree import Octree
+    tree = Octree.base(3, 4, 6)
+    for lvl, r in ((5, 5.5), (6, 4.5)):
+        # a ball on the reflecting x and the outflow z face, clear of
+        # the periodic y faces (graded on every face as it stands)
+        og = np.indices((1 << (lvl - 1),) * 3).reshape(3, -1).T
+        d = og - np.array([1.5, 1 << (lvl - 2), 1.5])
+        tree.set_level(lvl, og[(d * d).sum(axis=1) < r * r])
+    bc = [(1, 1), (0, 0), (2, 2)]
+    for lvl in (5, 6):
+        b = mapmod.build_block_maps(tree, lvl, bc)
+        os.environ["RAMSES_TPU_NATIVE"] = "0"
+        try:
+            a = mapmod.build_block_maps(tree, lvl, bc)
+        finally:
+            del os.environ["RAMSES_TPU_NATIVE"]
+        assert a.tiles_native == 0 and b.tiles_native == b.ntile > 0, \
+            (a.tiles_native, b.tiles_native, b.ntile)
+        for f in mapmod.BLOCK_TABLES:
+            x, y = getattr(a, f), getattr(b, f)
+            assert x.dtype == y.dtype and np.array_equal(x, y), (lvl, f)
+        say(f"[native] tile tables level {lvl}: {b.ntile} tiles, {b.ni} "
+            f"interpolation rows, native == numpy")
 
 
 def phase_uniform(nml, rehearse):
@@ -210,6 +237,10 @@ def phase_amr(nml, rehearse):
         # moved the refined shell at least once for it to count
         assert sim.regrid_interval == 1 and sim.nstep >= 2
         assert octs != octs0, "no regrid changed the tree"
+        bst = sim.block_stats
+        say(f"[amr] tile tables: tiles_native={bst['tiles_native']} of "
+            f"blocks_total={bst['blocks_total']}")
+        assert bst["tiles_native"] == bst["blocks_total"] > 0, bst
         check_finite("amr", [(f"u[{l}]", sim.u[l]) for l in sim.levels()])
         tot = sim.totals()
         dm, de = rel(tot[0], tot0[0]), rel(tot[4], tot0[4])

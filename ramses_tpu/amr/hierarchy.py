@@ -920,7 +920,7 @@ class AmrSim:
         self.dev: Dict[int, dict] = {}
         self.blocks: Dict[int, mapmod.BlockMaps] = {}
         self.block_stats = {"blocks_total": 0, "blocks_rebuilt": 0,
-                            "stencil_octs_built": 0}
+                            "stencil_octs_built": 0, "tiles_native": 0}
         self._built_lay = {}
         for l in range(self.lmin, self.lmax + 1):
             if not self.tree.has(l):
@@ -936,9 +936,9 @@ class AmrSim:
                 if l in prev_blocks:
                     # unchanged (l-1, l, l+1) oct sets: every per-block
                     # map is still valid — zero blocks rebuilt
-                    self.blocks[l] = prev_blocks[l]
-                    self.block_stats["blocks_total"] += \
-                        prev_blocks[l].ntile
+                    b = self.blocks[l] = prev_blocks[l]
+                    self.block_stats["blocks_total"] += b.ntile
+                    self.block_stats["tiles_native"] += b.tiles_native
                 continue
             if (l in prev_maps and prev_maps[l].complete
                     and self._keys_same(old_tree, l)):
@@ -1021,17 +1021,17 @@ class AmrSim:
                             "rep"),
                     )
             if self._block_level_ok(l):
-                b = mapmod.build_block_maps(
-                    self.tree, l, self.bc_kinds,
-                    shift=int(getattr(self.params.amr,
-                                      "oct_block_shift", 2)),
-                    noct_pad=m.noct_pad, prev=prev_blocks.get(l))
-                # cached/prev-reused in TREE order; layout-composed copy
-                # (flat-row values and scatter rows permuted, tile
-                # geometry untouched) is what ships to the device
+                with self.timers.section("regrid: maps tiles"):
+                    b = mapmod.build_block_maps(
+                        self.tree, l, self.bc_kinds,
+                        shift=int(getattr(self.params.amr,
+                                          "oct_block_shift", 2)),
+                        noct_pad=m.noct_pad, prev=prev_blocks.get(l))
+                # TREE order here; the layout-composed copy ships to the device
                 self.blocks[l] = b
                 self.block_stats["blocks_total"] += b.ntile
                 self.block_stats["blocks_rebuilt"] += b.blocks_rebuilt
+                self.block_stats["tiles_native"] += b.tiles_native
                 bt = (balance.apply_layout_blocks(b, lay_m1, lay_l)
                       if (lay_m1 is not None or lay_l is not None) else b)
                 with self.timers.section("regrid: maps upload"):

@@ -427,6 +427,7 @@ class BlockMaps:
     noct: int = 0
     noct_pad: int = 0
     blocks_rebuilt: int = 0          # tiles whose geometry was re-derived
+    tiles_native: int = 0            # tiles the native pass wrote (0: numpy)
 
     @property
     def ndim(self) -> int:
@@ -491,17 +492,65 @@ def _tile_geometry(tree: Octree, lvl: int, tile_key: np.ndarray,
     return ckey, vbits
 
 
+# the BlockMaps arrays that reach the device (``AmrSim._rebuild_maps``),
+# through ``balance.apply_layout_blocks`` where a layout is on
+BLOCK_TABLES = ("tile_src", "tile_ok", "tile_vsgn", "interp_cell",
+                "interp_nb", "interp_sgn", "cell_tile", "cell_slot",
+                "oct_tile", "oct_slot")
+_NO_KEYS = np.zeros(0, dtype=np.int64)
+
+
+def _block_maps_native(tree: Octree, lvl: int, bc_kinds: List[tuple],
+                       shift: int, noct_pad: int,
+                       prev: Optional[BlockMaps]) -> Optional[BlockMaps]:
+    """:func:`build_block_maps` through ``native.tile_tables``: one pass
+    over the level's tiles instead of numpy passes over every tile slot;
+    the same tables element for element.  ``None`` without the library.
+    The pass re-derives the slot geometry (a few integer operations a
+    slot), so ``prev`` only says which tile prefixes are new."""
+    from ramses_tpu import native
+
+    def keys_of(l):
+        return tree.levels[l].keys if tree.has(l) else _NO_KEYS
+
+    t = native.tile_tables(
+        keys_of(lvl - 1), tree.levels[lvl].keys, keys_of(lvl + 1),
+        tree.ndim, tree.cell_dims(lvl), bc_kinds, shift,
+        lvl > tree.levelmin, noct_pad,
+        lambda ntile, ni: (bucket(ntile, 8), bucket(ni, 8) if ni else 8))
+    if t is None:
+        return None
+    nmiss = t.pop("missing_fathers")
+    if nmiss:
+        raise RuntimeError(f"2:1 gradedness violated at level {lvl}: "
+                           f"{nmiss} missing father octs")
+    ntile = t["ntile"]
+    reuse = (prev is not None and prev.shift == shift
+             and prev.lvl == lvl and len(prev.tile_key) > 0)
+    rebuilt = ntile - (int(np.isin(t["tile_key"], prev.tile_key,
+                                   assume_unique=True).sum())
+                       if reuse else 0)
+    return BlockMaps(lvl=lvl, shift=shift, noct=tree.noct(lvl),
+                     noct_pad=noct_pad, blocks_rebuilt=rebuilt,
+                     tiles_native=ntile, **t)
+
+
 def build_block_maps(tree: Octree, lvl: int, bc_kinds: List[tuple],
                      shift: int = 2, noct_pad: Optional[int] = None,
                      prev: Optional[BlockMaps] = None) -> BlockMaps:
-    """Blocked tile maps for a partial level; with ``prev`` from the last
-    regrid, slot geometry is re-derived only for tiles whose Morton
-    prefix is new (``blocks_rebuilt`` counts them)."""
+    """Blocked tile maps for a partial level, by the native pass where
+    the library loaded (``tiles_native`` counts its tiles), else by the
+    numpy passes below; with ``prev`` from the last regrid those
+    re-derive slot geometry only for tiles whose Morton prefix is new
+    (``blocks_rebuilt`` counts the new prefixes either way)."""
     ndim = tree.ndim
     twotondim = 1 << ndim
     lev = tree.levels[lvl]
     noct = lev.noct
     noct_pad = noct_pad or bucket(noct)
+    nat = _block_maps_native(tree, lvl, bc_kinds, shift, noct_pad, prev)
+    if nat is not None:
+        return nat
     ncell_pad = noct_pad * twotondim
     c = 1 << (shift + 1)
     td = c + 2 * NGHOST_TILE

@@ -417,6 +417,10 @@ class Telemetry:
         if bst and "blocked_frac" in bst:
             # fraction of partial-level octs on the blocked tile sweep
             rec["blocked_frac"] = round(float(bst["blocked_frac"]), 4)
+            # tiles of the live tile tables, and those of them the native
+            # pass wrote (equal wherever ``ramses_tpu.native`` loaded)
+            rec["blocks_total"] = int(bst.get("blocks_total", 0))
+            rec["tiles_native"] = int(bst.get("tiles_native", 0))
         fst = getattr(sim, "flag_stats", None)
         if fst:
             # the newest regrid's flag decode: octs (bytes) fetched,
@@ -592,6 +596,8 @@ def sim_run_info(sim) -> Dict[str, Any]:
     bst = getattr(sim, "block_stats", None)
     if bst and "blocked_frac" in bst:
         info["blocked_frac"] = round(float(bst["blocked_frac"]), 4)
+        info["blocks_total"] = int(bst.get("blocks_total", 0))
+        info["tiles_native"] = int(bst.get("tiles_native", 0))
     off = getattr(sim, "_offload", None)
     if off is not None:
         info["offload"] = off.mode

@@ -145,6 +145,34 @@ def test_incremental_rebuild_matches_fresh():
             assert np.array_equal(b.tile_vsgn, fresh.tile_vsgn), l
 
 
+@pytest.mark.parametrize("use_native", [True, False],
+                         ids=["native", "RAMSES_TPU_NATIVE=0"])
+def test_tiles_native_counts_the_native_pass(monkeypatch, use_native):
+    """``block_stats["tiles_native"]``: every live tile where the native
+    pass wrote the tables (a reused level's too), none under
+    ``RAMSES_TPU_NATIVE=0`` — and the tables are the same either way."""
+    from ramses_tpu import native
+    monkeypatch.delenv("RAMSES_TPU_NATIVE", raising=False)
+    if native.lib() is None:
+        pytest.skip("no native lib")
+    ref = _sedov(".true.")
+    monkeypatch.setenv("RAMSES_TPU_NATIVE", "1" if use_native else "0")
+    sim = _sedov(".true.")
+    for s in (ref, sim):
+        s.step_coarse(s.coarse_dt())
+        s.regrid()
+    want = sim.block_stats["blocks_total"] if use_native else 0
+    assert sim.block_stats["tiles_native"] == want, sim.block_stats
+    sim.regrid()                       # unchanged tree: tables reused
+    assert sim.block_stats["tiles_native"] == want, sim.block_stats
+    assert ref.blocks.keys() == sim.blocks.keys() and sim.blocks
+    for l, b in sim.blocks.items():
+        for f in mapmod.BLOCK_TABLES:
+            x, y = getattr(ref.blocks[l], f), getattr(b, f)
+            assert (x is None and y is None) or (
+                x.dtype == y.dtype and np.array_equal(x, y)), (l, f)
+
+
 def _parity(lmin, lmax, ndim, dtype=None, riemann="llf", nstep=2,
             with_regrid=True):
     sims = {}

@@ -53,10 +53,10 @@ err_grad_p=0.1
 
 REGRID_LABELS = {
     "regrid", "regrid: flag", "regrid: flag fetch", "regrid: tree build",
-    "regrid: balance", "regrid: maps", "regrid: maps upload",
-    "regrid: migrate", "regrid: restrict"}
+    "regrid: balance", "regrid: maps", "regrid: maps tiles",
+    "regrid: maps upload", "regrid: migrate", "regrid: restrict"}
 REGRID_PHASES = REGRID_LABELS - {"regrid", "regrid: flag fetch",
-                                 "regrid: tree build",
+                                 "regrid: tree build", "regrid: maps tiles",
                                  "regrid: maps upload"}
 
 
@@ -231,7 +231,8 @@ def test_regrid_under_a_profiler_session(tmp_path, ring):
         "regrid": None, "regrid: flag": "regrid",
         "regrid: flag fetch": "regrid: flag",
         "regrid: tree build": "regrid: flag", "regrid: balance": "regrid",
-        "regrid: maps": "regrid", "regrid: maps upload": "regrid: maps",
+        "regrid: maps": "regrid", "regrid: maps tiles": "regrid: maps",
+        "regrid: maps upload": "regrid: maps",
         "regrid: migrate": "regrid", "regrid: restrict": "regrid"}
     # each phase once a regrid, inside it, and together nearly all of it
     for g in regrids:
